@@ -1,10 +1,1 @@
-"""Integer forward/backward fine-tuning reproduction (JAX + Pallas).
-
-Partitionable threefry is forced on so parameter init and stochastic
-rounding draw identical bits whether or not the computation is sharded —
-required for the sharded-vs-single-device equivalence tests and for
-reproducible multi-pod runs (newer jax versions default to this).
-"""
-import jax as _jax
-
-_jax.config.update("jax_threefry_partitionable", True)
+"""Integer forward/backward fine-tuning reproduction (JAX + Pallas)."""

@@ -226,9 +226,12 @@ def dfx_dot_general(
         bits_a, bits_b = bits if bits is not None else (
             _storage_bits(a.m), _storage_bits(b.m))
         preferred_element_type = acc_dtype(bits_a, bits_b, contraction)
+    # HIGHEST: a TPU otherwise runs f32 matmuls as bf16 passes, which would
+    # round the integer-valued mantissas the exactness bound above assumes.
     prod = jax.lax.dot_general(
         a.m.astype(preferred_element_type), b.m.astype(preferred_element_type),
         dimension_numbers=dimension_numbers,
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=preferred_element_type,
     )
     # Per-axis scales are re-laid-out to the dot_general output convention

@@ -304,6 +304,7 @@ def _stacked_pallas_quantize(x: Array, bits: int, *, stochastic: bool = False,
 def _batched_dfx_dot(a: dfx.DfxTensor, b: dfx.DfxTensor, dn) -> Array:
     prod = jax.lax.dot_general(a.m.astype(jnp.float32), b.m.astype(jnp.float32),
                                dimension_numbers=dn,
+                               precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
     out_exp = (a.exp + b.exp).astype(jnp.float32)             # (E, 1, 1)
     return prod * jnp.exp2(out_exp.reshape(-1, 1, 1))

@@ -37,15 +37,19 @@ def stability_violated(cfg: "QuantConfig") -> bool:
 
 
 def _env_default_backend() -> str:
-    """Default execution backend; ``REPRO_BACKEND`` overrides it.
+    """Default execution backend: ``"pallas"`` on a TPU, ``"sim"`` elsewhere.
 
-    Lets CI run the whole tier-1 suite as a ``{sim, pallas}`` backend matrix
-    (``.github/workflows/ci.yml``) without threading a flag through every
-    test — any ``QuantConfig`` built without an explicit ``backend=`` picks
-    up the environment's choice.  Invalid values fail fast in
-    ``__post_init__``.
+    Decided when a config is built (never at import), from the platform JAX
+    runs on, so a chip run takes the kernels without being told to.
+    ``REPRO_BACKEND`` overrides it — CI runs the whole tier-1 suite as a
+    ``{sim, pallas}`` backend matrix (``.github/workflows/ci.yml``) that
+    way.  Invalid values fail fast in ``__post_init__``.
     """
-    return os.environ.get("REPRO_BACKEND", "sim")
+    env = os.environ.get("REPRO_BACKEND")
+    if env:
+        return env
+    import jax
+    return "pallas" if jax.default_backend() == "tpu" else "sim"
 
 
 def _env_default_kept_ops() -> str:
@@ -85,8 +89,8 @@ class QuantConfig:
     #: by ``dfx.acc_dtype``); "pallas" routes quantization and both matmul
     #: directions (forward q(X)·q(W), backward dX/dW) through the Pallas
     #: kernels in ``repro.kernels`` — bit-exact int32 limb accumulation,
-    #: interpret mode off-TPU.  Defaults to $REPRO_BACKEND (else "sim") so
-    #: CI can matrix the whole suite over both backends.
+    #: interpret mode off-TPU.  Defaults to $REPRO_BACKEND, else "pallas" on
+    #: a TPU and "sim" elsewhere.
     backend: str = dataclasses.field(default_factory=_env_default_backend)
     #: what the paper's *kept* FP32 ops (softmax exp, GeLU/SiLU, the norm
     #: rsqrt, the pooler tanh) compute with: "fp32" is the paper's setting;

@@ -54,10 +54,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; take
-# whichever this version provides.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 # single source of the limb radix: the combine's 2^(7(jx+jw)) shifts MUST
 # match the digit split in the quantize kernel.
 from repro.kernels.dfx_quant import LIMB_BITS  # noqa: E402
@@ -107,7 +103,7 @@ def _bfp_matmul_kernel(x_ref, w_ref, exp_ref, o_ref, acc_ref, *,
     for jx in range(lx):
         for jw in range(lw):
             acc_ref[jx * lw + jw] += jax.lax.dot_general(
-                x_ref[jx].astype(jnp.int32), w_ref[jw].astype(jnp.int32),
+                x_ref[jx], w_ref[jw],
                 (((lc,), (rc,)), ((), ())),
                 preferred_element_type=jnp.int32,
             )
@@ -131,13 +127,13 @@ def _bfp_call(xm, wm, out_exp, *, out_shape, grid, x_spec, w_spec,
         in_specs=[
             x_spec,
             w_spec,
-            pl.BlockSpec(memory_space=pl.ANY),   # scalar exp, loaded whole
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # scalar exp
         ],
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((lx * lw,) + out_spec.block_shape, jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xm, wm, jnp.reshape(out_exp, (1,)).astype(jnp.int32))
@@ -262,7 +258,7 @@ def _bfp_matmul_batched_kernel(x_ref, w_ref, exp_ref, o_ref, acc_ref, *,
     for jx in range(lx):
         for jw in range(lw):
             acc_ref[jx * lw + jw] += jax.lax.dot_general(
-                x_ref[jx, 0].astype(jnp.int32), w_ref[jw, 0].astype(jnp.int32),
+                x_ref[jx, 0], w_ref[jw, 0],
                 (((lc,), (rc,)), ((), ())),
                 preferred_element_type=jnp.int32,
             )
@@ -285,13 +281,13 @@ def _bfp_batched_call(xm, wm, out_exp, *, out_shape, grid, x_spec, w_spec,
         in_specs=[
             x_spec,
             w_spec,
-            pl.BlockSpec(memory_space=pl.ANY),   # (E,) exp vector, whole
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # (E,) exp vector
         ],
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((lx * lw,) + out_spec.block_shape[1:], jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -6,9 +6,9 @@ Two-pass structure (DESIGN.md §2): pass 1 is the max-abs exponent reduction
 
     m = clip(round(x * 2^-exp  [+ u]), ±(2^(b-1)-1)) -> int8/int16
 
-with optional stochastic rounding (``u`` uniform noise; on real TPU this is
-generated in-kernel by ``pltpu.prng_random_bits`` — the noise input path is
-used for interpret-mode validation and bit-exact cross-checks).
+with optional stochastic rounding (``u`` uniform noise, drawn in XLA from
+the layer's key and streamed in beside ``x``, so both backends round with
+the same bits).  The scale exponent is a scalar operand in SMEM.
 
 **Fused limb splitting** (``limb_planes=True``): the matmul kernels consume
 ``b``-bit mantissas as stacked int8 **balanced base-2⁷ limb planes**
@@ -42,9 +42,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; take
-# whichever this version provides.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+#: scale exponents (a scalar, or the grouped kernel's (E,) vector) ride in
+#: SMEM: Mosaic loads scalars only from SMEM/VMEM refs.
+_EXP_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 #: balanced-digit radix: every non-final limb lies in [-64, 63] and the final
 #: carry in [-64, 64] — all int8, and every limb product fits the MXU's
@@ -146,20 +146,20 @@ def dfx_quantize(
         grid=grid,
         out_specs=out_spec,
         out_shape=out_shape,
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )
     if u is None:
         return pl.pallas_call(
             functools.partial(kern, bits=bits),
             in_specs=[pl.BlockSpec((br, N), lambda i: (i, 0)),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+                      _EXP_SPEC],
             **common,
         )(x, exp)
     return pl.pallas_call(
         functools.partial(kern_stoch, bits=bits),
         in_specs=[pl.BlockSpec((br, N), lambda i: (i, 0)),
-                  pl.BlockSpec(memory_space=pl.ANY),
+                  _EXP_SPEC,
                   pl.BlockSpec((br, N), lambda i: (i, 0))],
         **common,
     )(x, exp, u)
@@ -230,18 +230,18 @@ def dfx_quantize_grouped(
         grid=grid,
         out_specs=out_spec,
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )
     if u is None:
         return pl.pallas_call(
             functools.partial(kern, bits=bits),
-            in_specs=[blk, pl.BlockSpec(memory_space=pl.ANY)],
+            in_specs=[blk, _EXP_SPEC],
             **common,
         )(x, exp)
     return pl.pallas_call(
         functools.partial(kern_stoch, bits=bits),
-        in_specs=[blk, pl.BlockSpec(memory_space=pl.ANY), blk],
+        in_specs=[blk, _EXP_SPEC, blk],
         **common,
     )(x, exp, u)

@@ -64,10 +64,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; take
-# whichever this version provides.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 # single source of the limb radix + digit split: quantized P / dS planes cut
 # in-kernel MUST match the shifts the quantize kernel uses for Q/K/V.
 from repro.core import iapprox
@@ -75,6 +71,10 @@ from repro.kernels.dfx_quant import (  # noqa: E402
     LIMB_BITS, _round_clip, _split_planes, n_limbs)
 
 _BIG_NEG = -1e30
+
+#: the per-row query offsets and the scale exponents are scalars read by
+#: index: Mosaic loads those only from SMEM.
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _limb_dot(a_ref, b_ref, la: int, lb: int, dims, exp_f32, shift: int):
@@ -93,7 +93,7 @@ def _limb_dot(a_ref, b_ref, la: int, lb: int, dims, exp_f32, shift: int):
     for ja in range(la):
         for jb in range(lb):
             part = jax.lax.dot_general(
-                a_ref[ja, 0].astype(jnp.int32), b_ref[jb, 0].astype(jnp.int32),
+                a_ref[ja, 0], b_ref[jb, 0],
                 (((lc,), (rc,)), ((), ())),
                 preferred_element_type=jnp.int32,
             )
@@ -105,14 +105,15 @@ def _limb_dot(a_ref, b_ref, la: int, lb: int, dims, exp_f32, shift: int):
 
 def _plane_dot(planes, b_ref, lb: int, dims, exp_f32, shift: int):
     """Like ``_limb_dot`` but the lhs limbs are in-register f32 digit planes
-    (the just-quantized P or dS), converted to int32 at the MXU boundary."""
+    (the just-quantized P or dS), cast to int8 at the MXU boundary — every
+    digit lies in [-64, 64]."""
     lc, rc = dims
     scale0 = jnp.exp2(exp_f32)
     out = None
     for ja, plane in enumerate(planes):
         for jb in range(lb):
             part = jax.lax.dot_general(
-                plane.astype(jnp.int32), b_ref[jb, 0].astype(jnp.int32),
+                plane.astype(jnp.int8), b_ref[jb, 0],
                 (((lc,), (rc,)), ((), ())),
                 preferred_element_type=jnp.int32,
             )
@@ -257,8 +258,8 @@ def int_attn_fwd(
             pl.BlockSpec((Lq, 1, bq, hd_p), lambda h, i, j: (0, h, i, 0)),
             pl.BlockSpec((Lk, 1, bk, hd_p), lambda h, i, j: (0, h, j, 0)),
             pl.BlockSpec((Lv, 1, bk, hd_p), lambda h, i, j: (0, h, j, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),   # (B,) offsets, loaded whole
-            pl.BlockSpec(memory_space=pl.ANY),   # (3,) exps, loaded whole
+            _SMEM,                               # (B,) query offsets
+            _SMEM,                               # (3,) exps
         ],
         out_specs=[
             pl.BlockSpec((1, bq, hd_p), lambda h, i, j: (h, i, 0)),
@@ -273,7 +274,7 @@ def int_attn_fwd(
             pltpu.VMEM((bq, 1), jnp.float32),      # running normalizer
             pltpu.VMEM((bq, hd_p), jnp.float32),   # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qm, km, vm, q_off.astype(jnp.int32), exps.astype(jnp.int32))
@@ -370,13 +371,13 @@ def int_attn_bwd_dq(
             pl.BlockSpec((Lg, 1, bq, hd_p), lambda h, i, j: (0, h, i, 0)),
             pl.BlockSpec((1, bq, 1), lambda h, i, j: (h, i, 0)),
             pl.BlockSpec((1, bq, 1), lambda h, i, j: (h, i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
+            _SMEM,                               # (B,) query offsets
+            _SMEM,                               # (5,) exps
         ],
         out_specs=pl.BlockSpec((1, bq, hd_p), lambda h, i, j: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, R, hd_p), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bq, hd_p), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qm, km, vm, gm, lse, delta,
@@ -483,8 +484,8 @@ def int_attn_bwd_dkv(
             pl.BlockSpec((Lg, 1, bq, hd_p), lambda h, j, i: (0, h, i, 0)),
             pl.BlockSpec((1, bq, 1), lambda h, j, i: (h, i, 0)),
             pl.BlockSpec((1, bq, 1), lambda h, j, i: (h, i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
+            _SMEM,                               # (B,) query offsets
+            _SMEM,                               # (5,) exps
         ],
         out_specs=[
             pl.BlockSpec((1, bk, hd_p), lambda h, j, i: (h, j, 0)),
@@ -498,7 +499,7 @@ def int_attn_bwd_dkv(
             pltpu.VMEM((bk, hd_p), jnp.float32),
             pltpu.VMEM((bk, hd_p), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qm, km, vm, gm, lse, delta,

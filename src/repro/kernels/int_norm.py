@@ -18,7 +18,7 @@ it (the statistics-mismatch bug this module fixes), and the second full HBM
 pass over every normalized activation disappears.
 
 Backward kernels produce ``dx`` plus **per-row-block partial reductions**
-for ``dgamma``/``dbeta`` (row ``i`` of an ``(R/br, D)`` output is block
+for ``dgamma``/``dbeta`` (entry ``i`` of an ``(R/br, 1, D)`` output is block
 ``i``'s contribution); the cross-block combine is a small XLA tree-sum in
 the ops.py wrapper.  ``dbeta`` partials are exact int32 sums of the gradient
 mantissas; ``dgamma`` partials multiply the integer gradient mantissas by
@@ -40,9 +40,17 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import iapprox
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; take
-# whichever this version provides.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+#: the scalar scale exponents ride in SMEM (Mosaic loads scalars only from
+#: SMEM/VMEM refs).
+_EXP_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _partial_spec(d: int) -> pl.BlockSpec:
+    """Block ``i`` of the backward's ``(R/br, 1, D)`` parameter-gradient
+    partials.  The trailing ``(1, D)`` block spans the array's full trailing
+    dims, which the (8, 128) tiling rule admits; a ``(1, D)`` block over an
+    ``(R/br, D)`` array does not."""
+    return pl.BlockSpec((1, 1, d), lambda i: (i, 0, 0))
 
 
 def _exact_moments(xi: jax.Array):
@@ -136,7 +144,7 @@ def int_layernorm_fwd(
         grid=(R // br,),
         in_specs=[
             pl.BlockSpec((br, D), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            _EXP_SPEC,
             pl.BlockSpec((1, D), lambda i: (0, 0)),
             pl.BlockSpec((1, D), lambda i: (0, 0)),
         ],
@@ -150,7 +158,7 @@ def int_layernorm_fwd(
             jax.ShapeDtypeStruct((R, 1), jnp.float32),
             jax.ShapeDtypeStruct((R, 1), jnp.float32),
         ),
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(xm, jnp.reshape(x_exp, (1,)).astype(jnp.int32),
       gamma.reshape(1, D), beta.reshape(1, D))
@@ -173,8 +181,8 @@ def _ln_bwd_kernel(xm_ref, gm_ref, xexp_ref, gexp_ref, gv_ref, mu_ref,
     dx_ref[...] = rstd_ref[...] * (gg - mean_gg - xn * mean_ggxn)
     # Per-block partials; dbeta's row sum is exact int32 over the gradient
     # mantissas (|g| <= 2^15, br <= 128 ⇒ 22 bits), scaled once.
-    db_ref[...] = jnp.sum(gi, axis=0, keepdims=True).astype(jnp.float32) * gscale
-    dg_ref[...] = jnp.sum(gq * xn, axis=0, keepdims=True)
+    db_ref[0] = jnp.sum(gi, axis=0, keepdims=True).astype(jnp.float32) * gscale
+    dg_ref[0] = jnp.sum(gq * xn, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("br", "interpret"))
@@ -191,7 +199,8 @@ def int_layernorm_bwd(
     interpret: bool = False,
 ):
     """Fused LN backward. Returns ``(dx, dgamma_partials, dbeta_partials)``
-    with partials of shape (R/br, D) — row i is block i's contribution."""
+    with partials of shape (R/br, 1, D) — entry i is block i's
+    contribution."""
     R, D = xm.shape
     assert R % br == 0, (R, br)
     nb = R // br
@@ -201,23 +210,23 @@ def int_layernorm_bwd(
         in_specs=[
             pl.BlockSpec((br, D), lambda i: (i, 0)),
             pl.BlockSpec((br, D), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
+            _EXP_SPEC,
+            _EXP_SPEC,
             pl.BlockSpec((1, D), lambda i: (0, 0)),
             pl.BlockSpec((br, 1), lambda i: (i, 0)),
             pl.BlockSpec((br, 1), lambda i: (i, 0)),
         ],
         out_specs=(
             pl.BlockSpec((br, D), lambda i: (i, 0)),
-            pl.BlockSpec((1, D), lambda i: (i, 0)),
-            pl.BlockSpec((1, D), lambda i: (i, 0)),
+            _partial_spec(D),
+            _partial_spec(D),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((R, D), jnp.float32),
-            jax.ShapeDtypeStruct((nb, D), jnp.float32),
-            jax.ShapeDtypeStruct((nb, D), jnp.float32),
+            jax.ShapeDtypeStruct((nb, 1, D), jnp.float32),
+            jax.ShapeDtypeStruct((nb, 1, D), jnp.float32),
         ),
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(xm, gm, jnp.reshape(x_exp, (1,)).astype(jnp.int32),
       jnp.reshape(g_exp, (1,)).astype(jnp.int32), gamma.reshape(1, D),
@@ -263,7 +272,7 @@ def int_rmsnorm_fwd(
         grid=(R // br,),
         in_specs=[
             pl.BlockSpec((br, D), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            _EXP_SPEC,
             pl.BlockSpec((1, D), lambda i: (0, 0)),
         ],
         out_specs=(
@@ -274,7 +283,7 @@ def int_rmsnorm_fwd(
             jax.ShapeDtypeStruct((R, D), jnp.float32),
             jax.ShapeDtypeStruct((R, 1), jnp.float32),
         ),
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(xm, jnp.reshape(x_exp, (1,)).astype(jnp.int32), gamma.reshape(1, D))
 
@@ -291,7 +300,7 @@ def _rms_bwd_kernel(xm_ref, gm_ref, xexp_ref, gexp_ref, gv_ref, rstd_ref,
     gg = gq * gv_ref[...]
     mean_ggxn = jnp.sum(gg * xn, axis=-1, keepdims=True) / d
     dx_ref[...] = rstd_ref[...] * (gg - xn * mean_ggxn)
-    dg_ref[...] = jnp.sum(gq * xn, axis=0, keepdims=True)
+    dg_ref[0] = jnp.sum(gq * xn, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("br", "interpret"))
@@ -316,20 +325,20 @@ def int_rmsnorm_bwd(
         in_specs=[
             pl.BlockSpec((br, D), lambda i: (i, 0)),
             pl.BlockSpec((br, D), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
+            _EXP_SPEC,
+            _EXP_SPEC,
             pl.BlockSpec((1, D), lambda i: (0, 0)),
             pl.BlockSpec((br, 1), lambda i: (i, 0)),
         ],
         out_specs=(
             pl.BlockSpec((br, D), lambda i: (i, 0)),
-            pl.BlockSpec((1, D), lambda i: (i, 0)),
+            _partial_spec(D),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((R, D), jnp.float32),
-            jax.ShapeDtypeStruct((nb, D), jnp.float32),
+            jax.ShapeDtypeStruct((nb, 1, D), jnp.float32),
         ),
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(xm, gm, jnp.reshape(x_exp, (1,)).astype(jnp.int32),
       jnp.reshape(g_exp, (1,)).astype(jnp.int32), gamma.reshape(1, D), rstd)

@@ -16,7 +16,12 @@ Adds the pieces that keep the kernels simple:
   issued up to 3×3 = 9 kernel launches and re-streamed every operand tile
   from HBM once per pair.
 * shape padding to MXU tile multiples, and un-padding of the result;
-* automatic ``interpret=True`` when not running on real TPU hardware.
+* automatic ``interpret=True`` when not running on real TPU hardware;
+* one ``shard_map`` per kernel call over the active mesh's batch axes
+  (``sharding.over_batch``): XLA cannot partition a Mosaic kernel, so each
+  chip runs the kernel on its share of rows with the weights replicated,
+  and the products that contract over rows (dW, the norm dgamma/dbeta) are
+  summed across chips with ``psum``.
 
 Three matmul layouts cover the integer layers end-to-end (DESIGN.md §2):
 
@@ -67,12 +72,14 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import sharding
 from repro.kernels.bfp_matmul import (bfp_matmul, bfp_matmul_batched,
                                       bfp_matmul_batched_nt,
                                       bfp_matmul_batched_tn, bfp_matmul_nt,
                                       bfp_matmul_tn)
-from repro.kernels.dfx_quant import (LIMB_BITS as _LIMB_BITS, dfx_quantize,
-                                     dfx_quantize_grouped, n_limbs)
+from repro.kernels.dfx_quant import (LIMB_BITS as _LIMB_BITS, _out_dtype,
+                                     dfx_quantize, dfx_quantize_grouped,
+                                     n_limbs)
 from repro.kernels.int_attention import (int_attn_bwd_dkv, int_attn_bwd_dq,
                                          int_attn_fwd)
 from repro.kernels.int_norm import (int_layernorm_bwd, int_layernorm_fwd,
@@ -84,10 +91,14 @@ _LANE = 128
 #: VPU sublane width: the second-to-last block dimension's multiple.
 _SUBLANE = 8
 
-#: VMEM budget for one matmul grid step (operand blocks double-buffered,
-#: per-limb-pair int32 accumulator scratch, output block) — conservatively
-#: half of a TPU core's ~16 MB so the compiler keeps headroom for spills.
+#: VMEM budget for one matmul or quantize grid step (blocks double-buffered,
+#: plus the matmul's per-limb-pair int32 accumulator scratch) — half of the
+#: 16 MiB scoped VMEM a v5e kernel may use, so the compiler keeps headroom
+#: for in-kernel temporaries.
 _VMEM_BUDGET = 8 * 1024 * 1024
+
+#: the quantize kernel's row block never exceeds this many rows.
+_QUANT_ROWS = 256
 
 
 def on_tpu() -> bool:
@@ -217,16 +228,20 @@ def dfx_matmul_tiled(
     """
     if interpret is None:
         interpret = not on_tpu()
-    xm = _as_planes(xm, x_bits, 2)
-    wm = _as_planes(wm, w_bits, 2)
-    _, M, K = xm.shape
-    _, _, N = wm.shape
-    bm, bn, bk = _pick_blocks(M, N, K, xm.shape[0], wm.shape[0])
-    xm, wm = _pad_last2(xm, bm, bk), _pad_last2(wm, bk, bn)
     out_exp = (x_exp + w_exp).astype(jnp.int32)
-    out = bfp_matmul(xm, wm, out_exp, bm=bm, bn=bn, bk=bk,
-                     interpret=interpret)
-    return out[:M, :N]
+
+    def local(xm, wm, out_exp):
+        _, M, K = xm.shape
+        _, _, N = wm.shape
+        bm, bn, bk = _pick_blocks(M, N, K, xm.shape[0], wm.shape[0])
+        xm, wm = _pad_last2(xm, bm, bk), _pad_last2(wm, bk, bn)
+        out = bfp_matmul(xm, wm, out_exp, bm=bm, bn=bn, bk=bk,
+                         interpret=interpret)
+        return out[:M, :N]
+
+    return sharding.over_batch(
+        local, (_as_planes(xm, x_bits, 2), _as_planes(wm, w_bits, 2), out_exp),
+        (1, None, None), 0)
 
 
 def dfx_matmul_tiled_nt(
@@ -242,17 +257,21 @@ def dfx_matmul_tiled_nt(
     """
     if interpret is None:
         interpret = not on_tpu()
-    gm = _as_planes(gm, g_bits, 2)
-    wm = _as_planes(wm, w_bits, 2)
-    _, M, N = gm.shape
-    _, K, _ = wm.shape
-    # out is (M, K): M is the sublane-flexible dim, K and N ride the lanes.
-    bm, bn, bk = _pick_blocks(M, K, N, gm.shape[0], wm.shape[0])
-    gm, wm = _pad_last2(gm, bm, bk), _pad_last2(wm, bn, bk)
     out_exp = (g_exp + w_exp).astype(jnp.int32)
-    out = bfp_matmul_nt(gm, wm, out_exp, bm=bm, bn=bn, bk=bk,
-                        interpret=interpret)
-    return out[:M, :K]
+
+    def local(gm, wm, out_exp):
+        _, M, N = gm.shape
+        _, K, _ = wm.shape
+        # out is (M, K): M is the sublane-flexible dim, K and N ride lanes.
+        bm, bn, bk = _pick_blocks(M, K, N, gm.shape[0], wm.shape[0])
+        gm, wm = _pad_last2(gm, bm, bk), _pad_last2(wm, bn, bk)
+        out = bfp_matmul_nt(gm, wm, out_exp, bm=bm, bn=bn, bk=bk,
+                            interpret=interpret)
+        return out[:M, :K]
+
+    return sharding.over_batch(
+        local, (_as_planes(gm, g_bits, 2), _as_planes(wm, w_bits, 2), out_exp),
+        (1, None, None), 0)
 
 
 def dfx_matmul_tiled_tn(
@@ -264,24 +283,29 @@ def dfx_matmul_tiled_tn(
 
     xm: (Lx, M, K) activation limb planes, gm: (Lg, M, N) grad limb planes
     (logical 2-D mantissas also accepted).  Returns FP32 (K, N).  The kernel
-    contracts the shared M axis in place.
+    contracts the shared M axis in place; across chips each contracts its
+    own rows and the f32 partials are summed (``psum``).
     """
     if interpret is None:
         interpret = not on_tpu()
-    xm = _as_planes(xm, x_bits, 2)
-    gm = _as_planes(gm, g_bits, 2)
-    _, M, K = xm.shape
-    _, _, N = gm.shape
-    # out is (K, N): K and N ride the lanes of the output tile; the
-    # contracted M axis is the sublane-flexible one here (so the budget
-    # model must hold the accumulator/output tiles fixed — see _pick_blocks)
-    bk, bm, bn = _pick_blocks(M, K, N, xm.shape[0], gm.shape[0],
-                              contracted_sublane=True)
-    xm, gm = _pad_last2(xm, bk, bm), _pad_last2(gm, bk, bn)
     out_exp = (x_exp + g_exp).astype(jnp.int32)
-    out = bfp_matmul_tn(xm, gm, out_exp, bm=bm, bn=bn, bk=bk,
-                        interpret=interpret)
-    return out[:K, :N]
+
+    def local(xm, gm, out_exp):
+        _, M, K = xm.shape
+        _, _, N = gm.shape
+        # out is (K, N): K and N ride the lanes of the output tile; the
+        # contracted M axis is the sublane-flexible one here (so the budget
+        # model must hold the accumulator/output tiles fixed — _pick_blocks)
+        bk, bm, bn = _pick_blocks(M, K, N, xm.shape[0], gm.shape[0],
+                                  contracted_sublane=True)
+        xm, gm = _pad_last2(xm, bk, bm), _pad_last2(gm, bk, bn)
+        out = bfp_matmul_tn(xm, gm, out_exp, bm=bm, bn=bn, bk=bk,
+                            interpret=interpret)
+        return out[:K, :N]
+
+    return sharding.over_batch(
+        local, (_as_planes(xm, x_bits, 2), _as_planes(gm, g_bits, 2), out_exp),
+        (1, 1, None), "sum")
 
 
 def dfx_matmul_tiled_batched(
@@ -300,15 +324,20 @@ def dfx_matmul_tiled_batched(
     if interpret is None:
         interpret = not on_tpu()
     xm = _as_planes(xm, x_bits, 3)
-    wm = _as_planes(wm, w_bits, 3)
-    _, E, M, K = xm.shape
-    _, _, _, N = wm.shape
-    bm, bn, bk = _pick_blocks(M, N, K, xm.shape[0], wm.shape[0])
-    xm, wm = _pad_last2(xm, bm, bk), _pad_last2(wm, bk, bn)
+    E = xm.shape[1]
     out_exp = (jnp.reshape(x_exp, (E,)) + jnp.reshape(w_exp, (E,))).astype(jnp.int32)
-    out = bfp_matmul_batched(xm, wm, out_exp, bm=bm, bn=bn, bk=bk,
-                             interpret=interpret)
-    return out[:, :M, :N]
+
+    def local(xm, wm, out_exp):
+        _, _, M, K = xm.shape
+        _, _, _, N = wm.shape
+        bm, bn, bk = _pick_blocks(M, N, K, xm.shape[0], wm.shape[0])
+        xm, wm = _pad_last2(xm, bm, bk), _pad_last2(wm, bk, bn)
+        out = bfp_matmul_batched(xm, wm, out_exp, bm=bm, bn=bn, bk=bk,
+                                 interpret=interpret)
+        return out[:, :M, :N]
+
+    return sharding.over_batch(
+        local, (xm, _as_planes(wm, w_bits, 3), out_exp), (2, None, None), 1)
 
 
 def dfx_matmul_tiled_batched_nt(
@@ -324,15 +353,20 @@ def dfx_matmul_tiled_batched_nt(
     if interpret is None:
         interpret = not on_tpu()
     gm = _as_planes(gm, g_bits, 3)
-    wm = _as_planes(wm, w_bits, 3)
-    _, E, M, N = gm.shape
-    _, _, K, _ = wm.shape
-    bm, bn, bk = _pick_blocks(M, K, N, gm.shape[0], wm.shape[0])
-    gm, wm = _pad_last2(gm, bm, bk), _pad_last2(wm, bn, bk)
+    E = gm.shape[1]
     out_exp = (jnp.reshape(g_exp, (E,)) + jnp.reshape(w_exp, (E,))).astype(jnp.int32)
-    out = bfp_matmul_batched_nt(gm, wm, out_exp, bm=bm, bn=bn, bk=bk,
-                                interpret=interpret)
-    return out[:, :M, :K]
+
+    def local(gm, wm, out_exp):
+        _, _, M, N = gm.shape
+        _, _, K, _ = wm.shape
+        bm, bn, bk = _pick_blocks(M, K, N, gm.shape[0], wm.shape[0])
+        gm, wm = _pad_last2(gm, bm, bk), _pad_last2(wm, bn, bk)
+        out = bfp_matmul_batched_nt(gm, wm, out_exp, bm=bm, bn=bn, bk=bk,
+                                    interpret=interpret)
+        return out[:, :M, :K]
+
+    return sharding.over_batch(
+        local, (gm, _as_planes(wm, w_bits, 3), out_exp), (2, None, None), 1)
 
 
 def dfx_matmul_tiled_batched_tn(
@@ -348,16 +382,47 @@ def dfx_matmul_tiled_batched_tn(
     if interpret is None:
         interpret = not on_tpu()
     xm = _as_planes(xm, x_bits, 3)
-    gm = _as_planes(gm, g_bits, 3)
-    _, E, M, K = xm.shape
-    _, _, _, N = gm.shape
-    bk, bm, bn = _pick_blocks(M, K, N, xm.shape[0], gm.shape[0],
-                              contracted_sublane=True)
-    xm, gm = _pad_last2(xm, bk, bm), _pad_last2(gm, bk, bn)
+    E = xm.shape[1]
     out_exp = (jnp.reshape(x_exp, (E,)) + jnp.reshape(g_exp, (E,))).astype(jnp.int32)
-    out = bfp_matmul_batched_tn(xm, gm, out_exp, bm=bm, bn=bn, bk=bk,
-                                interpret=interpret)
-    return out[:, :K, :N]
+
+    def local(xm, gm, out_exp):
+        _, _, M, K = xm.shape
+        _, _, _, N = gm.shape
+        bk, bm, bn = _pick_blocks(M, K, N, xm.shape[0], gm.shape[0],
+                                  contracted_sublane=True)
+        xm, gm = _pad_last2(xm, bk, bm), _pad_last2(gm, bk, bn)
+        out = bfp_matmul_batched_tn(xm, gm, out_exp, bm=bm, bn=bn, bk=bk,
+                                    interpret=interpret)
+        return out[:, :K, :N]
+
+    return sharding.over_batch(
+        local, (xm, _as_planes(gm, g_bits, 3), out_exp), (2, 2, None), "sum")
+
+
+def quantize_vmem_bytes(br: int, n: int, out_bytes: int,
+                        stochastic: bool) -> int:
+    """VMEM bytes one grid step of the quantize kernel keeps resident.
+
+    Every block is double-buffered: the f32 ``(br, n)`` input, the f32
+    noise ``u`` when rounding is stochastic, and the output — ``out_bytes``
+    per element, i.e. ``L`` for the stacked int8 limb planes or the logical
+    mantissa's itemsize.
+    """
+    return 2 * br * n * (4 + (4 if stochastic else 0) + out_bytes)
+
+
+def _quant_rows(M: int, N: int, bits: int, stochastic: bool,
+                limb_planes: bool) -> int:
+    """Row block of the quantize kernel: at most ``_QUANT_ROWS`` rows,
+    halved (in sublane multiples) until a grid step fits the VMEM budget —
+    the same rule ``_pick_blocks`` applies to the matmul's sublane dim."""
+    out_bytes = (n_limbs(bits) if limb_planes
+                 else jnp.dtype(_out_dtype(bits)).itemsize)
+    br = min(_QUANT_ROWS, _round_up_multiple(M, _SUBLANE))
+    while br > _SUBLANE and quantize_vmem_bytes(
+            br, N, out_bytes, stochastic) > _VMEM_BUDGET:
+        br = _round_up_multiple(br // 2, _SUBLANE)
+    return br
 
 
 def quantize_pallas(x: jax.Array, exp: jax.Array, bits: int,
@@ -372,16 +437,23 @@ def quantize_pallas(x: jax.Array, exp: jax.Array, bits: int,
     """
     if interpret is None:
         interpret = not on_tpu()
-    M, N = x.shape
-    br = min(256, _round_up_multiple(M, _SUBLANE))
-    pm = (-M) % br
-    if pm:
-        x = jnp.pad(x, ((0, pm), (0, 0)))
-        if u is not None:
-            u = jnp.pad(u, ((0, pm), (0, 0)))
-    out = dfx_quantize(x, exp, bits=bits, u=u, br=br, interpret=interpret,
-                       limb_planes=limb_planes)
-    return out[:, :M] if limb_planes else out[:M]
+
+    def local(x, exp, *u):
+        u = u[0] if u else None
+        M, N = x.shape
+        br = _quant_rows(M, N, bits, u is not None, limb_planes)
+        pm = (-M) % br
+        if pm:
+            x = jnp.pad(x, ((0, pm), (0, 0)))
+            if u is not None:
+                u = jnp.pad(u, ((0, pm), (0, 0)))
+        out = dfx_quantize(x, exp, bits=bits, u=u, br=br,
+                           interpret=interpret, limb_planes=limb_planes)
+        return out[:, :M] if limb_planes else out[:M]
+
+    args = (x, exp) + (() if u is None else (u,))
+    return sharding.over_batch(local, args, (0, None, 0)[:len(args)],
+                               1 if limb_planes else 0)
 
 
 def quantize_pallas_batched(x: jax.Array, exp: jax.Array, bits: int,
@@ -399,17 +471,24 @@ def quantize_pallas_batched(x: jax.Array, exp: jax.Array, bits: int,
     """
     if interpret is None:
         interpret = not on_tpu()
-    E, M, N = x.shape
-    br = min(256, _round_up_multiple(M, _SUBLANE))
-    pm = (-M) % br
-    if pm:
-        x = jnp.pad(x, ((0, 0), (0, pm), (0, 0)))
-        if u is not None:
-            u = jnp.pad(u, ((0, 0), (0, pm), (0, 0)))
-    out = dfx_quantize_grouped(x, jnp.reshape(exp, (E,)), bits=bits, u=u,
-                               br=br, interpret=interpret,
-                               limb_planes=limb_planes)
-    return out[:, :, :M] if limb_planes else out[:, :M]
+
+    def local(x, exp, *u):
+        u = u[0] if u else None
+        _, M, N = x.shape
+        br = _quant_rows(M, N, bits, u is not None, limb_planes)
+        pm = (-M) % br
+        if pm:
+            x = jnp.pad(x, ((0, 0), (0, pm), (0, 0)))
+            if u is not None:
+                u = jnp.pad(u, ((0, 0), (0, pm), (0, 0)))
+        out = dfx_quantize_grouped(x, exp, bits=bits, u=u, br=br,
+                                   interpret=interpret,
+                                   limb_planes=limb_planes)
+        return out[:, :, :M] if limb_planes else out[:, :M]
+
+    args = (x, jnp.reshape(exp, (x.shape[0],))) + (() if u is None else (u,))
+    return sharding.over_batch(local, args, (1, None, 1)[:len(args)],
+                               2 if limb_planes else 1)
 
 
 def _pad_rows(R: int, cap: int, *arrs):
@@ -440,12 +519,17 @@ def layernorm_pallas(xm: jax.Array, x_exp: jax.Array, gamma: jax.Array,
     """
     if interpret is None:
         interpret = not on_tpu()
-    R = xm.shape[0]
-    br, (xm,) = _pad_rows(R, 8, xm)
-    y, mu, rstd = int_layernorm_fwd(xm, x_exp, gamma, beta, br=br, eps=eps,
-                                    interpret=interpret,
-                                    integer_rsqrt=integer_rsqrt)
-    return y[:R], mu[:R], rstd[:R]
+
+    def local(xm, x_exp, gamma, beta):
+        R = xm.shape[0]
+        br, (xm,) = _pad_rows(R, 8, xm)
+        y, mu, rstd = int_layernorm_fwd(xm, x_exp, gamma, beta, br=br,
+                                        eps=eps, interpret=interpret,
+                                        integer_rsqrt=integer_rsqrt)
+        return y[:R], mu[:R], rstd[:R]
+
+    return sharding.over_batch(local, (xm, x_exp, gamma, beta),
+                               (0, None, None, None), (0, 0, 0))
 
 
 def layernorm_bwd_pallas(xm: jax.Array, x_exp: jax.Array, gm: jax.Array,
@@ -454,15 +538,22 @@ def layernorm_bwd_pallas(xm: jax.Array, x_exp: jax.Array, gm: jax.Array,
     """Fused LN backward with row padding. Returns ``(dx, dgamma, dbeta)``.
 
     The kernel emits per-row-block dgamma/dbeta partials; the cross-block
-    combine here is a small (R/br, D) XLA tree-sum.
+    combine here is a small (R/br, 1, D) XLA tree-sum, then a ``psum``
+    across chips.
     """
     if interpret is None:
         interpret = not on_tpu()
-    R = xm.shape[0]
-    br, (xm, gm, mu, rstd) = _pad_rows(R, 64, xm, gm, mu, rstd)
-    dx, dgp, dbp = int_layernorm_bwd(xm, gm, x_exp, g_exp, gamma, mu, rstd,
-                                     br=br, interpret=interpret)
-    return dx[:R], jnp.sum(dgp, axis=0), jnp.sum(dbp, axis=0)
+
+    def local(xm, gm, mu, rstd, x_exp, g_exp, gamma):
+        R = xm.shape[0]
+        br, (xm, gm, mu, rstd) = _pad_rows(R, 64, xm, gm, mu, rstd)
+        dx, dgp, dbp = int_layernorm_bwd(xm, gm, x_exp, g_exp, gamma, mu,
+                                         rstd, br=br, interpret=interpret)
+        return dx[:R], jnp.sum(dgp, axis=(0, 1)), jnp.sum(dbp, axis=(0, 1))
+
+    return sharding.over_batch(
+        local, (xm, gm, mu, rstd, x_exp, g_exp, gamma),
+        (0, 0, 0, 0, None, None, None), (0, "sum", "sum"))
 
 
 def rmsnorm_pallas(xm: jax.Array, x_exp: jax.Array, gamma: jax.Array,
@@ -472,12 +563,17 @@ def rmsnorm_pallas(xm: jax.Array, x_exp: jax.Array, gamma: jax.Array,
     ``integer_rsqrt`` as in ``layernorm_pallas``."""
     if interpret is None:
         interpret = not on_tpu()
-    R = xm.shape[0]
-    br, (xm,) = _pad_rows(R, 8, xm)
-    y, rstd = int_rmsnorm_fwd(xm, x_exp, gamma, br=br, eps=eps,
-                              interpret=interpret,
-                              integer_rsqrt=integer_rsqrt)
-    return y[:R], rstd[:R]
+
+    def local(xm, x_exp, gamma):
+        R = xm.shape[0]
+        br, (xm,) = _pad_rows(R, 8, xm)
+        y, rstd = int_rmsnorm_fwd(xm, x_exp, gamma, br=br, eps=eps,
+                                  interpret=interpret,
+                                  integer_rsqrt=integer_rsqrt)
+        return y[:R], rstd[:R]
+
+    return sharding.over_batch(local, (xm, x_exp, gamma), (0, None, None),
+                               (0, 0))
 
 
 def rmsnorm_bwd_pallas(xm: jax.Array, x_exp: jax.Array, gm: jax.Array,
@@ -486,11 +582,16 @@ def rmsnorm_bwd_pallas(xm: jax.Array, x_exp: jax.Array, gm: jax.Array,
     """Fused RMS-norm backward with row padding. Returns ``(dx, dgamma)``."""
     if interpret is None:
         interpret = not on_tpu()
-    R = xm.shape[0]
-    br, (xm, gm, rstd) = _pad_rows(R, 64, xm, gm, rstd)
-    dx, dgp = int_rmsnorm_bwd(xm, gm, x_exp, g_exp, gamma, rstd, br=br,
-                              interpret=interpret)
-    return dx[:R], jnp.sum(dgp, axis=0)
+
+    def local(xm, gm, rstd, x_exp, g_exp, gamma):
+        R = xm.shape[0]
+        br, (xm, gm, rstd) = _pad_rows(R, 64, xm, gm, rstd)
+        dx, dgp = int_rmsnorm_bwd(xm, gm, x_exp, g_exp, gamma, rstd, br=br,
+                                  interpret=interpret)
+        return dx[:R], jnp.sum(dgp, axis=(0, 1))
+
+    return sharding.over_batch(local, (xm, gm, rstd, x_exp, g_exp, gamma),
+                               (0, 0, 0, None, None, None), (0, "sum"))
 
 
 # =========================================================================
@@ -549,19 +650,24 @@ def attention_fwd(qm: jax.Array, q_exp: jax.Array,
     """
     if interpret is None:
         interpret = not on_tpu()
-    Lq, B, Sq, KV, G, hd = qm.shape
-    Sk = km.shape[2]
-    bq, sq_p, bk, sk_p, hd_p = _attn_dims(Sq, Sk, hd)
     exps = jnp.stack([jnp.reshape(q_exp, ()), jnp.reshape(k_exp, ()),
                       jnp.reshape(v_exp, ())]).astype(jnp.int32)
-    o, lse = int_attn_fwd(
-        _q_rows(qm, sq_p, hd_p), _kv_rows(km, sk_p, hd_p),
-        _kv_rows(vm, sk_p, hd_p), q_off, exps,
-        p_bits=p_bits, sq_p=sq_p, kv_heads=KV, kv_len=Sk, causal=causal,
-        window=window, sc=1.0 / float(hd) ** 0.5, bq=bq, bk=bk,
-        interpret=interpret, integer_exp=integer_exp)
-    return (_rows_q_out(o, B, KV, G, sq_p, Sq, hd),
-            lse.reshape(B, KV, G, sq_p)[..., :Sq])
+
+    def local(qm, km, vm, q_off, exps):
+        _, B, Sq, KV, G, hd = qm.shape
+        Sk = km.shape[2]
+        bq, sq_p, bk, sk_p, hd_p = _attn_dims(Sq, Sk, hd)
+        o, lse = int_attn_fwd(
+            _q_rows(qm, sq_p, hd_p), _kv_rows(km, sk_p, hd_p),
+            _kv_rows(vm, sk_p, hd_p), q_off, exps,
+            p_bits=p_bits, sq_p=sq_p, kv_heads=KV, kv_len=Sk, causal=causal,
+            window=window, sc=1.0 / float(hd) ** 0.5, bq=bq, bk=bk,
+            interpret=interpret, integer_exp=integer_exp)
+        return (_rows_q_out(o, B, KV, G, sq_p, Sq, hd),
+                lse.reshape(B, KV, G, sq_p)[..., :Sq])
+
+    return sharding.over_batch(local, (qm, km, vm, q_off, exps),
+                               (1, 1, 1, 0, None), (0, 0))
 
 
 def attention_bwd(qm: jax.Array, q_exp: jax.Array,
@@ -583,30 +689,38 @@ def attention_bwd(qm: jax.Array, q_exp: jax.Array,
     """
     if interpret is None:
         interpret = not on_tpu()
-    Lq, B, Sq, KV, G, hd = qm.shape
-    Sk = km.shape[2]
-    bq, sq_p, bk, sk_p, hd_p = _attn_dims(Sq, Sk, hd)
-    qr = _q_rows(qm, sq_p, hd_p)
-    kr = _kv_rows(km, sk_p, hd_p)
-    vr = _kv_rows(vm, sk_p, hd_p)
-    gr = _q_rows(gm, sq_p, hd_p)
-    lse_r = jnp.pad(lse, [(0, 0)] * 3 + [(0, sq_p - Sq)],
-                    constant_values=1e30).reshape(B * KV, G * sq_p, 1)
-    d_r = jnp.pad(delta.transpose(0, 2, 3, 1),
-                  [(0, 0)] * 3 + [(0, sq_p - Sq)]
-                  ).reshape(B * KV, G * sq_p, 1)
     exps = jnp.stack([jnp.reshape(q_exp, ()), jnp.reshape(k_exp, ()),
                       jnp.reshape(v_exp, ()), jnp.reshape(g_exp, ()),
                       jnp.reshape(ds_exp, ())]).astype(jnp.int32)
-    sc = 1.0 / float(hd) ** 0.5
-    common = dict(sq_p=sq_p, kv_heads=KV, kv_len=Sk, causal=causal,
-                  window=window, sc=sc, bq=bq, bk=bk, interpret=interpret,
-                  integer_exp=integer_exp)
-    dq = int_attn_bwd_dq(qr, kr, vr, gr, lse_r, d_r, q_off, exps,
-                         ds_bits=ds_bits, **common)
-    dk, dv = int_attn_bwd_dkv(qr, kr, vr, gr, lse_r, d_r, q_off, exps,
-                              p_bits=p_bits, ds_bits=ds_bits, **common)
-    dq = _rows_q_out(dq, B, KV, G, sq_p, Sq, hd)
-    dk = dk.reshape(B, KV, sk_p, hd_p)[:, :, :Sk, :hd].transpose(0, 2, 1, 3)
-    dv = dv.reshape(B, KV, sk_p, hd_p)[:, :, :Sk, :hd].transpose(0, 2, 1, 3)
-    return dq, dk, dv
+
+    def local(qm, km, vm, gm, lse, delta, q_off, exps):
+        _, B, Sq, KV, G, hd = qm.shape
+        Sk = km.shape[2]
+        bq, sq_p, bk, sk_p, hd_p = _attn_dims(Sq, Sk, hd)
+        qr = _q_rows(qm, sq_p, hd_p)
+        kr = _kv_rows(km, sk_p, hd_p)
+        vr = _kv_rows(vm, sk_p, hd_p)
+        gr = _q_rows(gm, sq_p, hd_p)
+        lse_r = jnp.pad(lse, [(0, 0)] * 3 + [(0, sq_p - Sq)],
+                        constant_values=1e30).reshape(B * KV, G * sq_p, 1)
+        d_r = jnp.pad(delta.transpose(0, 2, 3, 1),
+                      [(0, 0)] * 3 + [(0, sq_p - Sq)]
+                      ).reshape(B * KV, G * sq_p, 1)
+        sc = 1.0 / float(hd) ** 0.5
+        common = dict(sq_p=sq_p, kv_heads=KV, kv_len=Sk, causal=causal,
+                      window=window, sc=sc, bq=bq, bk=bk,
+                      interpret=interpret, integer_exp=integer_exp)
+        dq = int_attn_bwd_dq(qr, kr, vr, gr, lse_r, d_r, q_off, exps,
+                             ds_bits=ds_bits, **common)
+        dk, dv = int_attn_bwd_dkv(qr, kr, vr, gr, lse_r, d_r, q_off, exps,
+                                  p_bits=p_bits, ds_bits=ds_bits, **common)
+        dq = _rows_q_out(dq, B, KV, G, sq_p, Sq, hd)
+        dk = dk.reshape(B, KV, sk_p, hd_p)[:, :, :Sk, :hd].transpose(
+            0, 2, 1, 3)
+        dv = dv.reshape(B, KV, sk_p, hd_p)[:, :, :Sk, :hd].transpose(
+            0, 2, 1, 3)
+        return dq, dk, dv
+
+    return sharding.over_batch(
+        local, (qm, km, vm, gm, lse, delta, q_off, exps),
+        (1, 1, 1, 1, 0, 0, 0, None), (0, 0, 0))

@@ -7,7 +7,7 @@ from __future__ import annotations
 
 import jax
 
-from repro.sharding import make_mesh_compat as _mesh
+from repro.sharding import make_mesh as _mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):

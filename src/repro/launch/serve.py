@@ -12,7 +12,7 @@ import time
 import jax
 import numpy as np
 
-from repro import sharding
+from repro import sharding, utils
 from repro.configs import registry
 from repro.launch.mesh import make_host_mesh
 from repro.models import lm
@@ -34,6 +34,7 @@ def main() -> None:
     ap.add_argument("--max-seq", type=int, default=256)
     args = ap.parse_args()
 
+    utils.use_compile_cache()
     logging.basicConfig(level=logging.INFO)
     cfg = registry.get_config(args.arch)
     if args.reduced:

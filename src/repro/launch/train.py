@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import sharding
+from repro import sharding, utils
 from repro.configs import registry
 from repro.core import grad_compress
 from repro.data.pipeline import DataConfig, SyntheticLM
@@ -92,6 +92,7 @@ def main() -> None:
                          "flipped bytes (restore must fall back)")
     args = ap.parse_args()
 
+    utils.use_compile_cache()
     logging.basicConfig(level=logging.INFO)
     cfg = registry.get_config(args.arch)
     if args.reduced:

@@ -22,7 +22,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import qtensor
 
@@ -34,36 +34,11 @@ from repro.core import qtensor
 _ACTIVE_MESH: Optional[Mesh] = None
 
 
-def make_mesh_compat(shape, axes) -> Mesh:
-    """``jax.make_mesh`` across jax versions: ``axis_types`` (and the
-    ``jax.sharding.AxisType`` enum) only exist in newer releases; older ones
-    default to auto sharding, which is the behaviour we want anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
-
-
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs, manual_axes=None):
-    """``shard_map`` across jax versions.
-
-    Newer jax: ``jax.shard_map(..., check_vma=False, axis_names=manual)``.
-    Older jax: ``jax.experimental.shard_map.shard_map(..., check_rep=False,
-    auto=<mesh axes not in manual>)``.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        if manual_axes is not None:
-            kwargs["axis_names"] = set(manual_axes)
-        return jax.shard_map(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as _sm
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    if manual_axes is not None:
-        kwargs["auto"] = frozenset(mesh.axis_names) - frozenset(manual_axes)
-    return _sm(f, **kwargs)
+def make_mesh(shape, axes, devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis in auto mode: XLA propagates the
+    shardings and ``constrain`` only hints at them."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 #: axes currently under manual (shard_map) control.  While any are active,
@@ -103,6 +78,57 @@ def batch_axes(mesh: Optional[Mesh] = None):
     if mesh is None:
         return ("data",)
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
+
+
+def over_batch(fn, args, in_dims, out_dims):
+    """Run ``fn(*args)`` once per chip on its share of the batch rows.
+
+    Pallas kernels cannot be partitioned by XLA, so every kernel call runs
+    under ``shard_map`` over the active mesh's batch axes.  ``in_dims`` and
+    ``out_dims`` give, per argument and per output, the axis split across
+    chips, or None for an operand every chip holds whole (weights, scale
+    exponents).  An output marked ``"sum"`` is a per-chip partial sum over
+    the split rows (the dW product, the norm parameter gradients) and is
+    ``psum``'d over the batch axes.
+
+    Without a mesh, or inside a region that is already manual (the
+    compressed step), ``fn`` runs as is.  Where some split axis does not
+    divide by the chip count, every chip runs the whole call.  Tensor
+    parallelism over the kernels is not supported: a mesh whose ``model``
+    axis is larger than 1 raises.
+    """
+    mesh = _ACTIVE_MESH
+    if mesh is None or _MANUAL_AXES:
+        return fn(*args)
+    if mesh.shape.get("model", 1) > 1:
+        raise NotImplementedError(
+            "backend='pallas' on a mesh with a 'model' axis of size "
+            f"{mesh.shape['model']}: the Pallas kernels shard over the batch "
+            "axes only; tensor parallelism over them is not supported")
+    axes = batch_axes(mesh)
+    n = int(np.prod([mesh.shape[a] for a in axes]))
+    split = all(d is None or a.shape[d] % n == 0
+                for a, d in zip(args, in_dims))
+    single = not isinstance(out_dims, (tuple, list))
+    outs = (out_dims,) if single else tuple(out_dims)
+
+    def spec(d):
+        if d is None or d == "sum" or not split:
+            return P()
+        return P(*([None] * d), axes)
+
+    def body(*local):
+        res = fn(*local)
+        res = (res,) if single else tuple(res)
+        if split:
+            res = tuple(jax.lax.psum(r, axes) if d == "sum" else r
+                        for r, d in zip(res, outs))
+        return res[0] if single else res
+
+    out_specs = spec(out_dims) if single else tuple(spec(d) for d in outs)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=tuple(spec(d) for d in in_dims),
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
 def constrain(x: jax.Array, *spec) -> jax.Array:
@@ -324,9 +350,8 @@ def _gathered_leaf(mesh: Mesh, spec, d: int, bits: int):
         shape[d] = shape[d] * mesh.shape["data"]
         return out.reshape(shape)
 
-    return shard_map_compat(body, mesh, in_specs=(P(*entries),),
-                            out_specs=out_spec,
-                            manual_axes=set(mesh.axis_names))
+    return jax.shard_map(body, mesh=mesh, in_specs=(P(*entries),),
+                         out_specs=out_spec, check_vma=False)
 
 
 def quantized_all_gather(params: Any, mesh: Mesh, *, bits: int,

@@ -193,11 +193,11 @@ def make_compressed_train_step(loss_fn: LossFn, cfg, qcfg: QuantLike,
                 opt_cfg, grads, opt_state, params)
         return params, opt_state, residuals, {**metrics, **om}
 
-    mapped = sharding.shard_map_compat(
-        body, mesh,
+    mapped = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(P(), P(), P(), P(dp_axes), P()),
         out_specs=(P(), P(), P(), P()),
-        manual_axes=set(mesh.axis_names),
+        check_vma=False,
     )
     # state in, state out: donating (params, opt, residuals) lets XLA reuse
     # their buffers across steps (TrainConfig.donate was silently ignored

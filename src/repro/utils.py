@@ -8,6 +8,9 @@ lowering keeps the rolled loops (small HLO, working activation memory).
 """
 from __future__ import annotations
 
+import os
+import pathlib
+
 import jax
 
 ANALYSIS_UNROLL = False
@@ -63,6 +66,23 @@ def count_pallas_calls(jaxpr) -> int:
     """
     from repro.analysis import walker
     return walker.count_pallas_calls(jaxpr)
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``$JAX_COMPILATION_CACHE_DIR`` wins when set (JAX reads it itself, and
+    no other directory is set).  Otherwise the cache lives at the fixed
+    ``<checkout>/.jax_cache``: the path is part of the cache key, so a
+    directory that moved between runs would never hit.  Called at the top
+    of the launchers' ``main()``, never at import.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        # src/repro/utils.py -> the checkout's root
+        path = str(pathlib.Path(__file__).resolve().parents[2] / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 class analysis_unroll:

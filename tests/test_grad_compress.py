@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import dfx, grad_compress
@@ -29,10 +28,10 @@ def _one_pod_mesh():
 
 def _psum_mean(grads, residuals, **kw):
     mesh = _one_pod_mesh()
-    f = shard_map(
+    f = jax.shard_map(
         lambda g, r: grad_compress.compressed_psum_mean(g, r, **kw),
         mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-        check_rep=False)
+        check_vma=False)
     return f(grads, residuals)
 
 
@@ -98,7 +97,6 @@ def test_multi_pod_int32_psum_exact():
     env["PYTHONPATH"] = os.path.join(_ROOT, "src")
     code = """
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.core import grad_compress
 
@@ -108,11 +106,11 @@ def test_multi_pod_int32_psum_exact():
         # per-pod distinct gradients, stacked on the pod axis
         gs = jax.random.normal(key, (npods, 16, 16), jnp.float32)
 
-        f = shard_map(
+        f = jax.shard_map(
             lambda g, r: grad_compress.compressed_psum_mean(
                 {"w": g[0]}, None, bits=8, min_size=1),
             mesh=mesh, in_specs=(P("pod"), None), out_specs=(P(), P()),
-            check_rep=False)
+            check_vma=False)
         out, _ = f(gs, None)
 
         # reference: quantize each pod's tensor with the SHARED scale
