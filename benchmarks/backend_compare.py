@@ -25,8 +25,8 @@ and reports
   interpreter, so the byte model is what makes them interpretable),
 * a policy section (``policy``): per-scope resolved bit-widths of the
   ``int8_embed16`` mixed-precision QuantPolicy plus per-step traced
-  dispatch counts and wall-clock for uniform-int8 vs mixed on the proxy
-  fine-tune step — the mixed policy's dispatch delta is pinned at 0,
+  dispatch counts for uniform-int8 vs mixed on the proxy fine-tune step —
+  the mixed policy's dispatch delta is pinned at 0,
 * a state-plane section (``state_plane``): the collective wire-bytes model
   (f32 vs QTensor int8/int16) for the two param-sized collectives of a real
   reduced config — FSDP param all-gather and grad psum — plus resident
@@ -292,9 +292,9 @@ def norm_bwd_report(preset: str = "int16", repeats: int = 3) -> dict:
     return {"preset": preset, "layers": layers}
 
 
-def policy_report(preset: str = "int8_embed16", repeats: int = 3) -> dict:
+def policy_report(preset: str = "int8_embed16") -> dict:
     """Mixed-precision policy vs uniform base: per-scope resolved bits +
-    per-step traced dispatches and wall-clock on the proxy fine-tune task.
+    per-step traced dispatches on the proxy fine-tune task.
 
     The resolved table is what a ``QuantPolicy`` actually hands each call
     site (the per-tensor-class leaf configs); the step rows pin the
@@ -321,9 +321,8 @@ def policy_report(preset: str = "int8_embed16", repeats: int = 3) -> dict:
     ft = FtConfig(steps=1)
     rows = {}
     for name, pol in (("uniform_int8", uniform), (preset, mixed)):
-        s = step_stats("cls", pol, ft, repeats=repeats)
-        rows[name] = {"pallas_calls_per_step": s["pallas_calls"],
-                      "step_us": s["step_us"]}
+        s = step_stats("cls", pol, ft)
+        rows[name] = {"pallas_calls_per_step": s["pallas_calls"]}
     return {"preset": preset, "resolved_bits": resolved, "steps": rows,
             "dispatch_delta_vs_uniform":
                 rows[preset]["pallas_calls_per_step"]
@@ -635,7 +634,7 @@ def run(repeats: int = 3, only: str = None) -> dict:
         "moe_dispatch": moe_dispatch_report,
         "matmul_dispatch": lambda: matmul_dispatch_report(repeats=repeats),
         "norm_bwd": lambda: norm_bwd_report(repeats=repeats),
-        "policy": lambda: policy_report(repeats=repeats),
+        "policy": policy_report,
         "state_plane": state_plane_report,
         "attention": lambda: attention_report(repeats=repeats),
         "kept_ops": lambda: kept_ops_report(repeats=repeats),

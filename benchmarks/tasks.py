@@ -166,15 +166,13 @@ def finetune(task: str, qcfg: QuantConfig, ft: FtConfig = FtConfig(),
     return (metric, losses) if return_losses else (metric, None)
 
 
-def step_stats(task: str, qcfg: QuantConfig, ft: FtConfig = FtConfig(),
-               repeats: int = 3) -> Dict[str, float]:
-    """Per-step traced-dispatch count + wall-clock of one train step.
+def step_stats(task: str, qcfg: QuantConfig, ft: FtConfig = FtConfig()
+               ) -> Dict[str, int]:
+    """Per-step traced-dispatch count of one train step.
 
     ``pallas_calls`` is the number of ``pallas_call`` equations traced into
-    the jitted value-and-grad step (0 on the sim/fp32 paths) — the quantity
-    the single-dispatch limb fusion makes bit-width-independent.  ``step_us``
-    is the best-of-``repeats`` wall-clock of the compiled step; off-TPU the
-    pallas backend runs interpreted, so only relative deltas are meaningful.
+    the value-and-grad step (0 on the sim/fp32 paths) — the quantity the
+    single-dispatch limb fusion makes bit-width-independent.
     """
     from repro.utils import count_pallas_calls
 
@@ -191,16 +189,8 @@ def step_stats(task: str, qcfg: QuantConfig, ft: FtConfig = FtConfig(),
         return params, opt_state, loss
 
     k0 = jax.random.fold_in(key, 0)
-    n_calls = count_pallas_calls(
-        jax.make_jaxpr(step)(params, opt_state, batch, k0))
-    jstep = jax.jit(step)
-    jax.block_until_ready(jstep(params, opt_state, batch, k0))   # compile
-    best = float("inf")
-    for _ in range(repeats):
-        t0 = time.time()
-        jax.block_until_ready(jstep(params, opt_state, batch, k0))
-        best = min(best, time.time() - t0)
-    return {"pallas_calls": n_calls, "step_us": best * 1e6}
+    return {"pallas_calls": count_pallas_calls(
+        jax.make_jaxpr(step)(params, opt_state, batch, k0))}
 
 
 def sweep(task: str, presets: List[str], ft: FtConfig = FtConfig()

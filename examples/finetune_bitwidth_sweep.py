@@ -1,14 +1,13 @@
 """Paper reproduction driver: BERT-style bit-width sweep (Tables 1-2, Figs
 3-4) on the synthetic GLUE/SQuAD proxies.
 
-Besides the metric sweep, prints a per-step dispatch/wall-clock table on the
-pallas backend: since the single-dispatch limb fusion the traced
-``pallas_call`` count per train step is IDENTICAL across bit-widths (one
-launch per matmul direction regardless of limb count), so the 16-bit
-configuration pays no dispatch overhead over int8 — the end-to-end shape of
-the paper's headline "16-bit matches FP32" claim.  (Off-TPU the wall-clock
-deltas measure the Pallas interpreter; the dispatch counts are the
-hardware-relevant quantity.)
+Besides the metric sweep, prints a per-step dispatch table on the pallas
+backend: since the single-dispatch limb fusion the traced ``pallas_call``
+count per train step is IDENTICAL across bit-widths (one launch per matmul
+direction regardless of limb count), so the 16-bit configuration pays no
+dispatch overhead over int8 — the end-to-end shape of the paper's headline
+"16-bit matches FP32" claim.  Step times come from the chip benchmark
+(``benchmarks/chip/``), not from here.
 
     PYTHONPATH=src python examples/finetune_bitwidth_sweep.py --task span \
         --steps 200
@@ -24,9 +23,8 @@ from repro.core.qconfig import QuantConfig  # noqa: E402
 
 
 def dispatch_report(task: str, presets, ft: FtConfig) -> None:
-    """Per-step traced-dispatch + wall-clock deltas between bit-widths."""
-    print("per-step dispatch/wall-clock (pallas backend, "
-          "interpret mode off-TPU):")
+    """Per-step traced-dispatch deltas between bit-widths."""
+    print("per-step dispatch (pallas backend):")
     rows = {}
     for p in presets:
         q = dataclasses.replace(QuantConfig.preset(p), backend="pallas") \
@@ -35,10 +33,8 @@ def dispatch_report(task: str, presets, ft: FtConfig) -> None:
     base = rows[presets[-1]]          # narrowest width = dispatch baseline
     for p, s in rows.items():
         d_calls = s["pallas_calls"] - base["pallas_calls"]
-        d_us = s["step_us"] - base["step_us"]
         print(f"  {p:7s} pallas_calls/step={s['pallas_calls']:4d} "
-              f"(delta vs {presets[-1]}: {d_calls:+d})  "
-              f"step={s['step_us']:9.0f}us (delta {d_us:+9.0f}us)")
+              f"(delta vs {presets[-1]}: {d_calls:+d})")
 
 
 def main():
@@ -48,7 +44,7 @@ def main():
     ap.add_argument("--fig4", action="store_true",
                     help="activation-bit-width sweep at w8/g8 (paper Fig. 4)")
     ap.add_argument("--no-dispatch-report", action="store_true",
-                    help="skip the per-step dispatch/wall-clock table")
+                    help="skip the per-step dispatch table")
     args = ap.parse_args()
 
     ft = FtConfig(steps=args.steps)

@@ -18,7 +18,7 @@ Checked sites: ``reduce_sum`` / ``cumsum`` (bound × reduced extent) and
 ``dot_general`` (|lhs|·|rhs| × contracted extent), anywhere in the XLA
 graph.  ``pallas_call`` kernels are checked **structurally** from the call
 site instead of by descending into their Ref-based bodies: the kernel kind
-(from ``name_and_src_info``), the operand shapes, the storage bit-width and
+(its pinned ``name=``), the operand shapes, the storage bit-width and
 the limb split determine the worst case —
 
 * limb matmul kernels accumulate balanced base-2⁷ digit products
@@ -376,9 +376,8 @@ class IntervalSemantics(walker.Semantics):
 
 
 def _kernel_name(eqn) -> str:
-    info = eqn.params.get("name_and_src_info",
-                          eqn.params.get("name", ""))
-    return getattr(info, "name", None) or str(info)
+    """The ``name=`` each ``pallas_call`` in ``kernels/`` pins."""
+    return eqn.params.get("name") or ""
 
 
 def _kernel_out_interval(eqn, i: int) -> Optional[Interval]:
@@ -386,8 +385,9 @@ def _kernel_out_interval(eqn, i: int) -> Optional[Interval]:
     rng = _dtype_interval(aval.dtype)
     if rng is None:
         return None
-    name = _kernel_name(eqn)
-    if "_quant_kernel_limbs" in name and len(aval.shape) >= 1:
+    x = eqn.invars[0].aval
+    if (_kernel_name(eqn).startswith("dfx_quantize")
+            and len(aval.shape) == len(x.shape) + 1):
         # fused limb split: balanced base-2⁷ digit planes, |digit| <= 64
         return Interval(-_MATMUL_DIGIT, _MATMUL_DIGIT)
     return rng
@@ -409,7 +409,7 @@ def check_kernel_site(eqn) -> List[OverflowSite]:
                                       capacity=_INT32_MAX, accum="int32",
                                       detail=detail))
 
-    if "_bfp_matmul" in name:
+    if name.startswith("bfp_matmul"):
         # contraction extent: the axis the in-kernel dot contracts on the
         # lhs block maps to the trailing dims of the full lhs operand
         lhs = eqn.invars[0].aval
@@ -421,7 +421,7 @@ def check_kernel_site(eqn) -> List[OverflowSite]:
         K = int(lhs.shape[-2 + lc])
         add(_MATMUL_DIGIT * _MATMUL_DIGIT * K,
             f"limb-pair int32 accumulator: 64² x K={K}")
-    elif "_int_attn" in name:
+    elif name.startswith("int_attn_"):
         # fused attention kernels: every in-kernel integer dot (QK^T digit
         # pairs, P·V planes, dS·K / dS^T·Q / P^T·dO in the backward)
         # accumulates balanced digit products in int32 over the block's
@@ -438,7 +438,7 @@ def check_kernel_site(eqn) -> List[OverflowSite]:
             K = int(sa.shape[lc])
             add(_NORM_DIGIT * _MATMUL_DIGIT * K,
                 f"attention digit-pair int32 accumulator: 128·64 x K={K}")
-    elif "_ln_fwd_kernel" in name or "_rms" in name or "_ln_bwd_kernel" in name:
+    elif name.startswith(("int_layernorm_", "int_rmsnorm_")):
         xm = eqn.invars[0].aval
         bits = _storage_bits(xm.dtype)
         D = int(xm.shape[-1])

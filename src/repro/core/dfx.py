@@ -84,6 +84,7 @@ def _round_stochastic(y: jax.Array, key: jax.Array) -> jax.Array:
     return jnp.floor(y + u)
 
 
+@jax.named_scope("quantize")
 def quantize(
     x: jax.Array,
     bits: int,

@@ -76,6 +76,7 @@ def _float0(x):
     return np.zeros(np.shape(x), dtype=jax.dtypes.float0)
 
 
+@jax.named_scope("quantize")
 def _pallas_quantize(x: Array, bits: int, *, stochastic: bool = False,
                      key=None, limb_planes: bool = False) -> dfx.DfxTensor:
     """Linear fixed-point mapping via the Pallas quantize kernel.
@@ -109,7 +110,10 @@ def _quantize(x: Array, bits: int, cfg: QuantConfig, *,
     """Backend-routed per-tensor quantization (per-axis stays on sim).
 
     ``limb_planes`` only takes effect on the pallas route — the sim path
-    always returns the logical mantissa it contracts in XLA.
+    always returns the logical mantissa it contracts in XLA.  Both routes
+    run under the ``quantize`` named scope (``_pallas_quantize``,
+    ``dfx.quantize``): the max-abs exponent, the rounding noise, the kernel
+    and the limb split are told apart from their caller in a profile.
     """
     if cfg.backend == "pallas" and reduce_axes is None:
         return _pallas_quantize(x, bits, stochastic=stochastic, key=key,
@@ -268,6 +272,7 @@ def _int_blinear_fwd(x, w, key, cfg: QuantConfig):
     return y, (qx, qw, key)
 
 
+@jax.named_scope("quantize")
 def _stacked_pallas_quantize(x: Array, bits: int, *, stochastic: bool = False,
                              key=None,
                              limb_planes: bool = False) -> dfx.DfxTensor:

@@ -115,7 +115,7 @@ def _bfp_matmul_kernel(x_ref, w_ref, exp_ref, o_ref, acc_ref, *,
             acc_ref, exp_ref[0].astype(jnp.float32), lx, lw)
 
 
-def _bfp_call(xm, wm, out_exp, *, out_shape, grid, x_spec, w_spec,
+def _bfp_call(xm, wm, out_exp, *, name, out_shape, grid, x_spec, w_spec,
               out_spec, dims, interpret):
     assert xm.dtype == jnp.int8 and wm.dtype == jnp.int8, (xm.dtype, wm.dtype)
     n_k = grid[2]
@@ -135,6 +135,7 @@ def _bfp_call(xm, wm, out_exp, *, out_shape, grid, x_spec, w_spec,
             pltpu.VMEM((lx * lw,) + out_spec.block_shape, jnp.int32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name=name,
         interpret=interpret,
     )(xm, wm, jnp.reshape(out_exp, (1,)).astype(jnp.int32))
 
@@ -158,6 +159,7 @@ def bfp_matmul(
         f"shapes ({M},{K})x({K},{N}) must tile by ({bm},{bn},{bk})")
     return _bfp_call(
         xm, wm, out_exp,
+        name="bfp_matmul",
         out_shape=(M, N),
         grid=(M // bm, N // bn, K // bk),
         x_spec=pl.BlockSpec((Lx, bm, bk), lambda i, j, k: (0, i, k)),
@@ -192,6 +194,7 @@ def bfp_matmul_nt(
         f"shapes ({M},{N})x({K},{N}) must tile by ({bm},{bn},{bk})")
     return _bfp_call(
         gm, wm, out_exp,
+        name="bfp_matmul_nt",
         out_shape=(M, K),
         grid=(M // bm, K // bn, N // bk),
         x_spec=pl.BlockSpec((Lg, bm, bk), lambda i, j, k: (0, i, k)),
@@ -225,6 +228,7 @@ def bfp_matmul_tn(
         f"shapes ({M},{K})x({M},{N}) must tile by ({bm},{bn},{bk})")
     return _bfp_call(
         xm, gm, out_exp,
+        name="bfp_matmul_tn",
         out_shape=(K, N),
         grid=(K // bm, N // bn, M // bk),
         x_spec=pl.BlockSpec((Lx, bk, bm), lambda i, j, k: (0, k, i)),
@@ -269,8 +273,8 @@ def _bfp_matmul_batched_kernel(x_ref, w_ref, exp_ref, o_ref, acc_ref, *,
             acc_ref, exp_ref[e].astype(jnp.float32), lx, lw)
 
 
-def _bfp_batched_call(xm, wm, out_exp, *, out_shape, grid, x_spec, w_spec,
-                      out_spec, dims, interpret):
+def _bfp_batched_call(xm, wm, out_exp, *, name, out_shape, grid, x_spec,
+                      w_spec, out_spec, dims, interpret):
     assert xm.dtype == jnp.int8 and wm.dtype == jnp.int8, (xm.dtype, wm.dtype)
     n_k = grid[3]
     lx, lw = xm.shape[0], wm.shape[0]
@@ -290,6 +294,7 @@ def _bfp_batched_call(xm, wm, out_exp, *, out_shape, grid, x_spec, w_spec,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
+        name=name,
         interpret=interpret,
     )(xm, wm, jnp.reshape(out_exp, (-1,)).astype(jnp.int32))
 
@@ -314,6 +319,7 @@ def bfp_matmul_batched(
         f"shapes ({E},{M},{K})x({E},{K},{N}) must tile by ({bm},{bn},{bk})")
     return _bfp_batched_call(
         xm, wm, out_exp,
+        name="bfp_matmul_batched",
         out_shape=(E, M, N),
         grid=(E, M // bm, N // bn, K // bk),
         x_spec=pl.BlockSpec((Lx, 1, bm, bk), lambda e, i, j, k: (0, e, i, k)),
@@ -344,6 +350,7 @@ def bfp_matmul_batched_nt(
         f"shapes ({E},{M},{N})x({E},{K},{N}) must tile by ({bm},{bn},{bk})")
     return _bfp_batched_call(
         gm, wm, out_exp,
+        name="bfp_matmul_batched_nt",
         out_shape=(E, M, K),
         grid=(E, M // bm, K // bn, N // bk),
         x_spec=pl.BlockSpec((Lg, 1, bm, bk), lambda e, i, j, k: (0, e, i, k)),
@@ -374,6 +381,7 @@ def bfp_matmul_batched_tn(
         f"shapes ({E},{M},{K})x({E},{M},{N}) must tile by ({bm},{bn},{bk})")
     return _bfp_batched_call(
         xm, gm, out_exp,
+        name="bfp_matmul_batched_tn",
         out_shape=(E, K, N),
         grid=(E, K // bm, N // bn, M // bk),
         x_spec=pl.BlockSpec((Lx, 1, bk, bm), lambda e, i, j, k: (0, e, k, i)),

@@ -147,6 +147,7 @@ def dfx_quantize(
         out_specs=out_spec,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        name="dfx_quantize",
         interpret=interpret,
     )
     if u is None:
@@ -232,6 +233,7 @@ def dfx_quantize_grouped(
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
+        name="dfx_quantize_grouped",
         interpret=interpret,
     )
     if u is None:

@@ -276,6 +276,7 @@ def int_attn_fwd(
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="int_attn_fwd",
         interpret=interpret,
     )(qm, km, vm, q_off.astype(jnp.int32), exps.astype(jnp.int32))
 
@@ -379,6 +380,7 @@ def int_attn_bwd_dq(
         scratch_shapes=[pltpu.VMEM((bq, hd_p), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="int_attn_bwd_dq",
         interpret=interpret,
     )(qm, km, vm, gm, lse, delta,
       q_off.astype(jnp.int32), exps.astype(jnp.int32))
@@ -501,6 +503,7 @@ def int_attn_bwd_dkv(
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="int_attn_bwd_dkv",
         interpret=interpret,
     )(qm, km, vm, gm, lse, delta,
       q_off.astype(jnp.int32), exps.astype(jnp.int32))

@@ -159,6 +159,7 @@ def int_layernorm_fwd(
             jax.ShapeDtypeStruct((R, 1), jnp.float32),
         ),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        name="int_layernorm_fwd",
         interpret=interpret,
     )(xm, jnp.reshape(x_exp, (1,)).astype(jnp.int32),
       gamma.reshape(1, D), beta.reshape(1, D))
@@ -227,6 +228,7 @@ def int_layernorm_bwd(
             jax.ShapeDtypeStruct((nb, 1, D), jnp.float32),
         ),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        name="int_layernorm_bwd",
         interpret=interpret,
     )(xm, gm, jnp.reshape(x_exp, (1,)).astype(jnp.int32),
       jnp.reshape(g_exp, (1,)).astype(jnp.int32), gamma.reshape(1, D),
@@ -284,6 +286,7 @@ def int_rmsnorm_fwd(
             jax.ShapeDtypeStruct((R, 1), jnp.float32),
         ),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        name="int_rmsnorm_fwd",
         interpret=interpret,
     )(xm, jnp.reshape(x_exp, (1,)).astype(jnp.int32), gamma.reshape(1, D))
 
@@ -339,6 +342,7 @@ def int_rmsnorm_bwd(
             jax.ShapeDtypeStruct((nb, 1, D), jnp.float32),
         ),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        name="int_rmsnorm_bwd",
         interpret=interpret,
     )(xm, gm, jnp.reshape(x_exp, (1,)).astype(jnp.int32),
       jnp.reshape(g_exp, (1,)).astype(jnp.int32), gamma.reshape(1, D), rstd)

@@ -17,6 +17,11 @@ scoped mixed precision) or a ``Scope`` (a policy already descended to this
 module's path by the caller).  Each integer call site resolves its own leaf
 config at trace time — ``scope.leaf("wq")`` — so the kernels below only
 ever see plain ``QuantConfig`` leaves.
+
+Trace names: each ``apply`` helper runs under a ``jax.named_scope`` named
+by the last segment of its scope path (``ln1``, ``attn``, ``ln2``,
+``mlp``, ...), and ``scan_stack`` under its stack's name (``blocks``), so
+the quantization policy and a profile name a module alike.
 """
 from __future__ import annotations
 
@@ -50,6 +55,20 @@ def subkey(key: Optional[Array], i) -> Optional[Array]:
     return jax.random.fold_in(key, i)
 
 
+def _named_module(default: str):
+    """Run an ``apply(p, x, cfg, qcfg, ...)`` helper under the named scope of
+    its module: the last segment of ``qcfg``'s scope path, or ``default``
+    for a root scope (a bare config)."""
+    def wrap(apply):
+        @functools.wraps(apply)
+        def named(p, x, cfg, qcfg, *args, **kwargs):
+            path = ensure_scope(qcfg).path
+            with jax.named_scope(path[-1] if path else default):
+                return apply(p, x, cfg, qcfg, *args, **kwargs)
+        return named
+    return wrap
+
+
 def mlp_leaves(cfg: ArchConfig, prefix: str = "mlp") -> list:
     """Integer-layer leaf paths of one MLP (policy-resolution probe set).
     ``act`` is the non-linearity's kept-ops leaf (DESIGN.md §10)."""
@@ -72,18 +91,21 @@ def scan_stack(make_body, carry, groups, xs):
     scans its slice of ``xs`` and stacked outputs are concatenated back in
     layer order (decode caches, per-layer KV, ...).
     """
-    if len(groups) == 1:
-        return utils.scan(make_body(groups[0][2]), carry, xs)
-    outs = []
-    for (s, e, bsc) in groups:
-        carry, out = utils.scan(
-            make_body(bsc), carry,
-            jax.tree.map(lambda a, s=s, e=e: a[s:e], xs))
-        outs.append(out)
-    if all(o is None for o in outs):
-        return carry, None
-    return carry, jax.tree.map(lambda *ys: jnp.concatenate(ys, axis=0),
-                               *outs)
+    # a layer's scope path ends (..., stack, index): the scan takes the
+    # stack's name
+    with jax.named_scope(groups[0][2].path[-2]):
+        if len(groups) == 1:
+            return utils.scan(make_body(groups[0][2]), carry, xs)
+        outs = []
+        for (s, e, bsc) in groups:
+            carry, out = utils.scan(
+                make_body(bsc), carry,
+                jax.tree.map(lambda a, s=s, e=e: a[s:e], xs))
+            outs.append(out)
+        if all(o is None for o in outs):
+            return carry, None
+        return carry, jax.tree.map(lambda *ys: jnp.concatenate(ys, axis=0),
+                                   *outs)
 
 
 # =========================================================================
@@ -207,6 +229,7 @@ def attention_init(key, cfg: ArchConfig) -> Params:
     return p
 
 
+@_named_module("attn")
 def attention_apply(
     p: Params, x: Array, cfg: ArchConfig, qcfg: QuantLike,
     key: Optional[Array],
@@ -310,6 +333,7 @@ def mlp_init(key, cfg: ArchConfig, d_ff: Optional[int] = None) -> Params:
             "w2": _init(ks[1], (F, D)), "b2": jnp.zeros((D,))}
 
 
+@_named_module("mlp")
 def mlp_apply(p: Params, x: Array, cfg: ArchConfig, qcfg: QuantLike,
               key: Optional[Array]) -> Array:
     sc = ensure_scope(qcfg)
@@ -346,6 +370,7 @@ def moe_init(key, cfg: ArchConfig) -> Params:
     return p
 
 
+@_named_module("moe")
 def moe_apply(p: Params, x: Array, cfg: ArchConfig, qcfg: QuantLike,
               key: Optional[Array]) -> Tuple[Array, Array]:
     """Returns (out, aux_loss). x: (B, S, D).
@@ -450,6 +475,7 @@ def norm_init(cfg: ArchConfig) -> Params:
     return {"g": jnp.ones((cfg.d_model,))}
 
 
+@_named_module("norm")
 def norm_apply(p: Params, x: Array, cfg: ArchConfig, qcfg: QuantLike,
                key: Optional[Array]) -> Array:
     sc = ensure_scope(qcfg)

@@ -31,6 +31,10 @@ Params = Dict[str, Any]
 # Block scopes carry the negative-index alias (blocks.-1 = last layer); a
 # policy resolving differently across block indices splits the encoder scan
 # into runs of identically-resolved layers (see models/lm.py).
+#
+# Trace scopes (``jax.named_scope``, read from a profile): embed (with
+# embed_ln) / patch_embed, blocks/{ln1, attn, ln2, mlp} (models/blocks.py),
+# final_ln, span_head / cls_head, loss.
 
 _ENC_BLOCK_LEAVES = (["ln1", "ln2"]
                      + ["attn." + n
@@ -118,44 +122,57 @@ def bert_apply(params: Params, tokens: Array, cfg: ArchConfig,
                pool: bool = True) -> Array:
     B, S = tokens.shape
     sc = ensure_scope(qcfg)
-    x = int_ops.int_embedding(params["embed"], tokens, subkey(key, -1),
-                              sc.leaf("embed"))
-    x = x + params["pos_embed"][None, :S]
-    if segment is not None:
-        x = x + int_ops.int_embedding(params["type_embed"], segment,
-                                      subkey(key, -2), sc.leaf("type_embed"))
-    x = blocks.norm_apply(params["embed_ln"], x, cfg, sc.child("embed_ln"),
-                          subkey(key, -3))
+    with jax.named_scope("embed"):
+        x = int_ops.int_embedding(params["embed"], tokens, subkey(key, -1),
+                                  sc.leaf("embed"))
+        x = x + params["pos_embed"][None, :S]
+        if segment is not None:
+            x = x + int_ops.int_embedding(params["type_embed"], segment,
+                                          subkey(key, -2),
+                                          sc.leaf("type_embed"))
+        x = blocks.norm_apply(params["embed_ln"], x, cfg,
+                              sc.child("embed_ln"), subkey(key, -3))
     x = _encoder(params, x, cfg, sc, key)
     if pool:
-        # BERT pooler: dense + tanh on the CLS token; the tanh is a kept op
-        cls = int_ops.int_linear(x[:, 0], params["pooler"],
-                                 params["pooler_b"], subkey(key, -5),
-                                 sc.leaf("pooler"))
-        cls = int_ops.int_activation(cls, sc.child("pooler").leaf("act"),
-                                     "tanh")
-        return int_ops.int_linear(cls, params["head"], params["head_b"],
-                                  subkey(key, -4), sc.leaf("head"))
-    return int_ops.int_linear(x, params["span"], None, subkey(key, -4),
-                              sc.leaf("span_head"))
+        with jax.named_scope("cls_head"):
+            # BERT pooler: dense + tanh on the CLS token; the tanh is a
+            # kept op
+            cls = int_ops.int_linear(x[:, 0], params["pooler"],
+                                     params["pooler_b"], subkey(key, -5),
+                                     sc.leaf("pooler"))
+            cls = int_ops.int_activation(
+                cls, sc.child("pooler").leaf("act"), "tanh")
+            return int_ops.int_linear(cls, params["head"], params["head_b"],
+                                      subkey(key, -4), sc.leaf("head"))
+    with jax.named_scope("span_head"):
+        return int_ops.int_linear(x, params["span"], None, subkey(key, -4),
+                                  sc.leaf("span_head"))
 
 
 def bert_cls_loss(params, batch, cfg, qcfg, key):
     logits = bert_apply(params, batch["tokens"], cfg, qcfg, key,
                         segment=batch.get("segment"))
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-    ll = jnp.take_along_axis(logp, batch["labels"][:, None], axis=-1)
-    return -jnp.mean(ll), {"logits": logits}
+    return _cls_loss(logits, batch["labels"])
+
+
+def _cls_loss(logits, labels):
+    with jax.named_scope("loss"):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+        ll = jnp.take_along_axis(logp, labels[:, None], axis=-1)
+        return -jnp.mean(ll), {"logits": logits}
 
 
 def bert_span_loss(params, batch, cfg, qcfg, key):
     """SQuAD-style span prediction: logits over positions for start/end."""
     out = bert_apply(params, batch["tokens"], cfg, qcfg, key, pool=False)
-    start_lp = jax.nn.log_softmax(out[..., 0].astype(jnp.float32), axis=-1)
-    end_lp = jax.nn.log_softmax(out[..., 1].astype(jnp.float32), axis=-1)
-    ls = jnp.take_along_axis(start_lp, batch["span_start"][:, None], 1)
-    le = jnp.take_along_axis(end_lp, batch["span_end"][:, None], 1)
-    return -0.5 * jnp.mean(ls + le), {"start_lp": start_lp, "end_lp": end_lp}
+    with jax.named_scope("loss"):
+        start_lp = jax.nn.log_softmax(out[..., 0].astype(jnp.float32),
+                                      axis=-1)
+        end_lp = jax.nn.log_softmax(out[..., 1].astype(jnp.float32), axis=-1)
+        ls = jnp.take_along_axis(start_lp, batch["span_start"][:, None], 1)
+        le = jnp.take_along_axis(end_lp, batch["span_end"][:, None], 1)
+        return (-0.5 * jnp.mean(ls + le),
+                {"start_lp": start_lp, "end_lp": end_lp})
 
 
 # ===================== ViT =====================
@@ -180,21 +197,21 @@ def vit_init(key, cfg: ArchConfig, num_classes: int = 10,
 def vit_apply(params: Params, images: Array, cfg: ArchConfig,
               qcfg: QuantLike, key, patch: int = 16) -> Array:
     sc = ensure_scope(qcfg)
-    x = int_ops.int_patch_embed(images, params["patch_w"], params["patch_b"],
-                                subkey(key, -1), sc.leaf("patch_embed"),
-                                patch)
-    B = x.shape[0]
-    cls = jnp.broadcast_to(params["cls"], (B, 1, cfg.d_model))
-    x = jnp.concatenate([cls, x], axis=1) + params["pos_embed"][None]
+    with jax.named_scope("patch_embed"):
+        x = int_ops.int_patch_embed(images, params["patch_w"],
+                                    params["patch_b"], subkey(key, -1),
+                                    sc.leaf("patch_embed"), patch)
+        B = x.shape[0]
+        cls = jnp.broadcast_to(params["cls"], (B, 1, cfg.d_model))
+        x = jnp.concatenate([cls, x], axis=1) + params["pos_embed"][None]
     x = _encoder(params, x, cfg, sc, key)
     x = blocks.norm_apply(params["final_ln"], x, cfg, sc.child("final_ln"),
                           subkey(key, -2))
-    return int_ops.int_linear(x[:, 0], params["head"], params["head_b"],
-                              subkey(key, -3), sc.leaf("head"))
+    with jax.named_scope("cls_head"):
+        return int_ops.int_linear(x[:, 0], params["head"], params["head_b"],
+                                  subkey(key, -3), sc.leaf("head"))
 
 
 def vit_cls_loss(params, batch, cfg, qcfg, key, patch: int = 16):
     logits = vit_apply(params, batch["images"], cfg, qcfg, key, patch=patch)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-    ll = jnp.take_along_axis(logp, batch["labels"][:, None], axis=-1)
-    return -jnp.mean(ll), {"logits": logits}
+    return _cls_loss(logits, batch["labels"])

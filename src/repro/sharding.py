@@ -354,6 +354,7 @@ def _gathered_leaf(mesh: Mesh, spec, d: int, bits: int):
                          out_specs=out_spec, check_vma=False)
 
 
+@jax.named_scope("gather")
 def quantized_all_gather(params: Any, mesh: Mesh, *, bits: int,
                          pspecs: Any = None) -> Any:
     """FSDP param materialization that moves int8 instead of FP32.

@@ -102,9 +102,11 @@ def _check_tree(name: str, tree: Any, tdef) -> None:
             "with optimizer.init(params, cfg)")
 
 
+@jax.named_scope("optimizer")
 def update(cfg: OptimizerConfig, grads: Any, state: OptState, params: Any
            ) -> Tuple[Any, OptState, dict]:
-    """Returns (new_params, new_state, metrics)."""
+    """Returns (new_params, new_state, metrics), traced under the
+    ``optimizer`` named scope for every train step that calls it."""
     tdef = jax.tree.structure(params)
     _check_tree("gradient", grads, tdef)
     _check_tree("moment (m)", state.m, tdef)

@@ -107,8 +107,9 @@ def make_train_step(loss_fn: LossFn, cfg, qcfg: QuantLike,
             qparams = sharding.quantized_all_gather(
                 params, mesh, bits=gb, pspecs=param_specs)
         elif gb:
-            qparams = jax.tree.map(
-                lambda p: qtensor.fake_quant_ste(p, gb), params)
+            with jax.named_scope("gather"):
+                qparams = jax.tree.map(
+                    lambda p: qtensor.fake_quant_ste(p, gb), params)
         else:
             qparams = params
         grads, metrics = grads_fn(qparams, batch, key)

@@ -20,6 +20,7 @@ from repro.analysis import budget, count_eqns, count_pallas_calls, rules, \
 from repro.core import dfx, int_ops, qpolicy, qtensor
 from repro.core.qconfig import QuantConfig
 from repro.core.qpolicy import QuantPolicy, ScopeRule
+from repro.kernels import ops
 
 KEY = jax.random.PRNGKey(0)
 
@@ -303,6 +304,23 @@ def test_ql006_conv_bwd_digit_split_is_clean():
         lambda w: jnp.sum(int_ops.int_conv1d_depthwise(x, w, None, cfg) ** 2)
     ))(w)
     assert not rules.check_accum_budget(jx)
+
+
+def test_ql006_kernel_sites_are_found_by_their_pinned_names():
+    """The structural kernel checks key on each ``pallas_call``'s pinned
+    ``name=``: a limb matmul contracting past K = 524 287 (64² · K over
+    int32) is a site; the same kernel at K = 256 is not."""
+    S = jax.ShapeDtypeStruct
+
+    def sites(K):
+        return budget.check_jaxpr(jax.make_jaxpr(
+            lambda x, w: ops.dfx_matmul_tiled(x, jnp.int32(0), 16,
+                                              w, jnp.int32(0), 16))(
+            S((3, 128, K), jnp.int8), S((3, K, 128), jnp.int8)))
+
+    (site,) = sites(1 << 20)
+    assert site.where == "bfp_matmul"
+    assert sites(256) == []
 
 
 # =========================================================================
