@@ -13,8 +13,10 @@ in ONE ``pallas_call``:
   HBM **once** instead of once per limb pair (up to 3× before);
 * the limb-pair loop is a statically unrolled in-kernel loop over plane
   slices, one int8×int8→int32 MXU contraction per pair per K step;
-* each pair accumulates bit-exactly into its own int32 VMEM scratch plane
-  across the K grid dimension;
+* when the contraction spans several grid steps, each pair accumulates
+  bit-exactly into its own int32 VMEM scratch plane across them; when one
+  block holds the whole contraction, the pair products go straight to the
+  combine (integer sums are exact, so the bits are the same);
 * the epilogue combines the partials in f32 with their ``2^(7(jx+jw))``
   limb shifts and the fused dequant scale ``2^out_exp`` (the single scale
   multiply of the paper's Fig. 2) — in the exact summation order of the
@@ -42,8 +44,12 @@ experts AND all limb pairs), and the scalar ``out_exp`` operand becomes a
 per-expert **vector** ``(E,)`` — the epilogue of grid slice ``e`` scales by
 ``2**out_exp[e]``.
 
-MXU alignment: block shapes are multiples of 128 in the N/K lanes and 8 in
-sublanes; defaults (128, 128, 128) match the MXU natively.
+Blocks: ``(bm, bn, bk)`` is the output tile and the contracted block in
+every layout; lane dims take multiples of 128 and sublane dims multiples of
+8.  The callers size them to the shapes and limb count
+(``kernels/ops.py::_pick_blocks``), and each call asks Mosaic for the
+scoped VMEM its blocks need (``_vmem_limit``).  The (128, 128, 128)
+defaults are the smallest MXU-native tiles.
 """
 from __future__ import annotations
 
@@ -59,8 +65,37 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.dfx_quant import LIMB_BITS  # noqa: E402
 
 
-def _combine_partials(acc_ref, exp_f32, lx: int, lw: int):
-    """Ordered f32 combine of the per-pair int32 partials.
+#: scoped VMEM a Mosaic kernel gets when it asks for no limit.
+_SCOPED_VMEM_DEFAULT = 16 * 1024 * 1024
+
+
+def matmul_vmem_bytes(bm: int, bn: int, bk: int, lx: int = 1, lw: int = 1,
+                      n_k: int = 1) -> int:
+    """VMEM bytes one grid step of the fused limb matmul keeps resident.
+
+    ``bm``×``bn`` is the output tile and ``bk`` the contracted block, in
+    every layout.  Counted: the double-buffered int8 operand blocks (all
+    ``lx``/``lw`` planes of a tile arrive together), the double-buffered f32
+    output block, one int32 pair product and the f32 running sum of the
+    combine, and — only when the contraction spans ``n_k > 1`` grid steps —
+    one int32 accumulator plane per limb pair.
+    """
+    ws = (2 * (lx * bm * bk + lw * bk * bn)     # int8 operand stacks
+          + 2 * bm * bn * 4                     # f32 output block
+          + 2 * bm * bn * 4)                    # pair product, running sum
+    if n_k > 1:
+        ws += lx * lw * bm * bn * 4             # per-pair accumulators
+    return ws
+
+
+def _vmem_limit(working_set: int) -> int:
+    """Scoped VMEM to ask Mosaic for: the modelled working set plus half
+    again for the compiler's own temporaries, never below the default."""
+    return max(_SCOPED_VMEM_DEFAULT, working_set + working_set // 2)
+
+
+def _combine_partials(partial, exp_f32, lx: int, lw: int):
+    """Ordered f32 combine of the per-pair int32 partials ``partial(jx, jw)``.
 
     Iterates x-limbs outer / w-limbs inner and sums sequentially — the exact
     order of the per-pair dispatch loop this kernel replaced.  The scale is
@@ -76,68 +111,96 @@ def _combine_partials(acc_ref, exp_f32, lx: int, lw: int):
     out = None
     for jx in range(lx):
         for jw in range(lw):
-            part = (acc_ref[jx * lw + jw].astype(jnp.float32) * scale0
+            part = (partial(jx, jw).astype(jnp.float32) * scale0
                     ) * (2.0 ** (LIMB_BITS * (jx + jw)))
             out = part if out is None else out + part
     return out
 
 
-def _bfp_matmul_kernel(x_ref, w_ref, exp_ref, o_ref, acc_ref, *,
-                       n_k: int, dims, lx: int, lw: int):
-    """One (i, j, k) grid step: acc[q] += contract(x_blk[jx], w_blk[jw]).
+def _bfp_matmul_kernel(x_ref, w_ref, exp_ref, o_ref, *acc_ref,
+                       n_k: int, dims, lx: int, lw: int, batched: bool):
+    """One grid step: every limb pair of one output tile.
 
     ``x_ref``/``w_ref`` hold the FULL limb stacks of the operand tiles
-    (shape ``(lx, bm, bk)`` / ``(lw, bk, bn)``); the limb-pair loop is
-    statically unrolled, one int32 MXU contraction per pair into its own
-    accumulator plane.  ``dims`` is the in-kernel dot_general contraction:
-    (1,0) for NN, (1,1) for NT, (0,0) for TN.
+    (``(lx, ·, ·)`` / ``(lw, ·, ·)``); the limb-pair loop is statically
+    unrolled, one int8×int8→int32 MXU contraction per pair.  ``dims`` is the
+    in-kernel dot_general contraction: (1,0) for NN, (1,1) for NT, (0,0) for
+    TN.  With the whole contraction in one block (``n_k == 1``) the pair
+    products feed the combine directly; otherwise each accumulates into its
+    own int32 scratch plane across the last grid axis and the combine runs
+    on the last step.  Integer accumulation is exact, so both give the same
+    bits.  Batched grids lead with the expert axis, whose exponent is
+    ``exp_ref[e]``.
     """
-    k = pl.program_id(2)
+    lc, rc = dims
+    e = pl.program_id(0) if batched else 0
+
+    def product(jx, jw):
+        return jax.lax.dot_general(
+            x_ref[jx], w_ref[jw], (((lc,), (rc,)), ((), ())),
+            preferred_element_type=jnp.int32)
+
+    if n_k == 1:
+        o_ref[...] = _combine_partials(
+            product, exp_ref[e].astype(jnp.float32), lx, lw)
+        return
+
+    acc_ref, = acc_ref
+    k = pl.program_id(3 if batched else 2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # int8 limb mantissas -> int32 MXU accumulate, bit-exact per pair.
-    lc, rc = dims
     for jx in range(lx):
         for jw in range(lw):
-            acc_ref[jx * lw + jw] += jax.lax.dot_general(
-                x_ref[jx], w_ref[jw],
-                (((lc,), (rc,)), ((), ())),
-                preferred_element_type=jnp.int32,
-            )
+            acc_ref[jx * lw + jw] += product(jx, jw)
 
     @pl.when(k == n_k - 1)
     def _epilogue():
         # Cross-limb combine + fused non-linear inverse mapping (Fig. 2).
         o_ref[...] = _combine_partials(
-            acc_ref, exp_ref[0].astype(jnp.float32), lx, lw)
+            lambda jx, jw: acc_ref[jx * lw + jw],
+            exp_ref[e].astype(jnp.float32), lx, lw)
 
 
-def _bfp_call(xm, wm, out_exp, *, name, out_shape, grid, x_spec, w_spec,
-              out_spec, dims, interpret):
+def _bfp_call(xm, wm, out_exp, *, name, out_shape, blocks, grid, x_spec,
+              w_spec, out_spec, dims, interpret):
+    """One ``pallas_call`` over all limb pairs; a 4-D grid is batched
+    (expert axis first) and takes an ``(E,)`` exponent vector."""
     assert xm.dtype == jnp.int8 and wm.dtype == jnp.int8, (xm.dtype, wm.dtype)
-    n_k = grid[2]
+    batched = len(grid) == 4
+    n_k = grid[-1]
     lx, lw = xm.shape[0], wm.shape[0]
-    return pl.pallas_call(
+    bm, bn, bk = blocks
+    scratch = ([pltpu.VMEM((lx * lw, bm, bn), jnp.int32)] if n_k > 1
+               else [])
+    vmem_limit = _vmem_limit(matmul_vmem_bytes(bm, bn, bk, lx, lw, n_k))
+    out = pl.pallas_call(
         functools.partial(_bfp_matmul_kernel, n_k=n_k, dims=dims,
-                          lx=lx, lw=lw),
+                          lx=lx, lw=lw, batched=batched),
         grid=grid,
         in_specs=[
             x_spec,
             w_spec,
-            pl.BlockSpec(memory_space=pltpu.SMEM),   # scalar exp
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # exp scalar / vector
         ],
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((lx * lw,) + out_spec.block_shape, jnp.int32)],
+        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel",) * (len(grid) - 1)
+            + ("arbitrary",),
+            vmem_limit_bytes=vmem_limit),
         name=name,
         interpret=interpret,
-    )(xm, wm, jnp.reshape(out_exp, (1,)).astype(jnp.int32))
+    )(xm, wm, jnp.reshape(out_exp, (-1,)).astype(jnp.int32))
+    if vmem_limit > _SCOPED_VMEM_DEFAULT and dims == (0, 0):
+        # A dW (TN) product feeds the layer scan's stacked gradient: XLA
+        # fuses that dynamic-update-slice into the kernel and compiles the
+        # fusion under the default scoped VMEM, not this call's limit.
+        out = jax.lax.optimization_barrier(out)
+    return out
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
@@ -161,6 +224,7 @@ def bfp_matmul(
         xm, wm, out_exp,
         name="bfp_matmul",
         out_shape=(M, N),
+        blocks=(bm, bn, bk),
         grid=(M // bm, N // bn, K // bk),
         x_spec=pl.BlockSpec((Lx, bm, bk), lambda i, j, k: (0, i, k)),
         w_spec=pl.BlockSpec((Lw, bk, bn), lambda i, j, k: (0, k, j)),
@@ -196,6 +260,7 @@ def bfp_matmul_nt(
         gm, wm, out_exp,
         name="bfp_matmul_nt",
         out_shape=(M, K),
+        blocks=(bm, bn, bk),
         grid=(M // bm, K // bn, N // bk),
         x_spec=pl.BlockSpec((Lg, bm, bk), lambda i, j, k: (0, i, k)),
         w_spec=pl.BlockSpec((Lw, bn, bk), lambda i, j, k: (0, j, k)),
@@ -230,6 +295,7 @@ def bfp_matmul_tn(
         xm, gm, out_exp,
         name="bfp_matmul_tn",
         out_shape=(K, N),
+        blocks=(bm, bn, bk),
         grid=(K // bm, N // bn, M // bk),
         x_spec=pl.BlockSpec((Lx, bk, bm), lambda i, j, k: (0, k, i)),
         w_spec=pl.BlockSpec((Lg, bk, bn), lambda i, j, k: (0, k, j)),
@@ -242,62 +308,6 @@ def bfp_matmul_tn(
 # =========================================================================
 # Batched (expert-axis) variants — grid: (E, i, j, k), exp: (E,) vector
 # =========================================================================
-
-def _bfp_matmul_batched_kernel(x_ref, w_ref, exp_ref, o_ref, acc_ref, *,
-                               n_k: int, dims, lx: int, lw: int):
-    """One (e, i, j, k) grid step over the full limb stacks of expert ``e``.
-
-    Identical limb-pair contraction to the unbatched kernel on the trailing
-    two block dims; the epilogue scale is the *per-expert* exponent
-    ``exp_ref[e]``.
-    """
-    e = pl.program_id(0)
-    k = pl.program_id(3)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    lc, rc = dims
-    for jx in range(lx):
-        for jw in range(lw):
-            acc_ref[jx * lw + jw] += jax.lax.dot_general(
-                x_ref[jx, 0], w_ref[jw, 0],
-                (((lc,), (rc,)), ((), ())),
-                preferred_element_type=jnp.int32,
-            )
-
-    @pl.when(k == n_k - 1)
-    def _epilogue():
-        o_ref[0] = _combine_partials(
-            acc_ref, exp_ref[e].astype(jnp.float32), lx, lw)
-
-
-def _bfp_batched_call(xm, wm, out_exp, *, name, out_shape, grid, x_spec,
-                      w_spec, out_spec, dims, interpret):
-    assert xm.dtype == jnp.int8 and wm.dtype == jnp.int8, (xm.dtype, wm.dtype)
-    n_k = grid[3]
-    lx, lw = xm.shape[0], wm.shape[0]
-    return pl.pallas_call(
-        functools.partial(_bfp_matmul_batched_kernel, n_k=n_k, dims=dims,
-                          lx=lx, lw=lw),
-        grid=grid,
-        in_specs=[
-            x_spec,
-            w_spec,
-            pl.BlockSpec(memory_space=pltpu.SMEM),   # (E,) exp vector
-        ],
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((lx * lw,) + out_spec.block_shape[1:], jnp.int32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        name=name,
-        interpret=interpret,
-    )(xm, wm, jnp.reshape(out_exp, (-1,)).astype(jnp.int32))
-
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def bfp_matmul_batched(
@@ -317,14 +327,17 @@ def bfp_matmul_batched(
     assert out_exp.shape == (E,), (out_exp.shape, E)
     assert M % bm == 0 and N % bn == 0 and K % bk == 0, (
         f"shapes ({E},{M},{K})x({E},{K},{N}) must tile by ({bm},{bn},{bk})")
-    return _bfp_batched_call(
+    return _bfp_call(
         xm, wm, out_exp,
         name="bfp_matmul_batched",
         out_shape=(E, M, N),
+        blocks=(bm, bn, bk),
         grid=(E, M // bm, N // bn, K // bk),
-        x_spec=pl.BlockSpec((Lx, 1, bm, bk), lambda e, i, j, k: (0, e, i, k)),
-        w_spec=pl.BlockSpec((Lw, 1, bk, bn), lambda e, i, j, k: (0, e, k, j)),
-        out_spec=pl.BlockSpec((1, bm, bn), lambda e, i, j, k: (e, i, j)),
+        x_spec=pl.BlockSpec((Lx, None, bm, bk),
+                            lambda e, i, j, k: (0, e, i, k)),
+        w_spec=pl.BlockSpec((Lw, None, bk, bn),
+                            lambda e, i, j, k: (0, e, k, j)),
+        out_spec=pl.BlockSpec((None, bm, bn), lambda e, i, j, k: (e, i, j)),
         dims=(1, 0),
         interpret=interpret,
     )
@@ -348,14 +361,17 @@ def bfp_matmul_batched_nt(
     assert out_exp.shape == (E,), (out_exp.shape, E)
     assert M % bm == 0 and K % bn == 0 and N % bk == 0, (
         f"shapes ({E},{M},{N})x({E},{K},{N}) must tile by ({bm},{bn},{bk})")
-    return _bfp_batched_call(
+    return _bfp_call(
         gm, wm, out_exp,
         name="bfp_matmul_batched_nt",
         out_shape=(E, M, K),
+        blocks=(bm, bn, bk),
         grid=(E, M // bm, K // bn, N // bk),
-        x_spec=pl.BlockSpec((Lg, 1, bm, bk), lambda e, i, j, k: (0, e, i, k)),
-        w_spec=pl.BlockSpec((Lw, 1, bn, bk), lambda e, i, j, k: (0, e, j, k)),
-        out_spec=pl.BlockSpec((1, bm, bn), lambda e, i, j, k: (e, i, j)),
+        x_spec=pl.BlockSpec((Lg, None, bm, bk),
+                            lambda e, i, j, k: (0, e, i, k)),
+        w_spec=pl.BlockSpec((Lw, None, bn, bk),
+                            lambda e, i, j, k: (0, e, j, k)),
+        out_spec=pl.BlockSpec((None, bm, bn), lambda e, i, j, k: (e, i, j)),
         dims=(1, 1),
         interpret=interpret,
     )
@@ -379,14 +395,17 @@ def bfp_matmul_batched_tn(
     assert out_exp.shape == (E,), (out_exp.shape, E)
     assert K % bm == 0 and N % bn == 0 and M % bk == 0, (
         f"shapes ({E},{M},{K})x({E},{M},{N}) must tile by ({bm},{bn},{bk})")
-    return _bfp_batched_call(
+    return _bfp_call(
         xm, gm, out_exp,
         name="bfp_matmul_batched_tn",
         out_shape=(E, K, N),
+        blocks=(bm, bn, bk),
         grid=(E, K // bm, N // bn, M // bk),
-        x_spec=pl.BlockSpec((Lx, 1, bk, bm), lambda e, i, j, k: (0, e, k, i)),
-        w_spec=pl.BlockSpec((Lg, 1, bk, bn), lambda e, i, j, k: (0, e, k, j)),
-        out_spec=pl.BlockSpec((1, bm, bn), lambda e, i, j, k: (e, i, j)),
+        x_spec=pl.BlockSpec((Lx, None, bk, bm),
+                            lambda e, i, j, k: (0, e, k, i)),
+        w_spec=pl.BlockSpec((Lg, None, bk, bn),
+                            lambda e, i, j, k: (0, e, k, j)),
+        out_spec=pl.BlockSpec((None, bm, bn), lambda e, i, j, k: (e, i, j)),
         dims=(0, 0),
         interpret=interpret,
     )

@@ -68,6 +68,7 @@ padded rows (a zero-padded lse would make it blow up instead).
 from __future__ import annotations
 
 import functools
+import itertools
 
 import jax
 import jax.numpy as jnp
@@ -76,7 +77,7 @@ from repro import sharding
 from repro.kernels.bfp_matmul import (bfp_matmul, bfp_matmul_batched,
                                       bfp_matmul_batched_nt,
                                       bfp_matmul_batched_tn, bfp_matmul_nt,
-                                      bfp_matmul_tn)
+                                      bfp_matmul_tn, matmul_vmem_bytes)
 from repro.kernels.dfx_quant import (LIMB_BITS as _LIMB_BITS, _out_dtype,
                                      dfx_quantize, dfx_quantize_grouped,
                                      n_limbs)
@@ -91,11 +92,23 @@ _LANE = 128
 #: VPU sublane width: the second-to-last block dimension's multiple.
 _SUBLANE = 8
 
-#: VMEM budget for one matmul or quantize grid step (blocks double-buffered,
-#: plus the matmul's per-limb-pair int32 accumulator scratch) — half of the
-#: 16 MiB scoped VMEM a v5e kernel may use, so the compiler keeps headroom
-#: for in-kernel temporaries.
+#: VMEM budget for one quantize grid step (blocks double-buffered) — half of
+#: the 16 MiB scoped VMEM a v5e kernel gets by default, so the compiler
+#: keeps headroom for in-kernel temporaries.
 _VMEM_BUDGET = 8 * 1024 * 1024
+
+#: VMEM budget for one limb-matmul grid step (``matmul_vmem_bytes``).  The
+#: kernel asks Mosaic for a scoped limit derived from the blocks it gets
+#: (``bfp_matmul._vmem_limit``), so this only has to stay well inside
+#: the 128 MiB of VMEM a v5e core has.
+_MATMUL_VMEM_BUDGET = 48 * 1024 * 1024
+
+#: most int8 multiply-accumulates one limb-matmul grid step may do, over all
+#: its limb pairs.  Mosaic unrolls a step's dots and combine in full, so its
+#: compile time grows with this; on a v5e, 3x3-limb steps of 2e9-8e9 MACs
+#: ran within 3% of each other and steps of ~1e10 ran 15-20% slower
+#: (the block sweep in PERF.md).
+_STEP_MACS = 1 << 32
 
 #: the quantize kernel's row block never exceeds this many rows.
 _QUANT_ROWS = 256
@@ -151,53 +164,55 @@ def _round_up_multiple(x: int, mult: int) -> int:
     return max(r, mult)
 
 
-def matmul_vmem_bytes(bm: int, bn: int, bk: int, lx: int = 1,
-                      lw: int = 1, contracted_sublane: bool = False) -> int:
-    """VMEM bytes one grid step of the fused limb matmul keeps resident.
+def _padded(n: int, sublane: bool) -> int:
+    """Extent a matmul operand dim is zero-padded to: a multiple of 128, or
+    of 8 for a sublane dim shorter than 128 (small row counts, decode)."""
+    if sublane and n < _LANE:
+        return _round_up_multiple(n, _SUBLANE)
+    return _round_up_multiple(n, _LANE)
 
-    Double-buffered int8 operand blocks (all ``lx``/``lw`` planes of a tile
-    arrive together), one int32 accumulator plane per limb pair, and the
-    double-buffered f32 output block.
 
-    ``contracted_sublane=False`` (NN/NT): ``bm`` is the OUTPUT tile's
-    sublane dim — the operand stacks, the accumulator planes, and the output
-    block all scale with it.  ``contracted_sublane=True`` (TN): ``bm`` is
-    the CONTRACTED block (the output tile stays ``(_LANE, _LANE)``) — both
-    operand stacks scale with it but the accumulator scratch and output
-    block do not.
-    """
-    if contracted_sublane:
-        return (2 * (lx * bm * _LANE + lw * bm * bn)  # int8 operand stacks
-                + lx * lw * _LANE * bn * 4            # fixed-size acc planes
-                + 2 * _LANE * bn * 4)                 # fixed f32 out block
-    return (2 * (lx * bm * bk + lw * bk * bn)        # int8 operand stacks
-            + lx * lw * bm * bn * 4                  # per-pair accumulators
-            + 2 * bm * bn * 4)                       # f32 output block
+def _tiles(extent: int, sublane: bool) -> list[int]:
+    """Block sizes that tile a padded extent exactly, smallest first: the
+    extent itself below 128, else every multiple of 128 that divides it —
+    and, on a sublane dim, 8 to 64 rows for budgets 128 rows overflow."""
+    if extent < _LANE:
+        return [extent]
+    small = [8, 16, 32, 64] if sublane else []
+    return small + [t for t in range(_LANE, extent + 1, _LANE)
+                    if extent % t == 0]
 
 
 def _pick_blocks(M: int, N: int, K: int, lx: int = 1, lw: int = 1,
-                 budget: int = _VMEM_BUDGET, contracted_sublane: bool = False):
-    """Block shapes for an (M, K) @ (K, N) tiling with ``lx``×``lw`` limbs.
+                 budget: int = _MATMUL_VMEM_BUDGET,
+                 contract_rows: bool = False):
+    """Blocks ``(bm, bn, bk)`` of an (M, N) output contracting K, with
+    ``lx``×``lw`` limb planes.
 
-    The lane dimensions (N and K here) must be full 128-lane tiles — inputs
-    smaller than 128 are padded up to one tile.  Only the sublane dimension
-    (M) may shrink, in multiples of 8, to avoid padding small row counts all
-    the way to 128 — and it also shrinks when the limb-plane stacks plus the
-    per-pair accumulator scratch would overflow the VMEM budget (the 1-limb
-    working set is ~9× smaller than the 3×3-limb one; blocks that fit the
-    former can overflow the latter).
-
-    ``contracted_sublane=True`` is the TN callers' interpretation: the
-    shrinkable first dimension they receive is the CONTRACTED block (the
-    output tile stays full-lane), so the budget model must not scale the
-    accumulator scratch with it — see ``matmul_vmem_bytes``.
+    Every block divides its dim's padded extent (``_padded``), so no operand
+    is padded further than to the next 128 rows or lanes — or 8 rows under
+    128.  The operand rows are the sublane dim: the output rows ``M`` in NN
+    and NT, the contraction ``K`` in TN (``contract_rows=True``), whose
+    output tile is all lanes.  Among the blocks whose working set
+    (``matmul_vmem_bytes``, limb planes and accumulators counted) fits the
+    budget and whose step does at most ``_STEP_MACS`` limb-pair MACs, the
+    chooser takes the whole contraction in one grid step if any block
+    allows it, then the largest output tile, then the widest.
     """
-    bm = _LANE if M >= _LANE else _round_up_multiple(M, _SUBLANE)
-    bn = bk = _LANE
-    while bm > _SUBLANE and matmul_vmem_bytes(
-            bm, bn, bk, lx, lw, contracted_sublane) > budget:
-        bm = _round_up_multiple(bm // 2, _SUBLANE)
-    return bm, bn, bk
+    Mp, Np = _padded(M, not contract_rows), _padded(N, False)
+    Kp = _padded(K, contract_rows)
+    bms, bns = _tiles(Mp, not contract_rows), _tiles(Np, False)
+    bks = _tiles(Kp, contract_rows)
+
+    def fits(b):
+        bm, bn, bk = b
+        return (matmul_vmem_bytes(bm, bn, bk, lx, lw, Kp // bk) <= budget
+                and lx * lw * bm * bn * bk <= _STEP_MACS)
+
+    fitting = [b for b in itertools.product(bms, bns, bks) if fits(b)]
+    if not fitting:
+        return bms[0], bns[0], bks[0]
+    return max(fitting, key=lambda b: (b[2] == Kp, b[0] * b[1], b[1], b[2]))
 
 
 def _pad_last2(a: jax.Array, r: int, c: int) -> jax.Array:
@@ -294,10 +309,9 @@ def dfx_matmul_tiled_tn(
         _, M, K = xm.shape
         _, _, N = gm.shape
         # out is (K, N): K and N ride the lanes of the output tile; the
-        # contracted M axis is the sublane-flexible one here (so the budget
-        # model must hold the accumulator/output tiles fixed — _pick_blocks)
-        bk, bm, bn = _pick_blocks(M, K, N, xm.shape[0], gm.shape[0],
-                                  contracted_sublane=True)
+        # contracted M axis is the operands' sublane dim
+        bm, bn, bk = _pick_blocks(K, N, M, xm.shape[0], gm.shape[0],
+                                  contract_rows=True)
         xm, gm = _pad_last2(xm, bk, bm), _pad_last2(gm, bk, bn)
         out = bfp_matmul_tn(xm, gm, out_exp, bm=bm, bn=bn, bk=bk,
                             interpret=interpret)
@@ -388,8 +402,8 @@ def dfx_matmul_tiled_batched_tn(
     def local(xm, gm, out_exp):
         _, _, M, K = xm.shape
         _, _, _, N = gm.shape
-        bk, bm, bn = _pick_blocks(M, K, N, xm.shape[0], gm.shape[0],
-                                  contracted_sublane=True)
+        bm, bn, bk = _pick_blocks(K, N, M, xm.shape[0], gm.shape[0],
+                                  contract_rows=True)
         xm, gm = _pad_last2(xm, bk, bm), _pad_last2(gm, bk, bn)
         out = bfp_matmul_batched_tn(xm, gm, out_exp, bm=bm, bn=bn, bk=bk,
                                     interpret=interpret)

@@ -119,6 +119,36 @@ def test_layernorm_kernel(R, D, bits):
                                rtol=1e-6, atol=0)
 
 
+@pytest.mark.parametrize("E", [1, 3])
+def test_bfp_matmul_batched_k_grid(E):
+    """Batched NN/NT/TN kernels whose contraction spans two grid steps (the
+    int32 scratch path, k on the fourth grid axis), 2 x 3 limb planes and a
+    per-expert exponent, bit-equal to the per-pair limb loop."""
+    from repro.kernels.bfp_matmul import (bfp_matmul_batched,
+                                          bfp_matmul_batched_nt,
+                                          bfp_matmul_batched_tn)
+    exps = jnp.arange(E, dtype=jnp.int32) - 2
+
+    def planes(key, n, shape):
+        return jax.random.randint(jax.random.fold_in(KEY, key), (n, E) + shape,
+                                  -64, 64, jnp.int32).astype(jnp.int8)
+
+    a, b = planes(1, 2, (128, 256)), planes(2, 3, (256, 128))
+    bt, at = planes(3, 3, (128, 256)), planes(4, 2, (256, 128))
+    cases = [
+        (bfp_matmul_batched(a, b, exps, bk=128, interpret=True),
+         a, b, (((2,), (1,)), ((0,), (0,)))),
+        (bfp_matmul_batched_nt(a, bt, exps, bk=128, interpret=True),
+         a, bt, (((2,), (2,)), ((0,), (0,)))),
+        (bfp_matmul_batched_tn(at, b, exps, bk=128, interpret=True),
+         at, b, (((1,), (1,)), ((0,), (0,)))),
+    ]
+    for y, xm, wm, dn in cases:
+        loop = ref.limb_loop_matmul_ref(xm, wm, exps.reshape(E, 1, 1),
+                                        dimension_numbers=dn)
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(loop))
+
+
 @pytest.mark.parametrize("E", [1, 4])
 @pytest.mark.parametrize("M,K,N", [(128, 128, 128), (96, 200, 72)])
 def test_bfp_matmul_batched_exact(E, M, K, N):
@@ -220,68 +250,109 @@ def test_round_up_multiple():
     assert ops._round_up_multiple(129, 128) == 256
 
 
+def _assert_tiles(blocks, M, N, K, contract_rows=False):
+    """Each block divides its dim's padded extent: the next multiple of 128,
+    or of 8 for an operand row dim under 128 rows (no extra padding)."""
+    bm, bn, bk = blocks
+    rows, rb = (K, bk) if contract_rows else (M, bm)
+    for n, b, sublane in ((M, bm, not contract_rows), (N, bn, False),
+                          (K, bk, contract_rows)):
+        ext = ops._padded(n, sublane)
+        assert ext == (ops._round_up_multiple(n, 8) if sublane and n < 128
+                       else ops._round_up_multiple(n, 128)), (n, ext)
+        assert ext % b == 0, (n, b, ext)
+        assert b % (8 if sublane else 128) == 0 or b == ext, (n, b)
+    if rows < 128:          # small row counts keep their 8-rounding
+        assert rb == ops._round_up_multiple(rows, 8)
+
+
 @pytest.mark.parametrize("M,N,K", [(1, 1, 1), (4, 7, 100), (8, 128, 128),
                                    (100, 37, 60), (128, 256, 512),
                                    (200, 130, 70)])
 def test_pick_blocks_small_and_ragged(M, N, K):
-    """Lane dims (N, K) always use full 128-lane tiles; the sublane dim (M)
-    shrinks in 8-multiples for small row counts (regression: bn used to be
-    computed from a misnamed round-up that always returned 128 — true, but
-    by accident — and small-M inputs were padded all the way to 128 rows)."""
-    bm, bn, bk = ops._pick_blocks(M, N, K)
-    assert bn == 128 and bk == 128
-    assert bm % 8 == 0 and 8 <= bm <= 128
-    if M < 128:
-        assert bm == ops._round_up_multiple(M, 8)   # no over-padding
-    else:
-        assert bm == 128
-    # the padded operands must tile exactly
-    assert ops._round_up_multiple(M, bm) % bm == 0
+    """Lane dims take multiples of 128 and the operand row dim multiples of
+    8, each dividing its padded extent exactly, so no operand is padded
+    further than before; small row counts are padded to the next 8 rows,
+    not to 128 (decode keeps its row block), and the working set fits the
+    budget — with the rows on the output (NN, NT) or contracted (TN)."""
+    for contract_rows in (False, True):
+        blocks = ops._pick_blocks(M, N, K, 3, 3, contract_rows=contract_rows)
+        _assert_tiles(blocks, M, N, K, contract_rows)
+        n_k = ops._padded(K, contract_rows) // blocks[2]
+        assert ops.matmul_vmem_bytes(*blocks, 3, 3, n_k) \
+            <= ops._MATMUL_VMEM_BUDGET
 
 
 @pytest.mark.parametrize("lx,lw", [(1, 1), (2, 2), (3, 3), (3, 1)])
 def test_pick_blocks_vmem_budget(lx, lw):
-    """The block chooser accounts for the limb-plane count and the per-pair
-    accumulator scratch: at any limb count the chosen blocks fit the VMEM
-    budget, and under a tight injected budget the 3×3-limb working set
-    shrinks the sublane dim where the 1-limb one would not (regression: the
-    old chooser sized blocks for the 1-limb case only)."""
-    bm, bn, bk = ops._pick_blocks(4096, 4096, 4096, lx, lw)
-    assert bn == 128 and bk == 128 and bm % 8 == 0
-    assert ops.matmul_vmem_bytes(bm, bn, bk, lx, lw) <= ops._VMEM_BUDGET
-    # the default budget has headroom even for 3x3 limbs at full tiles
-    if (lx, lw) == (3, 3):
-        assert bm == 128
-    # tight budget: fits 1-limb at bm=128 but NOT 3x3-limb
-    tight = ops.matmul_vmem_bytes(128, 128, 128, 1, 1)
-    b1 = ops._pick_blocks(4096, 4096, 4096, 1, 1, budget=tight)
-    b9 = ops._pick_blocks(4096, 4096, 4096, 3, 3, budget=tight)
-    assert b1 == (128, 128, 128)
-    assert b9[0] < 128 and b9[0] % 8 == 0          # sublane dim shrank
-    assert ops.matmul_vmem_bytes(*b9, 3, 3) <= tight or b9[0] == 8
-    # TN interpretation: the shrinkable first dim is the CONTRACTED block —
-    # the accumulator/output tiles stay (128, 128), so the budget model must
-    # not scale them with it (regression: the chooser used the NN model and
-    # returned blocks whose real TN working set exceeded the budget)
-    bt = ops._pick_blocks(4096, 4096, 4096, lx, lw, budget=tight,
-                          contracted_sublane=True)
-    assert ops.matmul_vmem_bytes(bt[0], bt[1], bt[2], lx, lw,
-                                 contracted_sublane=True) <= tight \
-        or bt[0] == 8
-    fixed = lx * lw * 128 * 128 * 4 + 2 * 128 * 128 * 4
-    assert ops.matmul_vmem_bytes(8, 128, 128, lx, lw,
-                                 contracted_sublane=True) >= fixed
+    """The chooser counts limb planes and per-pair accumulators: at any limb
+    count the chosen blocks fit the budget, and under a tight budget the
+    3×3-limb blocks are no larger than the 1-limb ones (regression: the old
+    chooser sized blocks for the 1-limb case only) — in both the output-row
+    and the contracted-row (TN) layout."""
+    for contract_rows in (False, True):
+        blocks = ops._pick_blocks(4096, 4096, 4096, lx, lw,
+                                  contract_rows=contract_rows)
+        _assert_tiles(blocks, 4096, 4096, 4096, contract_rows)
+        assert ops.matmul_vmem_bytes(*blocks, lx, lw, 4096 // blocks[2]) \
+            <= ops._MATMUL_VMEM_BUDGET
+        # tight: the 1-limb working set of a 128^3 k-grid step
+        tight = ops.matmul_vmem_bytes(128, 128, 128, 1, 1, 2)
+        b1 = ops._pick_blocks(4096, 4096, 4096, 1, 1, budget=tight,
+                              contract_rows=contract_rows)
+        bl = ops._pick_blocks(4096, 4096, 4096, lx, lw, budget=tight,
+                              contract_rows=contract_rows)
+        assert ops.matmul_vmem_bytes(*b1, 1, 1, 4096 // b1[2]) <= tight
+        fits = ops.matmul_vmem_bytes(*bl, lx, lw, 4096 // bl[2]) <= tight
+        smallest = min(bl) == 8     # nothing fits: the smallest tiles
+        assert fits or smallest, (bl, lx, lw)
+        assert bl[0] * bl[1] * bl[2] <= b1[0] * b1[1] * b1[2]
+        if (lx, lw) == (3, 3):
+            assert bl[0] * bl[1] * bl[2] < b1[0] * b1[1] * b1[2]
 
 
 def test_matmul_vmem_bytes_model():
-    """9 limb pairs cost ~9x the accumulator scratch and 3x the operand
-    stacks of the 1-limb case — the quantities the chooser must see."""
-    one = ops.matmul_vmem_bytes(128, 128, 128, 1, 1)
-    nine = ops.matmul_vmem_bytes(128, 128, 128, 3, 3)
-    assert nine > 3 * one
+    """9 limb pairs cost 9x the accumulator scratch and 3x the operand
+    stacks of the 1-limb case; a contraction in one grid step (n_k = 1)
+    keeps no accumulators at all."""
+    one = ops.matmul_vmem_bytes(128, 128, 128, 1, 1, n_k=2)
+    nine = ops.matmul_vmem_bytes(128, 128, 128, 3, 3, n_k=2)
+    assert nine - one == (2 * 4 * 128 * 128       # 4 more operand planes
+                          + 8 * 128 * 128 * 4)    # 8 more accumulators
     assert nine == (2 * (3 + 3) * 128 * 128        # int8 operand stacks x2
-                    + 9 * 128 * 128 * 4            # per-pair int32 acc
-                    + 2 * 128 * 128 * 4)           # f32 out block x2
+                    + 2 * 128 * 128 * 4            # f32 out block x2
+                    + 2 * 128 * 128 * 4            # pair product, sum
+                    + 9 * 128 * 128 * 4)           # per-pair int32 acc
+    assert ops.matmul_vmem_bytes(128, 128, 128, 3, 3, n_k=1) \
+        == nine - 9 * 128 * 128 * 4
+
+
+#: bert-base (B=32, S=384) and vit-base (64 x 197) rows; the linears of one
+#: encoder layer as (K, N): q/k/v/o, the FFN up- and down-projection.
+PAPER_ROWS = (32 * 384, 64 * 197)
+PAPER_LINEARS = {"qkvo": (768, 768), "w1": (768, 3072), "w2": (3072, 768)}
+
+
+@pytest.mark.parametrize("rows", PAPER_ROWS, ids=["bert", "vit"])
+@pytest.mark.parametrize("linear", sorted(PAPER_LINEARS))
+def test_pick_blocks_paper_shapes_full_contraction(rows, linear):
+    """At every bert and vit int16 product the forward (NN) and dX (NT)
+    blocks hold the whole contraction in one grid step, the budget allows
+    it, and the blocks tile the padded extents exactly (vit's 12608 rows
+    pad to 12672 = 128 x 99, not to a multiple of a larger block)."""
+    K, N = PAPER_LINEARS[linear]
+    for M, Nout, Kc in ((rows, N, K), (rows, K, N)):        # NN, NT
+        bm, bn, bk = ops._pick_blocks(M, Nout, Kc, 3, 3)
+        _assert_tiles((bm, bn, bk), M, Nout, Kc)
+        assert bk == Kc
+        assert ops.matmul_vmem_bytes(bm, bn, bk, 3, 3, 1) \
+            <= ops._MATMUL_VMEM_BUDGET
+        assert ops._padded(M, True) == ops._round_up_multiple(M, 128)
+    tn = ops._pick_blocks(K, N, rows, 3, 3, contract_rows=True)  # dW
+    _assert_tiles(tn, K, N, rows, contract_rows=True)
+    assert ops.matmul_vmem_bytes(
+        *tn, 3, 3, ops._padded(rows, True) // tn[2]) \
+        <= ops._MATMUL_VMEM_BUDGET
 
 
 @pytest.mark.parametrize("M,N,K", [(3, 5, 2), (100, 37, 60), (130, 128, 250)])

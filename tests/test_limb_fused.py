@@ -196,6 +196,52 @@ def test_fused_bit_exact_vs_limb_loop_batched(bits):
                                       err_msg=f"{name} b={bits}")
 
 
+#: rows a multiple of vit's 197 positions; every dim spans several 128
+#: blocks, so the 128^3 tiling runs a k-grid over int32 scratch planes
+BLOCK_M, BLOCK_K, BLOCK_N = 2 * 197, 384, 256
+
+
+@pytest.mark.parametrize("bits", [(8, 8), (12, 8), (16, 16)],
+                         ids=["1x1", "2x1", "3x3"])
+def test_chosen_blocks_bit_identical_to_128_blocks(bits, monkeypatch):
+    """NN, NT and TN with the chooser's blocks (the whole contraction in one
+    grid step, pair products straight into the combine) give exactly the
+    bits of forced 128^3 blocks: integer accumulation is exact at any
+    tiling and the f32 combine runs in the same order."""
+    ba, bb = bits
+    la, lb = LIMBS[ba], LIMBS[bb]
+    M, K, N = BLOCK_M, BLOCK_K, BLOCK_N
+    qx = _quant(20, (M, K), ba, 2.0)
+    qw = _quant(21, (K, N), bb, 0.3)
+    qg = _quant(22, (M, N), ba)
+    qgb = _quant(22, (M, N), bb)
+    picks = {"nn": (ops._pick_blocks(M, N, K, la, lb), K),
+             "nt": (ops._pick_blocks(M, K, N, la, lb), N),
+             "tn": (ops._pick_blocks(K, N, M, la, lb, contract_rows=True),
+                    ops._padded(M, True))}
+    for name, (blocks, contraction) in picks.items():
+        assert blocks[2] == contraction, (name, blocks)   # one k step
+
+    def run():
+        return {
+            "nn": ops.dfx_matmul_tiled(qx.m, qx.exp, ba, qw.m, qw.exp, bb,
+                                       interpret=True),
+            "nt": ops.dfx_matmul_tiled_nt(qg.m, qg.exp, ba, qw.m, qw.exp,
+                                          bb, interpret=True),
+            "tn": ops.dfx_matmul_tiled_tn(qx.m, qx.exp, ba, qgb.m, qgb.exp,
+                                          bb, interpret=True),
+        }
+
+    chosen = run()
+    monkeypatch.setattr(ops, "_pick_blocks",
+                        lambda *a, **k: (128, 128, 128))
+    forced = run()
+    for name in chosen:
+        np.testing.assert_array_equal(np.asarray(chosen[name]),
+                                      np.asarray(forced[name]),
+                                      err_msg=f"{name} {bits}")
+
+
 # -------------------------------------------------------------------------
 # fused quantize: limb planes straight from the kernel
 # -------------------------------------------------------------------------

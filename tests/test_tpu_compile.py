@@ -76,27 +76,41 @@ def test_quantize_compiles(one_chip, stochastic):
     assert "tpu_custom_call" in _compile(fn, *args)
 
 
+#: vit-base rows: 64 images x 197 positions (padded to 12672 = 128 x 99)
+M_VIT = 64 * 197
+
+#: the linears of one encoder layer as (K, N): q/k/v/o, FFN up and down
+LINEARS = {"qkvo": (D, D), "w1": (D, F), "w2": (F, D)}
+
+
 @pytest.mark.parametrize("bits", [(12, 8), (16, 16)], ids=["2x1", "3x3"])
 @pytest.mark.parametrize("direction", ["nn", "nt", "tn"])
 def test_matmul_compiles(one_chip, direction, bits):
-    """The FFN up-projection's three products at M=12288, K=768, N=3072."""
+    """Each product of the linears at the blocks the chooser gives them:
+    w16 (3x3 limbs) at every bert (M=12288) and vit (M=12608) linear, the
+    2x1 preset at the FFN up-projection — so Mosaic checks the tiling and
+    the scoped VMEM each call asks for."""
     ba, bb = bits
     la, lb = n_limbs(ba), n_limbs(bb)
     i8 = functools.partial(_s, dtype=jnp.int8, where=one_chip)
     e = _s((), jnp.int32, one_chip)
-    if direction == "nn":
-        fn = lambda a, ea, b, eb: ops.dfx_matmul_tiled(      # noqa: E731
-            a, ea, ba, b, eb, bb, interpret=False)
-        a, b = i8((la, M, D)), i8((lb, D, F))
-    elif direction == "nt":
-        fn = lambda a, ea, b, eb: ops.dfx_matmul_tiled_nt(   # noqa: E731
-            a, ea, ba, b, eb, bb, interpret=False)
-        a, b = i8((la, M, F)), i8((lb, D, F))
-    else:
-        fn = lambda a, ea, b, eb: ops.dfx_matmul_tiled_tn(   # noqa: E731
-            a, ea, ba, b, eb, bb, interpret=False)
-        a, b = i8((la, M, D)), i8((lb, M, F))
-    assert "tpu_custom_call" in _compile(fn, a, e, b, e)
+    shapes = ([(rows, k, n) for rows in (M, M_VIT)
+               for k, n in LINEARS.values()] if la == lb == 3
+              else [(M, D, F)])
+    for rows, k, n in shapes:
+        if direction == "nn":
+            fn = lambda a, ea, b, eb: ops.dfx_matmul_tiled(      # noqa: E731
+                a, ea, ba, b, eb, bb, interpret=False)
+            a, b = i8((la, rows, k)), i8((lb, k, n))
+        elif direction == "nt":
+            fn = lambda a, ea, b, eb: ops.dfx_matmul_tiled_nt(   # noqa: E731
+                a, ea, ba, b, eb, bb, interpret=False)
+            a, b = i8((la, rows, n)), i8((lb, k, n))
+        else:
+            fn = lambda a, ea, b, eb: ops.dfx_matmul_tiled_tn(   # noqa: E731
+                a, ea, ba, b, eb, bb, interpret=False)
+            a, b = i8((la, rows, k)), i8((lb, rows, n))
+        assert "tpu_custom_call" in _compile(fn, a, e, b, e), (rows, k, n)
 
 
 def test_layernorm_fwd_bwd_compile(one_chip):
@@ -139,18 +153,13 @@ def test_attention_fwd_bwd_compile(one_chip):
                                          off)
 
 
-def test_data_parallel_step_compiles(topo, monkeypatch):
-    """One int8 bert-base span fine-tuning step (depth cut to 2 layers),
-    data-parallel over the four chips of the described 2x2 mesh, through
-    the trainer's own entry points.  XLA refuses to partition a Mosaic
-    kernel, so this fails unless every kernel call is shard_mapped."""
-    # the described chips are not this process's backend: steer the kernel
-    # wrappers off interpret mode for this trace only
-    monkeypatch.setattr(ops, "on_tpu", lambda: True)
-    cfg = dataclasses.replace(BERT_BASE, n_layers=2)
-    qcfg = dataclasses.replace(QuantConfig.int8(), backend="pallas")
-    mesh = sharding.make_mesh((4, 1), ("data", "model"),
-                              devices=topo.devices)
+def _bert_step_text(devices, qcfg, n_layers):
+    """Compiled text of one bert-base span fine-tuning step (depth cut to
+    ``n_layers``) at B=32, S=384, data-parallel over ``devices``, through
+    the trainer's own entry points."""
+    cfg = dataclasses.replace(BERT_BASE, n_layers=n_layers)
+    mesh = sharding.make_mesh((len(devices), 1), ("data", "model"),
+                              devices=devices)
     init = functools.partial(pm.bert_init, cfg=cfg, span_head=True)
     shapes = jax.eval_shape(init, jax.random.PRNGKey(0))
     pspecs = sharding.param_pspecs(shapes, mesh, fsdp=False)
@@ -178,8 +187,32 @@ def test_data_parallel_step_compiles(topo, monkeypatch):
         mesh, pspecs)
     sharding.set_mesh(mesh)
     try:
-        text = step.lower(placed(shapes, pspecs), placed(opt_shapes, opt_specs),
+        return step.lower(placed(shapes, pspecs),
+                          placed(opt_shapes, opt_specs),
                           batch, key).compile().as_text()
     finally:
         sharding.set_mesh(None)
-    assert "tpu_custom_call" in text
+
+
+def test_data_parallel_step_compiles(topo, monkeypatch):
+    """One int8 bert-base span fine-tuning step (depth cut to 2 layers),
+    data-parallel over the four chips of the described 2x2 mesh, through
+    the trainer's own entry points.  XLA refuses to partition a Mosaic
+    kernel, so this fails unless every kernel call is shard_mapped."""
+    # the described chips are not this process's backend: steer the kernel
+    # wrappers off interpret mode for this trace only
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    qcfg = dataclasses.replace(QuantConfig.int8(), backend="pallas")
+    assert "tpu_custom_call" in _bert_step_text(topo.devices, qcfg, 2)
+
+
+def test_int16_step_compiles_on_one_chip(topo, monkeypatch):
+    """The int16 (3x3-limb) bert-base step on one chip, 2 layers: the limb
+    matmuls run at their full-size blocks inside the layer scan, whose
+    backward writes each dW straight into the stacked gradient.  XLA
+    compiles a kernel fused with that write under the default 16 MiB
+    scoped VMEM, not the kernel's own limit, so this fails unless the dW
+    kernel is kept out of the fusion."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    qcfg = dataclasses.replace(QuantConfig.int16(), backend="pallas")
+    assert "bfp_matmul_tn" in _bert_step_text(topo.devices[:1], qcfg, 2)
