@@ -88,7 +88,18 @@ def current_counts() -> dict:
         dec = lambda q, k, v: int_ops.int_attention(
             q, k, v, jnp.asarray(7), None, cfg, cfg, True, None)
 
+        # grouped (sorted-rows) linear of an expert share: 2 groups of one
+        # 8-row tile each, then an unused tile
+        xg = jax.random.normal(key, (24, 32))
+        wg = jax.random.normal(jax.random.fold_in(key, 6), (2, 32, 16)) * 0.1
+        off = jnp.asarray([0, 8, 16], jnp.int32)
+        gl = lambda x, w: int_ops.int_grouped_linear(x, w, off, None, cfg, 8)
+        gl_l = lambda x, w: jnp.sum(gl(x, w) ** 2)
+
         counts[preset] = {
+            "grouped_linear_fwd": count(gl, xg, wg),
+            "grouped_linear_fwd_bwd": count(
+                jax.grad(gl_l, argnums=(0, 1)), xg, wg),
             "linear_fwd": count(lin, x, w),
             "linear_fwd_bwd": count(jax.grad(lin_l, argnums=(0, 1)), x, w),
             "batched_linear_fwd": count(bl, xb, wb),

@@ -411,8 +411,11 @@ def check_kernel_site(eqn) -> List[OverflowSite]:
 
     if name.startswith("bfp_matmul"):
         # contraction extent: the axis the in-kernel dot contracts on the
-        # lhs block maps to the trailing dims of the full lhs operand
-        lhs = eqn.invars[0].aval
+        # lhs block maps to the trailing dims of the full lhs operand (the
+        # first after any scalar-prefetch operands: the grouped kernels'
+        # group offsets); a grouped TN contracts a group's rows, at most
+        # all of them
+        lhs = eqn.invars[eqn.params["grid_mapping"].num_index_operands].aval
         lc = 1
         for site in walker.iter_eqns(eqn.params["jaxpr"]):
             if site.prim == "dot_general":

@@ -26,6 +26,7 @@ _MODULES = {
     "qwen2-moe-a2.7b": "qwen2_moe_a2p7b",
     "mamba2-370m": "mamba2_370m",
     "whisper-large-v3": "whisper_large_v3",
+    "mellum2-12b-a2.5b": "mellum2_12b_a2p5b",
 }
 
 ARCH_IDS = tuple(_MODULES)
@@ -34,6 +35,7 @@ ARCH_IDS = tuple(_MODULES)
 FSDP_ARCHS = frozenset({
     "mistral-nemo-12b", "mistral-large-123b", "llava-next-mistral-7b",
     "mixtral-8x7b", "qwen2-moe-a2.7b", "zamba2-2.7b", "whisper-large-v3",
+    "mellum2-12b-a2.5b",
 })
 
 

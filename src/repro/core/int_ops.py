@@ -345,6 +345,150 @@ int_batched_linear.defvjp(_int_blinear_fwd, _int_blinear_bwd)
 
 
 # =========================================================================
+# Grouped (sorted-rows) linear — an expert share's SwiGLU products, drop-free
+# =========================================================================
+#
+# Rows are (token, choice) pairs sorted by expert, group ``g`` in
+# ``[offsets[g], offsets[g+1])``, each group padded with zero rows to a
+# multiple of the row tile ``tm`` and at least one tile long; rows past
+# ``offsets[G]`` are zero.  Each group's rows take their own DFX exponent,
+# as ``int_batched_linear`` gives each expert's slab its own.
+
+def row_groups(offsets: Array, rows: int) -> Array:
+    """(rows,) group of every row: ``G`` past the used rows."""
+    r = jnp.arange(rows, dtype=jnp.int32)
+    return jnp.sum(r[:, None] >= offsets[None, 1:], axis=1).astype(jnp.int32)
+
+
+@jax.named_scope("quantize")
+def _grouped_quantize(x: Array, gid: Array, groups: int, bits: int,
+                      cfg: QuantConfig, *, stochastic: bool = False,
+                      key=None) -> dfx.DfxTensor:
+    """Per-group quantization of sorted rows: ``exp`` is (G,).
+
+    The rows are scaled by their group's ``2**-exp`` in XLA (exact: a power
+    of two) and rounded at exponent 0, on pallas by the quantize kernel as
+    limb planes, on sim in XLA — the same bits as quantizing each group's
+    rows at its own exponent.
+    """
+    x = x.astype(jnp.float32)
+    absmax = jax.ops.segment_max(jnp.max(jnp.abs(x), axis=-1), gid,
+                                 num_segments=groups + 1)[:groups]
+    _, e = jnp.frexp(absmax)
+    exp = (jnp.where(absmax > 0, e, 0) - (bits - 1)).astype(jnp.int32)
+    row_exp = jnp.concatenate([exp, jnp.zeros((1,), jnp.int32)])[gid]
+    xs = x * jnp.exp2(-row_exp.astype(jnp.float32))[:, None]
+    u = None
+    if stochastic:
+        if key is None:
+            raise ValueError("stochastic rounding requires a PRNG key")
+        u = jax.random.uniform(key, xs.shape, dtype=jnp.float32)
+    if cfg.backend == "pallas":
+        m = kops.quantize_pallas(xs, jnp.int32(0), bits, u=u,
+                                 limb_planes=True)
+    else:
+        y = jnp.floor(xs + u) if stochastic else jnp.round(xs)
+        lim = float(2 ** (bits - 1) - 1)
+        m = jnp.clip(y, -lim, lim).astype(dfx.storage_dtype(bits))
+    return dfx.DfxTensor(m=m, exp=exp)
+
+
+def _grouped_sim_dot(a: dfx.DfxTensor, b: dfx.DfxTensor, gid: Array,
+                     dims) -> Array:
+    """Sim path: each group's rows times its expert's matrix, at the sum of
+    the two exponents; rows of other groups masked out."""
+    out = 0.0
+    for g in range(b.m.shape[0]):
+        prod = jax.lax.dot_general(
+            a.m.astype(jnp.float32), b.m[g].astype(jnp.float32),
+            (dims, ((), ())), precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        scale = jnp.exp2((a.exp[g] + jnp.reshape(b.exp, (-1,))[g]).astype(
+            jnp.float32))
+        out = out + jnp.where((gid == g)[:, None], prod * scale, 0.0)
+    return out
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def int_grouped_linear(x: Array, w: Array, offsets: Array, key,
+                       cfg: QuantConfig, tm: int) -> Array:
+    """``y[r] = x[r] @ w[g]`` for every row ``r`` of group ``g``, integer
+    forward and backward, one DFX exponent per group.
+
+    x: (M, K) rows sorted by group, w: (G, K, N), offsets: (G+1,) int32
+    (see above; ``tm`` the row tile each group is padded to).  Returns
+    (M, N), zero past ``offsets[G]``.
+    """
+    y, _ = _int_glinear_fwd(x, w, offsets, key, cfg, tm)
+    return y
+
+
+def _int_glinear_fwd(x, w, offsets, key, cfg: QuantConfig, tm: int):
+    G = w.shape[0]
+    gid = row_groups(offsets, x.shape[0])
+    if not cfg.enabled:
+        y = _grouped_sim_dot(dfx.DfxTensor(x, jnp.zeros((G,), jnp.int32)),
+                             dfx.DfxTensor(w, jnp.zeros((G,), jnp.int32)),
+                             gid, ((1,), (0,)))
+        return y, (x, w, offsets, key)
+    kf = None
+    if cfg.stochastic_fwd and key is not None:
+        key, kf = jax.random.split(key)
+    qx = _grouped_quantize(x, gid, G, cfg.act_bits, cfg,
+                           stochastic=kf is not None, key=kf)
+    if cfg.backend == "pallas":
+        qw = _stacked_pallas_quantize(w, cfg.weight_bits, limb_planes=True)
+        y = kops.dfx_matmul_grouped(qx.m, qx.exp, cfg.act_bits, qw.m,
+                                    qw.exp, cfg.weight_bits, offsets, tm)
+    else:
+        qw = dfx.quantize(w, cfg.weight_bits, reduce_axes=(1, 2))
+        y = _grouped_sim_dot(qx, qw, gid, ((1,), (0,)))
+    return y, (qx, qw, offsets, key)
+
+
+def _int_glinear_bwd(cfg: QuantConfig, tm: int, res, g):
+    if not cfg.enabled:
+        x, w, offsets, key = res
+        G = w.shape[0]
+        gid = row_groups(offsets, x.shape[0])
+        z = jnp.zeros((G,), jnp.int32)
+        dx = _grouped_sim_dot(dfx.DfxTensor(g, z), dfx.DfxTensor(w, z), gid,
+                              ((1,), (1,)))
+        dw = jnp.stack([jnp.einsum("mk,mn->kn",
+                                   jnp.where((gid == e)[:, None], x, 0.0), g)
+                        for e in range(G)])
+        return dx, dw, _float0(offsets), _float0(key) if key is not None \
+            else None
+    qx, qw, offsets, key = res
+    G = qw.m.shape[-3]
+    gid = row_groups(offsets, g.shape[0])
+    stoch = cfg.stochastic_grad and key is not None
+    qg = _grouped_quantize(g, gid, G, cfg.grad_bits, cfg, stochastic=stoch,
+                           key=key)
+    if cfg.backend == "pallas":
+        dx = kops.dfx_matmul_grouped_nt(qg.m, qg.exp, cfg.grad_bits,
+                                        qw.m, qw.exp, cfg.weight_bits,
+                                        offsets, tm)
+        dw = kops.dfx_matmul_grouped_tn(qx.m, qx.exp, cfg.act_bits,
+                                        qg.m, qg.exp, cfg.grad_bits,
+                                        offsets, tm)
+    else:
+        dx = _grouped_sim_dot(qg, qw, gid, ((1,), (1,)))
+        dw = jnp.stack([
+            jax.lax.dot_general(
+                jnp.where((gid == e)[:, None], qx.m, 0).astype(jnp.float32),
+                qg.m.astype(jnp.float32), (((0,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+            * jnp.exp2((qx.exp[e] + qg.exp[e]).astype(jnp.float32))
+            for e in range(G)])
+    return dx, dw, _float0(offsets), _float0(key) if key is not None else None
+
+
+int_grouped_linear.defvjp(_int_glinear_fwd, _int_glinear_bwd)
+
+
+# =========================================================================
 # Embedding
 # =========================================================================
 
@@ -617,8 +761,10 @@ def int_softmax(x: Array, cfg: QuantConfig, axis: int = -1) -> Array:
 # * backward (FA2): p rebuilt from the saved per-row lse; delta = rowsum of
 #   the RAW upstream grad times o (an O(N·hd) XLA f32 reduce — kept op);
 #   dS = p·(dp - delta) quantizes at a norm-derived exponent (see
-#   ``_ds_exp`` — O(N·hd) row norms, no max pass over the S×S matrix), and
-#   dq/dk/dv are integer products of the quantized planes.
+#   ``_ds_exp`` — O(N·hd) row norms, no max pass over the S×S matrix) in a
+#   call that is neither causal nor windowed, and in one that is at each
+#   kernel tile's own DFX exponent (``_ds_tile_exp``), and dq/dk/dv are
+#   integer products of the quantized planes.
 #
 # The sim forward mirrors the kernel's 128-wide chunked online softmax so
 # the per-chunk P quantization (against the running, not global, max) agrees
@@ -651,6 +797,22 @@ def _ds_exp(g_norm: Array, v_norm: Array, ds_bits: int) -> Array:
     bound = 2.0 * g_norm * v_norm
     e = jnp.ceil(jnp.log2(jnp.maximum(bound, 1e-30))) - (ds_bits - 1)
     return e.astype(jnp.int32)
+
+
+def _ds_tile_exp(ds: Array, hd: int, ds_bits: int) -> Array:
+    """Per-element f32 dS scale exponent of a causal or windowed call: the
+    DFX exponent of the largest magnitude in each (bq, bk) tile of the
+    kernels' rows layout (``kops._attn_dims``; a q tile is ``bq``
+    consecutive queries of one head), as the kernels take it in-tile.
+    ds: (B, KV, G, Sq, Sk)."""
+    B, KV, G, Sq, Sk = ds.shape
+    bq, sq_p, bk, sk_p, _ = kops._attn_dims(Sq, Sk, hd)
+    a = jnp.pad(jnp.abs(ds), [(0, 0)] * 3 + [(0, sq_p - Sq), (0, sk_p - Sk)])
+    a = jnp.max(a.reshape(B, KV, G, sq_p // bq, bq, sk_p // bk, bk),
+                axis=(4, 6), keepdims=True)
+    e = jnp.frexp(a)[1] - (ds_bits - 1)
+    e = jnp.broadcast_to(e, (B, KV, G, sq_p // bq, bq, sk_p // bk, bk))
+    return e.reshape(B, KV, G, sq_p, sk_p)[..., :Sq, :Sk].astype(jnp.float32)
 
 
 def _sim_attention_fwd(qd: Array, kd: Array, vd: Array, off: Array,
@@ -716,7 +878,8 @@ def _sim_attention_bwd(qd: Array, kd: Array, vd: Array, gd: Array,
                        p_bits: int, ds_bits: int, causal: bool, window,
                        integer_exp: bool = False):
     """XLA backward on dequantized values — same quantization points as the
-    kernels (P and dS clipped at their static exponents)."""
+    kernels (P and dS clipped at their exponents; ``ds_exp`` None for a
+    causal or windowed call, which takes each tile's own)."""
     _exp = iapprox.i_exp if integer_exp else jnp.exp
     B, Sq, KV, G, hd = qd.shape
     Sk = kd.shape[1]
@@ -738,8 +901,14 @@ def _sim_attention_bwd(qd: Array, kd: Array, vd: Array, gd: Array,
     dp = jnp.einsum("bqhgd,bkhd->bhgqk", gd, vd)
     dl = delta.transpose(0, 2, 3, 1)[..., None]
     ds = p * (dp - dl)
-    dss = jnp.exp2(ds_exp.astype(jnp.float32))
     dlim = float(2 ** (ds_bits - 1) - 1)
+    if ds_exp is None:
+        e = _ds_tile_exp(ds, hd, ds_bits)
+        ds = jnp.clip(jnp.round(ds * jnp.exp2(-e)), -dlim, dlim) * jnp.exp2(e)
+        dq = jnp.einsum("bhgqk,bkhd->bqhgd", ds, kd) * sc
+        dk = jnp.einsum("bhgqk,bqhgd->bkhd", ds, qd) * sc
+        return dq, dk, dv
+    dss = jnp.exp2(ds_exp.astype(jnp.float32))
     dsm = jnp.clip(jnp.round(ds * jnp.exp2(-ds_exp.astype(jnp.float32))),
                    -dlim, dlim)
     dq = jnp.einsum("bhgqk,bkhd->bqhgd", dsm, kd) * dss * sc
@@ -792,7 +961,9 @@ def _int_attention_fwd(q, k, v, q_offset, key, cfg_qk: QuantConfig,
         o, lse = _sim_attention_fwd(dfx.dequantize(qq), dfx.dequantize(qk),
                                     dfx.dequantize(qv), off, p_bits,
                                     causal, window, integer_exp=iexp)
-    v_norm = _max_row_norm(v)          # residual for the bwd dS exponent
+    # residual for the bwd dS exponent of a call that is neither causal
+    # nor windowed; the others take each tile's own
+    v_norm = (None if causal or window is not None else _max_row_norm(v))
     return o, (qq, qk, qv, o, lse, v_norm, q_offset, off, key)
 
 
@@ -806,7 +977,8 @@ def _int_attention_bwd(cfg_qk: QuantConfig, cfg_pv: QuantConfig, causal,
     delta = jnp.sum(g * o, axis=-1)                           # (B,Sq,KV,G)
     p_bits = cfg_pv.act_bits
     ds_bits = cfg_qk.grad_bits
-    ds_exp = _ds_exp(_max_row_norm(g), v_norm, ds_bits)
+    ds_exp = (None if v_norm is None
+              else _ds_exp(_max_row_norm(g), v_norm, ds_bits))
     iexp = cfg_qk.enabled and cfg_qk.kept_ops == "integer"
     if planes:
         dq, dk, dv = kops.attention_bwd(

@@ -44,6 +44,10 @@ experts AND all limb pairs), and the scalar ``out_exp`` operand becomes a
 per-expert **vector** ``(E,)`` — the epilogue of grid slice ``e`` scales by
 ``2**out_exp[e]``.
 
+The **grouped** variants (``bfp_matmul_grouped{,_nt,_tn}``) take rows
+sorted by expert with run-time group offsets (an expert share's SwiGLU
+products, see the section at the end of this file).
+
 Blocks: ``(bm, bn, bk)`` is the output tile and the contracted block in
 every layout; lane dims take multiples of 128 and sublane dims multiples of
 8.  The callers size them to the shapes and limb count
@@ -409,3 +413,258 @@ def bfp_matmul_batched_tn(
         dims=(0, 0),
         interpret=interpret,
     )
+
+
+# =========================================================================
+# Grouped (sorted-rows) variants — rows sorted by expert, each group padded
+# to the row tile; the group offsets ride in as a scalar-prefetch operand
+# =========================================================================
+#
+# ``offsets`` is ``(G+1,)`` int32: group ``g`` owns rows ``[offsets[g],
+# offsets[g+1])``, every group at least one row tile long, and rows at and
+# past ``offsets[G]`` are unused.  The sizes are known only at run time, so
+# the grid covers every row block the buffer can hold; a block past the
+# used rows re-points its inputs at the last used block (a repeated block
+# index is not fetched again) and does no MXU work.  Its rows of a
+# row-split output (NN, NT) are written as zeros, so what follows the
+# kernel never reads stale memory.
+
+def _group_of(off_ref, row, groups: int):
+    """The group that holds ``row``: how many group starts past the first
+    lie at or below it."""
+    g = jnp.int32(0)
+    for h in range(1, groups):
+        g = g + jnp.where(row >= off_ref[h], 1, 0).astype(jnp.int32)
+    return g
+
+
+def _last_block(off_ref, groups: int, block: int):
+    """Index of the last used block of ``block`` rows."""
+    return off_ref[groups] // block - 1
+
+
+def _bfp_grouped_rows_kernel(off_ref, x_ref, w_ref, exp_ref, o_ref, *acc_ref,
+                             n_k: int, dims, lx: int, lw: int, groups: int,
+                             bm: int):
+    """NN / NT over sorted rows: the row block's group picks the weight
+    block and the exponent; a block past the used rows writes zeros."""
+    lc, rc = dims
+    row0 = pl.program_id(0) * bm
+    live = row0 < off_ref[groups]
+    g = _group_of(off_ref, row0, groups)
+
+    def product(jx, jw):
+        return jax.lax.dot_general(
+            x_ref[jx], w_ref[jw], (((lc,), (rc,)), ((), ())),
+            preferred_element_type=jnp.int32)
+
+    def dead():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    if n_k == 1:
+        @pl.when(live)
+        def _live():
+            o_ref[...] = _combine_partials(
+                product, exp_ref[g].astype(jnp.float32), lx, lw)
+
+        pl.when(jnp.logical_not(live))(dead)
+        return
+
+    acc_ref, = acc_ref
+    k = pl.program_id(2)
+
+    @pl.when(jnp.logical_and(live, k == 0))
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(live)
+    def _accumulate():
+        for jx in range(lx):
+            for jw in range(lw):
+                acc_ref[jx * lw + jw] += product(jx, jw)
+
+    @pl.when(jnp.logical_and(live, k == n_k - 1))
+    def _epilogue():
+        o_ref[...] = _combine_partials(
+            lambda jx, jw: acc_ref[jx * lw + jw],
+            exp_ref[g].astype(jnp.float32), lx, lw)
+
+    pl.when(jnp.logical_and(jnp.logical_not(live), k == n_k - 1))(dead)
+
+
+def _bfp_grouped_tn_kernel(off_ref, x_ref, g_ref, exp_ref, o_ref, acc_ref, *,
+                           lx: int, lg: int, groups: int, bk: int):
+    """TN over sorted rows: the contraction runs over one group's row blocks
+    in turn, accumulating exactly in int32, and the group's (K, N) product
+    is combined on its last block."""
+    row0 = pl.program_id(2) * bk
+    live = row0 < off_ref[groups]
+    g = _group_of(off_ref, row0, groups)
+
+    @pl.when(jnp.logical_and(live, row0 == off_ref[g]))
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(live)
+    def _accumulate():
+        for jx in range(lx):
+            for jg in range(lg):
+                acc_ref[jx * lg + jg] += jax.lax.dot_general(
+                    x_ref[jx], g_ref[jg], (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.int32)
+
+    @pl.when(jnp.logical_and(live, row0 + bk == off_ref[g + 1]))
+    def _epilogue():
+        o_ref[...] = _combine_partials(
+            lambda jx, jg: acc_ref[jx * lg + jg],
+            exp_ref[g].astype(jnp.float32), lx, lg)
+
+
+def _grouped_call(kernel, lhs, rhs, out_exp, offsets, *, name, out_shape,
+                  grid, lhs_spec, rhs_spec, out_spec, blocks, n_k, accumulate,
+                  interpret):
+    """One ``pallas_call`` over sorted rows; ``offsets`` is the scalar
+    prefetch operand every index map and the kernel read."""
+    assert lhs.dtype == jnp.int8 and rhs.dtype == jnp.int8, (lhs.dtype,
+                                                            rhs.dtype)
+    lx, lw = lhs.shape[0], rhs.shape[0]
+    bm, bn, bk = blocks
+    scratch = ([pltpu.VMEM((lx * lw, bm, bn), jnp.int32)] if accumulate
+               else [])
+    vmem_limit = _vmem_limit(matmul_vmem_bytes(bm, bn, bk, lx, lw,
+                                               2 if accumulate else n_k))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[lhs_spec, rhs_spec,
+                      pl.BlockSpec(memory_space=pltpu.SMEM)],  # (G,) exps
+            out_specs=out_spec,
+            scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit),
+        name=name,
+        interpret=interpret,
+    )(offsets.astype(jnp.int32), lhs, rhs, out_exp.astype(jnp.int32))
+
+
+def _grouped_rows(xm, wm, out_exp, offsets, *, name, dims, bm, bn, bk,
+                  interpret):
+    """NN (``dims`` (1, 0), W (G, K, N)) or NT (``dims`` (1, 1), W (G, N,
+    K) in forward layout) over the sorted rows of ``xm`` (L, M, K)."""
+    lx, M, K = xm.shape
+    lw, G = wm.shape[:2]
+    N = wm.shape[3] if dims == (1, 0) else wm.shape[2]
+    assert wm.shape[2 if dims == (1, 0) else 3] == K, (xm.shape, wm.shape)
+    assert offsets.shape == (G + 1,) and out_exp.shape == (G,), (
+        offsets.shape, out_exp.shape, G)
+    assert M % bm == 0 and N % bn == 0 and K % bk == 0, (
+        f"rows ({M},{K}) x groups {wm.shape[1:]} must tile by ({bm},{bn},{bk})")
+    n_k = K // bk
+
+    def rows(i, off):
+        return jnp.minimum(i, _last_block(off, G, bm))
+
+    def group(i, off):
+        return _group_of(off, rows(i, off) * bm, G)
+
+    if dims == (1, 0):
+        w_spec = pl.BlockSpec((lw, None, bk, bn),
+                              lambda i, j, k, off: (0, group(i, off), k, j))
+    else:
+        w_spec = pl.BlockSpec((lw, None, bn, bk),
+                              lambda i, j, k, off: (0, group(i, off), j, k))
+    return _grouped_call(
+        functools.partial(_bfp_grouped_rows_kernel, n_k=n_k, dims=dims,
+                          lx=lx, lw=lw, groups=G, bm=bm),
+        xm, wm, out_exp, offsets, name=name, out_shape=(M, N),
+        grid=(M // bm, N // bn, n_k),
+        lhs_spec=pl.BlockSpec((lx, bm, bk),
+                              lambda i, j, k, off: (0, rows(i, off), k)),
+        rhs_spec=w_spec,
+        out_spec=pl.BlockSpec((bm, bn), lambda i, j, k, off: (i, j)),
+        blocks=(bm, bn, bk), n_k=n_k, accumulate=n_k > 1,
+        interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
+def bfp_matmul_grouped(
+    xm: jax.Array,          # (Lx, M, K) int8 limb planes, rows sorted by group
+    wm: jax.Array,          # (Lw, G, K, N) int8 limb planes
+    out_exp: jax.Array,     # (G,) int32: x_exp[g] + w_exp[g]
+    offsets: jax.Array,     # (G+1,) int32 group starts, then the used rows
+    *,
+    bm: int = 128,
+    bn: int = 128,
+    bk: int = 128,
+    interpret: bool = False,
+) -> jax.Array:
+    """Grouped NN: row ``r`` of group ``g`` -> ``(x[r] @ w[g]) *
+    2**out_exp[g]``; (M, N) f32, zero past the used rows."""
+    return _grouped_rows(xm, wm, out_exp, offsets, name="bfp_matmul_grouped",
+                         dims=(1, 0), bm=bm, bn=bn, bk=bk,
+                         interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
+def bfp_matmul_grouped_nt(
+    gm: jax.Array,          # (Lg, M, N) grad limb planes, rows sorted
+    wm: jax.Array,          # (Lw, G, K, N) weight limb planes, forward layout
+    out_exp: jax.Array,     # (G,) int32: g_exp[g] + w_exp[g]
+    offsets: jax.Array,     # (G+1,) int32
+    *,
+    bm: int = 128,
+    bn: int = 128,
+    bk: int = 128,
+    interpret: bool = False,
+) -> jax.Array:
+    """Grouped NT: ``(g[r] @ w[g]ᵀ) * 2**out_exp[g]`` -> (M, K) f32 — the
+    dX product, W in its forward layout."""
+    return _grouped_rows(gm, wm, out_exp, offsets,
+                         name="bfp_matmul_grouped_nt", dims=(1, 1),
+                         bm=bm, bn=bn, bk=bk, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
+def bfp_matmul_grouped_tn(
+    xm: jax.Array,          # (Lx, M, K) activation limb planes, rows sorted
+    gm: jax.Array,          # (Lg, M, N) grad limb planes, rows sorted
+    out_exp: jax.Array,     # (G,) int32: x_exp[g] + g_exp[g]
+    offsets: jax.Array,     # (G+1,) int32
+    *,
+    bm: int = 128,
+    bn: int = 128,
+    bk: int = 128,
+    interpret: bool = False,
+) -> jax.Array:
+    """Grouped TN: ``(x[rows of g]ᵀ @ g[rows of g]) * 2**out_exp[g]`` ->
+    (G, K, N) f32 — the dW product.  ``bk`` row blocks tile every group, so
+    a group's contraction is its own blocks, accumulated in int32."""
+    lx, M, K = xm.shape
+    lg, M2, N = gm.shape
+    G = out_exp.shape[0]
+    assert M == M2 and offsets.shape == (G + 1,), (xm.shape, gm.shape,
+                                                   offsets.shape)
+    assert K % bm == 0 and N % bn == 0 and M % bk == 0, (
+        f"rows ({M},{K})x({M},{N}) must tile by ({bm},{bn},{bk})")
+
+    def rows(r, off):
+        return jnp.minimum(r, _last_block(off, G, bk))
+
+    return _grouped_call(
+        functools.partial(_bfp_grouped_tn_kernel, lx=lx, lg=lg, groups=G,
+                          bk=bk),
+        xm, gm, out_exp, offsets, name="bfp_matmul_grouped_tn",
+        out_shape=(G, K, N), grid=(K // bm, N // bn, M // bk),
+        lhs_spec=pl.BlockSpec((lx, bk, bm),
+                              lambda i, j, r, off: (0, rows(r, off), i)),
+        rhs_spec=pl.BlockSpec((lg, bk, bn),
+                              lambda i, j, r, off: (0, rows(r, off), j)),
+        out_spec=pl.BlockSpec(
+            (None, bm, bn),
+            lambda i, j, r, off: (_group_of(off, rows(r, off) * bk, G), i, j)),
+        blocks=(bm, bn, bk), n_k=M // bk, accumulate=True,
+        interpret=interpret)

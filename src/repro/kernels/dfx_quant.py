@@ -111,7 +111,8 @@ def _out_dtype(bits: int):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("bits", "br", "interpret", "limb_planes"))
+                   static_argnames=("bits", "br", "interpret", "limb_planes",
+                                    "vmem_limit"))
 def dfx_quantize(
     x: jax.Array,            # (M, N) float32
     exp: jax.Array,          # scalar int32 (e_max - bits + 1)
@@ -121,13 +122,15 @@ def dfx_quantize(
     br: int = 256,
     interpret: bool = False,
     limb_planes: bool = False,
+    vmem_limit: int | None = None,
 ) -> jax.Array:
     """Shift-round-clip pass; one streaming kernel launch.
 
     ``limb_planes=False`` returns the logical (M, N) int8/int16 mantissa
     (norm layers, embedding tables).  ``limb_planes=True`` returns the
     (L, M, N) int8 limb-plane stack the matmul kernels consume — the digit
-    split is fused into this same launch.
+    split is fused into this same launch.  ``vmem_limit`` is the scoped
+    VMEM to ask Mosaic for; None leaves its default.
     """
     M, N = x.shape
     assert M % br == 0, (M, br)
@@ -142,11 +145,13 @@ def dfx_quantize(
         out_spec = pl.BlockSpec((br, N), lambda i: (i, 0))
         out_shape = jax.ShapeDtypeStruct((M, N), _out_dtype(bits))
         kern, kern_stoch = _quant_kernel, _quant_kernel_stoch
+    limit = {} if vmem_limit is None else {"vmem_limit_bytes": vmem_limit}
     common = dict(
         grid=grid,
         out_specs=out_spec,
         out_shape=out_shape,
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",),
+                                             **limit),
         name="dfx_quantize",
         interpret=interpret,
     )

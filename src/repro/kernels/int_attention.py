@@ -41,14 +41,22 @@ innermost accumulating one q-row block; ``dk/dv`` iterates q blocks
 innermost accumulating one k-row block.  Both recompute ``p`` from the saved
 row ``lse`` (no S×S residual), quantize ``p`` and ``dS = p·(dp − δ)`` to
 limb planes **in-register** (the digit split of kernels/dfx_quant.py), and
-run every contraction on the integer MXU path.  ``dS``'s scale exponent is
-a *bound-derived* static-per-trace int32 operand (see core.int_ops) — no
-max pass over dS either.
+run every contraction on the integer MXU path.  ``dS``'s scale exponent
+is, in a call that is neither causal nor windowed, a *bound-derived* int32
+operand (see core.int_ops); a causal or windowed call takes each (bq, bk)
+tile's own, the DFX exponent of the tile's largest magnitude
+(``_tile_ds_exp``), since there a row's P spreads over up to Sk keys and
+the bound's ``p <= 1`` leaves ~log2(Sk) of its bits unused.  No max pass
+over dS either way: a tile's maximum is taken where the tile is made, and
+each tile's product is scaled on its own before the f32 accumulation.
 
 Masking: ``qpos = q_offset[b] + i_local`` (per-row offsets for KV-cache
 decode / chunked prefill / continuous-batching slots), ``kpos`` the global
 K column; validity is ``kpos < kv_len`` (ragged tail) ∧ causal
-(``kpos ≤ qpos``) ∧ sliding window (``kpos > qpos − window``).
+(``kpos ≤ qpos``) ∧ sliding window (``kpos > qpos − window``).  A causal
+or windowed call visits only the blocks that validity can reach (the
+band calls at the end of this file); a call that is neither runs the
+full grid.
 
 Accumulator budget (quantlint QL006): every integer dot is digit×digit —
 |limb| ≤ 64 — so the int32 partials are bounded by ``64²·K`` with
@@ -123,6 +131,19 @@ def _plane_dot(planes, b_ref, lb: int, dims, exp_f32, shift: int):
     return out
 
 
+def _tile_ds_exp(ds, ds_bits: int):
+    """(1, 1) f32 scale exponent of one dS tile, as the DFX quantizer picks
+    a tensor's (core/dfx.py): the frexp exponent of the tile's largest
+    magnitude, less ``ds_bits - 1``.  Read exactly from the float's
+    exponent field; an all-zero tile (floored at 2^-100) gets a finite
+    one, and its mantissas are zero whatever it is."""
+    amax = jnp.max(jnp.max(jnp.abs(ds), axis=1, keepdims=True), axis=0,
+                   keepdims=True)
+    field = jax.lax.bitcast_convert_type(jnp.maximum(amax, 2.0 ** -100),
+                                         jnp.int32) >> 23
+    return (field - 126 - (ds_bits - 1)).astype(jnp.float32)
+
+
 def _valid_mask(off, qi, kj, *, bq: int, bk: int, sq_p: int, kv_len: int,
                 causal: bool, window):
     """(bq, bk) bool validity of score block (qi, kj).
@@ -145,6 +166,119 @@ def _valid_mask(off, qi, kj, *, bq: int, bk: int, sq_p: int, kv_len: int,
     return ok
 
 
+def _k_band(off, qi, *, bq: int, bk: int, sq_p: int, n_k: int, causal: bool,
+            window):
+    """First and last K block that q block ``qi`` can see under the causal
+    and window masks (``_valid_mask``); a scalar computation, run by the
+    index maps and by the kernel alike."""
+    g_blk = (qi * bq) // sq_p
+    r0 = qi * bq - g_blk * sq_p
+    lo, hi = 0, n_k - 1
+    if causal:
+        hi = jnp.minimum(hi, (off + r0 + bq - 1) // bk)
+    if window is not None:
+        lo = jnp.maximum(off + r0 - window + 1, 0) // bk
+    return lo, hi
+
+
+def _q_band(off, kj, *, bq: int, bk: int, nqb: int, causal: bool, window):
+    """First and last q block, inside every GQA group of ``nqb`` blocks,
+    that can see K block ``kj``; ``hi < lo`` when none can."""
+    lo, hi = 0, nqb - 1
+    if causal:
+        lo = jnp.maximum(kj * bk - off, 0) // bq
+    if window is not None:
+        last = kj * bk + bk + window - 2 - off
+        hi = jnp.minimum(hi, jnp.where(last < 0, -1, last // bq))
+    return lo, hi
+
+
+def _k_visits(n_k: int, *, bq: int, bk: int, sq_p: int, kv_heads: int,
+              causal: bool, window):
+    """``(band, grid steps, k-block index map)`` of a call that steps over
+    key blocks innermost (forward, dq): every block in turn, or, when
+    causal or windowed, the band ``_k_band`` gives, clamped at its end."""
+    if not causal and window is None:
+        return None, n_k, lambda h, i, j, off: j
+    band = functools.partial(_k_band, bq=bq, bk=bk, sq_p=sq_p, n_k=n_k,
+                             causal=causal, window=window)
+
+    def kblk(h, i, j, off):
+        lo, hi = band(off[h // kv_heads], i)
+        return jnp.minimum(lo + j, hi)
+    return band, _band_steps(n_k, bq, bk, causal, window), kblk
+
+
+def _band_steps(n: int, rows: int, cols: int, causal: bool, window) -> int:
+    """Grid steps along the visited axis: the widest band a block of
+    ``rows`` can see over blocks of ``cols`` when causal and windowed, else
+    every block (the skipped ones re-point at a visited block)."""
+    if causal and window is not None:
+        return min(n, (rows + window - 2) // cols + 2)
+    return n
+
+
+def _visit_band(visit, band, off, blk, step):
+    """Run ``visit`` on the block a grid step points at: the step itself
+    on the full grid (``band`` None), else the step-th block of the band
+    that ``band(off, blk)`` gives, skipped past the band's end."""
+    if band is None:
+        visit(step)
+        return
+    lo, hi = band(off, blk)
+    pl.when(lo + step <= hi)(lambda: visit(jnp.minimum(lo + step, hi)))
+
+
+def _attn_call(kernel, *, banded: bool, grid, in_blocks, out_blocks,
+               out_shape, scratch, name: str, interpret: bool):
+    """One attention ``pallas_call``, returned as ``f(*blocked, q_off=,
+    exps=)``.  ``in_blocks``/``out_blocks`` pair each block shape with its
+    index map ``(h, i, j, off)``; the kernel takes the blocked refs, then
+    the (B,) query offsets and the exponents, both in SMEM.
+
+    A banded (causal or windowed) call moves the query offsets to the
+    scalar prefetch, where its index maps read them: a grid step past the
+    last block its row block can see re-points at that block, which Pallas
+    does not fetch again, and the kernel skips its work.  Every block
+    skipped is wholly masked, and a wholly masked block changes none of
+    the running sums, so the results are the full grid's bit for bit.  Any
+    other call (the encoders) runs the full grid with no scalar prefetch,
+    and its index maps never read ``off``."""
+    def spec(shape, index):
+        if banded:
+            return pl.BlockSpec(shape, index)
+        return pl.BlockSpec(shape, lambda h, i, j: index(h, i, j, None))
+
+    n = len(in_blocks)
+    if banded:
+        inner = kernel
+
+        def kernel(off_ref, *refs):
+            return inner(*refs[:n], off_ref, *refs[n:])
+
+    in_specs = [spec(*b) for b in in_blocks] + [_SMEM] * (1 if banded else 2)
+    out_specs = (spec(*out_blocks) if isinstance(out_blocks, tuple)
+                 else [spec(*b) for b in out_blocks])
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=int(banded), grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name=name,
+        interpret=interpret,
+    )
+
+    def run(*blocked, q_off, exps):
+        q_off, exps = q_off.astype(jnp.int32), exps.astype(jnp.int32)
+        if banded:
+            return call(q_off, *blocked, exps)
+        return call(*blocked, q_off, exps)
+    return run
+
+
 def _p_exp(x, integer_exp: bool):
     """In-kernel softmax exp: FP32 (the paper's kept op) or the iapprox
     fixed-point form under ``kept_ops="integer"``.  Static flag — the swap
@@ -165,12 +299,12 @@ def _int_attn_fwd_kernel(q_ref, k_ref, v_ref, off_ref, exp_ref,
                          n_k: int, lq: int, lk: int, lv: int, p_bits: int,
                          sq_p: int, kv_heads: int, kv_len: int, causal: bool,
                          window, sc: float, bq: int, bk: int,
-                         integer_exp: bool):
+                         integer_exp: bool, band=None):
     h = pl.program_id(0)
     qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    step = pl.program_id(2)
 
-    @pl.when(kj == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, _BIG_NEG)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -181,27 +315,31 @@ def _int_attn_fwd_kernel(q_ref, k_ref, v_ref, off_ref, exp_ref,
     ve = exp_ref[2].astype(jnp.float32)
     off = off_ref[h // kv_heads]
 
-    ok = _valid_mask(off, qi, kj, bq=bq, bk=bk, sq_p=sq_p, kv_len=kv_len,
-                     causal=causal, window=window)
-    s = _limb_dot(q_ref, k_ref, lq, lk, (1, 1), qe + ke, 0) * sc
-    s = jnp.where(ok, s, _BIG_NEG)
+    def visit(kj):
+        ok = _valid_mask(off, qi, kj, bq=bq, bk=bk, sq_p=sq_p,
+                         kv_len=kv_len, causal=causal, window=window)
+        s = _limb_dot(q_ref, k_ref, lq, lk, (1, 1), qe + ke, 0) * sc
+        s = jnp.where(ok, s, _BIG_NEG)
 
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    # the where-guard is load-bearing: a fully masked block has
-    # s == m_new == _BIG_NEG and exp(0) = 1 would corrupt l
-    p = jnp.where(ok, _p_exp(s - m_new, integer_exp), 0.0)
-    alpha = _p_exp(m_prev - m_new, integer_exp)
-    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    m_scr[...] = m_new
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        # the where-guard is load-bearing: a fully masked block has
+        # s == m_new == _BIG_NEG and exp(0) = 1 would corrupt l
+        p = jnp.where(ok, _p_exp(s - m_new, integer_exp), 0.0)
+        alpha = _p_exp(m_prev - m_new, integer_exp)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        m_scr[...] = m_new
 
-    # P quantizes at the static exponent -(p_bits-1): p <= 1 by construction
-    pm = _round_clip(jnp.round(p * (2.0 ** (p_bits - 1))), p_bits)
-    pv = _plane_dot(_split_planes(pm, n_limbs(p_bits)), v_ref, lv,
-                    (1, 0), ve, -(p_bits - 1))
-    acc_scr[...] = acc_scr[...] * alpha + pv
+        # P quantizes at the static exponent -(p_bits-1): p <= 1 by
+        # construction
+        pm = _round_clip(jnp.round(p * (2.0 ** (p_bits - 1))), p_bits)
+        pv = _plane_dot(_split_planes(pm, n_limbs(p_bits)), v_ref, lv,
+                        (1, 0), ve, -(p_bits - 1))
+        acc_scr[...] = acc_scr[...] * alpha + pv
 
-    @pl.when(kj == n_k - 1)
+    _visit_band(visit, band, off, qi, step)
+
+    @pl.when(step == n_k - 1)
     def _epilogue():
         l = l_scr[...]
         if integer_exp:
@@ -246,39 +384,41 @@ def int_attn_fwd(
         qm.shape, km.shape, vm.shape)
     assert R % bq == 0 and Skp % bk == 0 and sq_p % bq == 0, (
         R, Skp, sq_p, bq, bk)
-    n_k = Skp // bk
-    return pl.pallas_call(
-        functools.partial(
-            _int_attn_fwd_kernel, n_k=n_k, lq=Lq, lk=Lk, lv=Lv,
-            p_bits=p_bits, sq_p=sq_p, kv_heads=kv_heads, kv_len=kv_len,
-            causal=causal, window=window, sc=sc, bq=bq, bk=bk,
-            integer_exp=integer_exp),
-        grid=(BH, R // bq, n_k),
-        in_specs=[
-            pl.BlockSpec((Lq, 1, bq, hd_p), lambda h, i, j: (0, h, i, 0)),
-            pl.BlockSpec((Lk, 1, bk, hd_p), lambda h, i, j: (0, h, j, 0)),
-            pl.BlockSpec((Lv, 1, bk, hd_p), lambda h, i, j: (0, h, j, 0)),
-            _SMEM,                               # (B,) query offsets
-            _SMEM,                               # (3,) exps
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, hd_p), lambda h, i, j: (h, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda h, i, j: (h, i, 0)),
-        ],
+    masked = causal or window is not None
+    band, steps, kblk = _k_visits(Skp // bk, bq=bq, bk=bk, sq_p=sq_p,
+                                  kv_heads=kv_heads, causal=causal,
+                                  window=window)
+
+    def q_rows(h, i, j, off):
+        return (0, h, i, 0)
+
+    def k_rows(h, i, j, off):
+        return (0, h, kblk(h, i, j, off), 0)
+
+    def out_rows(h, i, j, off):
+        return (h, i, 0)
+
+    kernel = functools.partial(
+        _int_attn_fwd_kernel, n_k=steps, lq=Lq, lk=Lk, lv=Lv,
+        p_bits=p_bits, sq_p=sq_p, kv_heads=kv_heads, kv_len=kv_len,
+        causal=causal, window=window, sc=sc, bq=bq, bk=bk,
+        integer_exp=integer_exp, band=band)
+    return _attn_call(
+        kernel, banded=masked, grid=(BH, R // bq, steps),
+        in_blocks=[((Lq, 1, bq, hd_p), q_rows), ((Lk, 1, bk, hd_p), k_rows),
+                   ((Lv, 1, bk, hd_p), k_rows)],
+        out_blocks=[((1, bq, hd_p), out_rows), ((1, bq, 1), out_rows)],
         out_shape=[
             jax.ShapeDtypeStruct((BH, R, hd_p), jnp.float32),
             jax.ShapeDtypeStruct((BH, R, 1), jnp.float32),
         ],
-        scratch_shapes=[
+        scratch=[
             pltpu.VMEM((bq, 1), jnp.float32),      # running row max
             pltpu.VMEM((bq, 1), jnp.float32),      # running normalizer
             pltpu.VMEM((bq, hd_p), jnp.float32),   # output accumulator
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        name="int_attn_fwd",
-        interpret=interpret,
-    )(qm, km, vm, q_off.astype(jnp.int32), exps.astype(jnp.int32))
+        name="int_attn_fwd", interpret=interpret,
+    )(qm, km, vm, q_off=q_off, exps=exps)
 
 
 # =========================================================================
@@ -290,12 +430,13 @@ def _int_attn_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref,
                             n_k: int, lq: int, lk: int, lv: int, lg: int,
                             ds_bits: int, sq_p: int, kv_heads: int,
                             kv_len: int, causal: bool, window, sc: float,
-                            bq: int, bk: int, integer_exp: bool):
+                            bq: int, bk: int, integer_exp: bool,
+                            band=None):
     h = pl.program_id(0)
     qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    step = pl.program_id(2)
 
-    @pl.when(kj == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
@@ -303,23 +444,29 @@ def _int_attn_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref,
     ke = exp_ref[1].astype(jnp.float32)
     ve = exp_ref[2].astype(jnp.float32)
     ge = exp_ref[3].astype(jnp.float32)
-    dse = exp_ref[4].astype(jnp.float32)
+    # a causal or windowed call scales dS per tile (module docstring)
+    tile_ds = causal or window is not None
+    dse = None if tile_ds else exp_ref[4].astype(jnp.float32)
     off = off_ref[h // kv_heads]
 
-    ok = _valid_mask(off, qi, kj, bq=bq, bk=bk, sq_p=sq_p, kv_len=kv_len,
-                     causal=causal, window=window)
-    s = _limb_dot(q_ref, k_ref, lq, lk, (1, 1), qe + ke, 0) * sc
-    s = jnp.where(ok, s, _BIG_NEG)
-    # padded q rows carry lse = +1e30, so p vanishes there exactly
-    p = jnp.where(ok, _p_exp(s - lse_ref[0], integer_exp), 0.0)
+    def visit(kj):
+        ok = _valid_mask(off, qi, kj, bq=bq, bk=bk, sq_p=sq_p,
+                         kv_len=kv_len, causal=causal, window=window)
+        s = _limb_dot(q_ref, k_ref, lq, lk, (1, 1), qe + ke, 0) * sc
+        s = jnp.where(ok, s, _BIG_NEG)
+        # padded q rows carry lse = +1e30, so p vanishes there exactly
+        p = jnp.where(ok, _p_exp(s - lse_ref[0], integer_exp), 0.0)
 
-    dp = _limb_dot(g_ref, v_ref, lg, lv, (1, 1), ge + ve, 0)
-    ds = p * (dp - d_ref[0])
-    dsm = _round_clip(jnp.round(ds * jnp.exp2(-dse)), ds_bits)
-    dq_scr[...] += _plane_dot(_split_planes(dsm, n_limbs(ds_bits)), k_ref,
-                              lk, (1, 0), dse + ke, 0)
+        dp = _limb_dot(g_ref, v_ref, lg, lv, (1, 1), ge + ve, 0)
+        ds = p * (dp - d_ref[0])
+        e = _tile_ds_exp(ds, ds_bits) if tile_ds else dse
+        dsm = _round_clip(jnp.round(ds * jnp.exp2(-e)), ds_bits)
+        dq_scr[...] += _plane_dot(_split_planes(dsm, n_limbs(ds_bits)),
+                                  k_ref, lk, (1, 0), e + ke, 0)
 
-    @pl.when(kj == n_k - 1)
+    _visit_band(visit, band, off, qi, step)
+
+    @pl.when(step == n_k - 1)
     def _epilogue():
         dq_ref[0] = dq_scr[...] * sc
 
@@ -335,7 +482,8 @@ def int_attn_bwd_dq(
     lse: jax.Array,         # (BH, R, 1) f32 (+1e30 on padded rows)
     delta: jax.Array,       # (BH, R, 1) f32 rowsum(dO * O)
     q_off: jax.Array,       # (B,) int32
-    exps: jax.Array,        # (5,) int32 [q, k, v, g, dS] exponents
+    exps: jax.Array,        # (5,) int32 [q, k, v, g, dS] exponents; no dS
+                            # in a causal or windowed call
     *,
     ds_bits: int,
     sq_p: int,
@@ -357,33 +505,35 @@ def int_attn_bwd_dq(
     Lv, Lg = vm.shape[0], gm.shape[0]
     assert gm.shape[1:] == qm.shape[1:] and lse.shape == (BH, R, 1), (
         qm.shape, gm.shape, lse.shape)
-    n_k = Skp // bk
-    return pl.pallas_call(
-        functools.partial(
-            _int_attn_bwd_dq_kernel, n_k=n_k, lq=Lq, lk=Lk, lv=Lv, lg=Lg,
-            ds_bits=ds_bits, sq_p=sq_p, kv_heads=kv_heads, kv_len=kv_len,
-            causal=causal, window=window, sc=sc, bq=bq, bk=bk,
-            integer_exp=integer_exp),
-        grid=(BH, R // bq, n_k),
-        in_specs=[
-            pl.BlockSpec((Lq, 1, bq, hd_p), lambda h, i, j: (0, h, i, 0)),
-            pl.BlockSpec((Lk, 1, bk, hd_p), lambda h, i, j: (0, h, j, 0)),
-            pl.BlockSpec((Lv, 1, bk, hd_p), lambda h, i, j: (0, h, j, 0)),
-            pl.BlockSpec((Lg, 1, bq, hd_p), lambda h, i, j: (0, h, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda h, i, j: (h, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda h, i, j: (h, i, 0)),
-            _SMEM,                               # (B,) query offsets
-            _SMEM,                               # (5,) exps
-        ],
-        out_specs=pl.BlockSpec((1, bq, hd_p), lambda h, i, j: (h, i, 0)),
+    masked = causal or window is not None
+    band, steps, kblk = _k_visits(Skp // bk, bq=bq, bk=bk, sq_p=sq_p,
+                                  kv_heads=kv_heads, causal=causal,
+                                  window=window)
+
+    def q_rows(h, i, j, off):
+        return (0, h, i, 0)
+
+    def k_rows(h, i, j, off):
+        return (0, h, kblk(h, i, j, off), 0)
+
+    def row(h, i, j, off):
+        return (h, i, 0)
+
+    kernel = functools.partial(
+        _int_attn_bwd_dq_kernel, n_k=steps, lq=Lq, lk=Lk, lv=Lv, lg=Lg,
+        ds_bits=ds_bits, sq_p=sq_p, kv_heads=kv_heads, kv_len=kv_len,
+        causal=causal, window=window, sc=sc, bq=bq, bk=bk,
+        integer_exp=integer_exp, band=band)
+    return _attn_call(
+        kernel, banded=masked, grid=(BH, R // bq, steps),
+        in_blocks=[((Lq, 1, bq, hd_p), q_rows), ((Lk, 1, bk, hd_p), k_rows),
+                   ((Lv, 1, bk, hd_p), k_rows), ((Lg, 1, bq, hd_p), q_rows),
+                   ((1, bq, 1), row), ((1, bq, 1), row)],
+        out_blocks=((1, bq, hd_p), row),
         out_shape=jax.ShapeDtypeStruct((BH, R, hd_p), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bq, hd_p), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        name="int_attn_bwd_dq",
-        interpret=interpret,
-    )(qm, km, vm, gm, lse, delta,
-      q_off.astype(jnp.int32), exps.astype(jnp.int32))
+        scratch=[pltpu.VMEM((bq, hd_p), jnp.float32)],
+        name="int_attn_bwd_dq", interpret=interpret,
+    )(qm, km, vm, gm, lse, delta, q_off=q_off, exps=exps)
 
 
 # =========================================================================
@@ -397,12 +547,12 @@ def _int_attn_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref,
                              p_bits: int, ds_bits: int, sq_p: int,
                              kv_heads: int, kv_len: int, causal: bool,
                              window, sc: float, bq: int, bk: int,
-                             integer_exp: bool):
+                             integer_exp: bool, q_block=None):
     h = pl.program_id(0)
     kj = pl.program_id(1)
-    qi = pl.program_id(2)
+    step = pl.program_id(2)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
@@ -411,28 +561,37 @@ def _int_attn_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref,
     ke = exp_ref[1].astype(jnp.float32)
     ve = exp_ref[2].astype(jnp.float32)
     ge = exp_ref[3].astype(jnp.float32)
-    dse = exp_ref[4].astype(jnp.float32)
+    tile_ds = causal or window is not None
+    dse = None if tile_ds else exp_ref[4].astype(jnp.float32)
     off = off_ref[h // kv_heads]
 
-    ok = _valid_mask(off, qi, kj, bq=bq, bk=bk, sq_p=sq_p, kv_len=kv_len,
-                     causal=causal, window=window)
-    s = _limb_dot(q_ref, k_ref, lq, lk, (1, 1), qe + ke, 0) * sc
-    s = jnp.where(ok, s, _BIG_NEG)
-    p = jnp.where(ok, _p_exp(s - lse_ref[0], integer_exp), 0.0)
+    def visit(qi):
+        ok = _valid_mask(off, qi, kj, bq=bq, bk=bk, sq_p=sq_p,
+                         kv_len=kv_len, causal=causal, window=window)
+        s = _limb_dot(q_ref, k_ref, lq, lk, (1, 1), qe + ke, 0) * sc
+        s = jnp.where(ok, s, _BIG_NEG)
+        p = jnp.where(ok, _p_exp(s - lse_ref[0], integer_exp), 0.0)
 
-    # dV: quantized-Pᵀ · dO — the same static-exponent P mantissa the
-    # forward contracted against V (straight-through at the quantizer)
-    pm = _round_clip(jnp.round(p * (2.0 ** (p_bits - 1))), p_bits)
-    dv_scr[...] += _plane_dot(_split_planes(pm, n_limbs(p_bits)), g_ref, lg,
-                              (0, 0), ge, -(p_bits - 1))
+        # dV: quantized-Pᵀ · dO — the same static-exponent P mantissa the
+        # forward contracted against V (straight-through at the quantizer)
+        pm = _round_clip(jnp.round(p * (2.0 ** (p_bits - 1))), p_bits)
+        dv_scr[...] += _plane_dot(_split_planes(pm, n_limbs(p_bits)), g_ref,
+                                  lg, (0, 0), ge, -(p_bits - 1))
 
-    dp = _limb_dot(g_ref, v_ref, lg, lv, (1, 1), ge + ve, 0)
-    ds = p * (dp - d_ref[0])
-    dsm = _round_clip(jnp.round(ds * jnp.exp2(-dse)), ds_bits)
-    dk_scr[...] += _plane_dot(_split_planes(dsm, n_limbs(ds_bits)), q_ref,
-                              lq, (0, 0), dse + qe, 0)
+        dp = _limb_dot(g_ref, v_ref, lg, lv, (1, 1), ge + ve, 0)
+        ds = p * (dp - d_ref[0])
+        e = _tile_ds_exp(ds, ds_bits) if tile_ds else dse
+        dsm = _round_clip(jnp.round(ds * jnp.exp2(-e)), ds_bits)
+        dk_scr[...] += _plane_dot(_split_planes(dsm, n_limbs(ds_bits)),
+                                  q_ref, lq, (0, 0), e + qe, 0)
 
-    @pl.when(qi == n_q - 1)
+    if q_block is None:
+        visit(step)
+    else:
+        qi, live = q_block(off, kj, step)
+        pl.when(live)(lambda: visit(qi))
+
+    @pl.when(step == n_q - 1)
     def _epilogue():
         dk_ref[0] = dk_scr[...] * sc
         dv_ref[0] = dv_scr[...]
@@ -449,7 +608,8 @@ def int_attn_bwd_dkv(
     lse: jax.Array,         # (BH, R, 1) f32 (+1e30 on padded rows)
     delta: jax.Array,       # (BH, R, 1) f32 rowsum(dO * O)
     q_off: jax.Array,       # (B,) int32
-    exps: jax.Array,        # (5,) int32 [q, k, v, g, dS] exponents
+    exps: jax.Array,        # (5,) int32 [q, k, v, g, dS] exponents; no dS
+                            # in a causal or windowed call
     *,
     p_bits: int,
     ds_bits: int,
@@ -471,39 +631,61 @@ def int_attn_bwd_dkv(
     Lv, Lg = vm.shape[0], gm.shape[0]
     assert gm.shape[1:] == qm.shape[1:] and lse.shape == (BH, R, 1), (
         qm.shape, gm.shape, lse.shape)
-    n_q = R // bq
-    return pl.pallas_call(
-        functools.partial(
-            _int_attn_bwd_dkv_kernel, n_q=n_q, lq=Lq, lk=Lk, lv=Lv, lg=Lg,
-            p_bits=p_bits, ds_bits=ds_bits, sq_p=sq_p, kv_heads=kv_heads,
-            kv_len=kv_len, causal=causal, window=window, sc=sc,
-            bq=bq, bk=bk, integer_exp=integer_exp),
-        grid=(BH, Skp // bk, n_q),
-        in_specs=[
-            pl.BlockSpec((Lq, 1, bq, hd_p), lambda h, j, i: (0, h, i, 0)),
-            pl.BlockSpec((Lk, 1, bk, hd_p), lambda h, j, i: (0, h, j, 0)),
-            pl.BlockSpec((Lv, 1, bk, hd_p), lambda h, j, i: (0, h, j, 0)),
-            pl.BlockSpec((Lg, 1, bq, hd_p), lambda h, j, i: (0, h, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda h, j, i: (h, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda h, j, i: (h, i, 0)),
-            _SMEM,                               # (B,) query offsets
-            _SMEM,                               # (5,) exps
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, hd_p), lambda h, j, i: (h, j, 0)),
-            pl.BlockSpec((1, bk, hd_p), lambda h, j, i: (h, j, 0)),
-        ],
+    masked = causal or window is not None
+    q_block, steps = None, R // bq
+
+    def qblk(h, j, i, off):
+        return i
+
+    if masked:
+        nqb = sq_p // bq                   # q blocks in one GQA group
+        band = functools.partial(_q_band, bq=bq, bk=bk, nqb=nqb,
+                                 causal=causal, window=window)
+        per_group = _band_steps(nqb, bk, bq, causal, window)
+        steps = (R // sq_p) * per_group
+
+        def q_block(off, kj, step):
+            """(q block, whether it is visited) of grid step ``step``: the
+            group's band, group by group."""
+            g = step // per_group
+            j = step - g * per_group
+            lo, hi = band(off, kj)
+            li = jnp.clip(jnp.minimum(lo + j, hi), 0, nqb - 1)
+            return g * nqb + li, lo + j <= hi
+
+        def qblk(h, j, i, off):
+            return q_block(off[h // kv_heads], j, i)[0]
+
+    def q_rows(h, j, i, off):
+        return (0, h, qblk(h, j, i, off), 0)
+
+    def row(h, j, i, off):
+        return (h, qblk(h, j, i, off), 0)
+
+    def k_rows(h, j, i, off):
+        return (0, h, j, 0)
+
+    def out_rows(h, j, i, off):
+        return (h, j, 0)
+
+    kernel = functools.partial(
+        _int_attn_bwd_dkv_kernel, n_q=steps, lq=Lq, lk=Lk, lv=Lv, lg=Lg,
+        p_bits=p_bits, ds_bits=ds_bits, sq_p=sq_p, kv_heads=kv_heads,
+        kv_len=kv_len, causal=causal, window=window, sc=sc, bq=bq, bk=bk,
+        integer_exp=integer_exp, q_block=q_block)
+    return _attn_call(
+        kernel, banded=masked, grid=(BH, Skp // bk, steps),
+        in_blocks=[((Lq, 1, bq, hd_p), q_rows), ((Lk, 1, bk, hd_p), k_rows),
+                   ((Lv, 1, bk, hd_p), k_rows), ((Lg, 1, bq, hd_p), q_rows),
+                   ((1, bq, 1), row), ((1, bq, 1), row)],
+        out_blocks=[((1, bk, hd_p), out_rows), ((1, bk, hd_p), out_rows)],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Skp, hd_p), jnp.float32),
             jax.ShapeDtypeStruct((BH, Skp, hd_p), jnp.float32),
         ],
-        scratch_shapes=[
+        scratch=[
             pltpu.VMEM((bk, hd_p), jnp.float32),
             pltpu.VMEM((bk, hd_p), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        name="int_attn_bwd_dkv",
-        interpret=interpret,
-    )(qm, km, vm, gm, lse, delta,
-      q_off.astype(jnp.int32), exps.astype(jnp.int32))
+        name="int_attn_bwd_dkv", interpret=interpret,
+    )(qm, km, vm, gm, lse, delta, q_off=q_off, exps=exps)

@@ -76,7 +76,9 @@ import jax.numpy as jnp
 from repro import sharding
 from repro.kernels.bfp_matmul import (bfp_matmul, bfp_matmul_batched,
                                       bfp_matmul_batched_nt,
-                                      bfp_matmul_batched_tn, bfp_matmul_nt,
+                                      bfp_matmul_batched_tn, bfp_matmul_grouped,
+                                      bfp_matmul_grouped_nt,
+                                      bfp_matmul_grouped_tn, bfp_matmul_nt,
                                       bfp_matmul_tn, matmul_vmem_bytes)
 from repro.kernels.dfx_quant import (LIMB_BITS as _LIMB_BITS, _out_dtype,
                                      dfx_quantize, dfx_quantize_grouped,
@@ -112,6 +114,9 @@ _STEP_MACS = 1 << 32
 
 #: the quantize kernel's row block never exceeds this many rows.
 _QUANT_ROWS = 256
+
+#: scoped VMEM a Mosaic kernel gets when it asks for no limit.
+_SCOPED_VMEM_DEFAULT = 16 * 1024 * 1024
 
 
 def on_tpu() -> bool:
@@ -185,7 +190,7 @@ def _tiles(extent: int, sublane: bool) -> list[int]:
 
 def _pick_blocks(M: int, N: int, K: int, lx: int = 1, lw: int = 1,
                  budget: int = _MATMUL_VMEM_BUDGET,
-                 contract_rows: bool = False):
+                 contract_rows: bool = False, accumulate: bool = False):
     """Blocks ``(bm, bn, bk)`` of an (M, N) output contracting K, with
     ``lx``×``lw`` limb planes.
 
@@ -206,13 +211,22 @@ def _pick_blocks(M: int, N: int, K: int, lx: int = 1, lw: int = 1,
 
     def fits(b):
         bm, bn, bk = b
-        return (matmul_vmem_bytes(bm, bn, bk, lx, lw, Kp // bk) <= budget
+        n_k = max(2, Kp // bk) if accumulate else Kp // bk
+        return (matmul_vmem_bytes(bm, bn, bk, lx, lw, n_k) <= budget
                 and lx * lw * bm * bn * bk <= _STEP_MACS)
+
+    def rows_ok(b):
+        # a row tile under 128 is for budgets 128 rows overflow: on a v5e
+        # a (64, 3072, 2304) 3x3 step needed 73.9 MiB of scoped VMEM
+        # against the 44.3 MiB modelled, while (128, 1536, 2304) fit
+        rows, extent = (b[2], Kp) if contract_rows else (b[0], Mp)
+        return rows >= min(_LANE, extent)
 
     fitting = [b for b in itertools.product(bms, bns, bks) if fits(b)]
     if not fitting:
         return bms[0], bns[0], bks[0]
-    return max(fitting, key=lambda b: (b[2] == Kp, b[0] * b[1], b[1], b[2]))
+    return max(fitting, key=lambda b: (b[2] == Kp, rows_ok(b), b[0] * b[1],
+                                       b[1], b[2]))
 
 
 def _pad_last2(a: jax.Array, r: int, c: int) -> jax.Array:
@@ -413,6 +427,113 @@ def dfx_matmul_tiled_batched_tn(
         local, (xm, _as_planes(gm, g_bits, 3), out_exp), (2, 2, None), "sum")
 
 
+#: largest row tile of the grouped (sorted-rows) matmul: every group of rows
+#: is padded to a multiple of it.
+_GROUP_ROWS = 256
+
+
+def group_row_tile(mean_rows: int) -> int:
+    """Row tile every group is padded to: the mean group's rows in sublane
+    multiples up to 128, else ``_GROUP_ROWS`` — small enough that padding
+    stays a fraction of a mean group, and a block size ``_pick_blocks`` can
+    take whole."""
+    if mean_rows > _LANE:
+        return _GROUP_ROWS
+    return _round_up_multiple(max(mean_rows, 1), _SUBLANE)
+
+
+def _grouped_exp(a_exp, b_exp, G: int) -> jax.Array:
+    return (jnp.reshape(a_exp, (G,)) + jnp.reshape(b_exp, (G,))).astype(
+        jnp.int32)
+
+
+def dfx_matmul_grouped(
+    xm: jax.Array, x_exp: jax.Array, x_bits: int,
+    wm: jax.Array, w_exp: jax.Array, w_bits: int,
+    offsets: jax.Array, tm: int, *, interpret: bool | None = None,
+) -> jax.Array:
+    """Grouped NN: ``q(X[r])·q(W[g])`` for every row ``r`` of group ``g``.
+
+    xm: (Lx, M, K) limb planes (or logical (M, K)) with rows sorted by
+    group, group ``g`` in ``[offsets[g], offsets[g+1])``, every group a
+    multiple of the row tile ``tm``; wm: (Lw, G, K, N); x_exp/w_exp one
+    exponent per group.  Returns FP32 (M, N), zero past ``offsets[G]``.
+    Every chip runs the whole call: the rows are one chip's expert share.
+    """
+    if interpret is None:
+        interpret = not on_tpu()
+    wm = _as_planes(wm, w_bits, 3)
+    out_exp = _grouped_exp(x_exp, w_exp, wm.shape[1])
+
+    def local(xm, wm, out_exp, offsets):
+        _, M, K = xm.shape
+        N = wm.shape[-1]
+        bm, bn, bk = _pick_blocks(tm, N, K, xm.shape[0], wm.shape[0])
+        xm, wm = _pad_last2(xm, bm, bk), _pad_last2(wm, bk, bn)
+        out = bfp_matmul_grouped(xm, wm, out_exp, offsets, bm=bm, bn=bn,
+                                 bk=bk, interpret=interpret)
+        return out[:, :N]
+
+    return sharding.over_batch(
+        local, (_as_planes(xm, x_bits, 2), wm, out_exp, offsets),
+        (None, None, None, None), None)
+
+
+def dfx_matmul_grouped_nt(
+    gm: jax.Array, g_exp: jax.Array, g_bits: int,
+    wm: jax.Array, w_exp: jax.Array, w_bits: int,
+    offsets: jax.Array, tm: int, *, interpret: bool | None = None,
+) -> jax.Array:
+    """Grouped NT (dX): ``q(G[r])·q(W[g])ᵀ``, W (Lw, G, K, N) in forward
+    layout; gm (Lg, M, N) sorted rows.  Returns FP32 (M, K)."""
+    if interpret is None:
+        interpret = not on_tpu()
+    wm = _as_planes(wm, w_bits, 3)
+    out_exp = _grouped_exp(g_exp, w_exp, wm.shape[1])
+
+    def local(gm, wm, out_exp, offsets):
+        _, M, N = gm.shape
+        K = wm.shape[2]
+        bm, bn, bk = _pick_blocks(tm, K, N, gm.shape[0], wm.shape[0])
+        gm, wm = _pad_last2(gm, bm, bk), _pad_last2(wm, bn, bk)
+        out = bfp_matmul_grouped_nt(gm, wm, out_exp, offsets, bm=bm, bn=bn,
+                                    bk=bk, interpret=interpret)
+        return out[:, :K]
+
+    return sharding.over_batch(
+        local, (_as_planes(gm, g_bits, 2), wm, out_exp, offsets),
+        (None, None, None, None), None)
+
+
+def dfx_matmul_grouped_tn(
+    xm: jax.Array, x_exp: jax.Array, x_bits: int,
+    gm: jax.Array, g_exp: jax.Array, g_bits: int,
+    offsets: jax.Array, tm: int, *, interpret: bool | None = None,
+) -> jax.Array:
+    """Grouped TN (dW): ``q(X[rows of g])ᵀ·q(G[rows of g])`` for every
+    group; xm (Lx, M, K), gm (Lg, M, N) sorted rows.  Returns FP32
+    (G, K, N); a group of padding rows only gets zeros."""
+    if interpret is None:
+        interpret = not on_tpu()
+    G = offsets.shape[0] - 1
+    out_exp = _grouped_exp(x_exp, g_exp, G)
+
+    def local(xm, gm, out_exp, offsets):
+        _, M, K = xm.shape
+        N = gm.shape[-1]
+        bm, bn, bk = _pick_blocks(K, N, tm, xm.shape[0], gm.shape[0],
+                                  contract_rows=True, accumulate=True)
+        xm, gm = _pad_last2(xm, bk, bm), _pad_last2(gm, bk, bn)
+        out = bfp_matmul_grouped_tn(xm, gm, out_exp, offsets, bm=bm, bn=bn,
+                                    bk=bk, interpret=interpret)
+        return out[:, :K, :N]
+
+    return sharding.over_batch(
+        local, (_as_planes(xm, x_bits, 2), _as_planes(gm, g_bits, 2),
+                out_exp, offsets),
+        (None, None, None, None), None)
+
+
 def quantize_vmem_bytes(br: int, n: int, out_bytes: int,
                         stochastic: bool) -> int:
     """VMEM bytes one grid step of the quantize kernel keeps resident.
@@ -439,6 +560,20 @@ def _quant_rows(M: int, N: int, bits: int, stochastic: bool,
     return br
 
 
+def _quant_vmem_limit(br: int, N: int, bits: int, stochastic: bool,
+                      limb_planes: bool) -> int | None:
+    """Scoped VMEM for a quantize step whose blocks and in-kernel f32
+    temporaries (the rounded mantissa and one per limb plane, about
+    ``L + 2`` tiles) pass Mosaic's 16 MiB default: half again their size.
+    None below it, where the call keeps the default (a v5e compile of a
+    (256, 2304) 3-limb step used 19.1 MiB against a 8.3 MiB block model)."""
+    L = n_limbs(bits) if limb_planes else 1
+    out_bytes = L if limb_planes else jnp.dtype(_out_dtype(bits)).itemsize
+    need = (quantize_vmem_bytes(br, N, out_bytes, stochastic)
+            + br * N * 4 * (L + 2))
+    return None if need <= _SCOPED_VMEM_DEFAULT else need + need // 2
+
+
 def quantize_pallas(x: jax.Array, exp: jax.Array, bits: int,
                     u: jax.Array | None = None,
                     interpret: bool | None = None,
@@ -462,7 +597,9 @@ def quantize_pallas(x: jax.Array, exp: jax.Array, bits: int,
             if u is not None:
                 u = jnp.pad(u, ((0, pm), (0, 0)))
         out = dfx_quantize(x, exp, bits=bits, u=u, br=br,
-                           interpret=interpret, limb_planes=limb_planes)
+                           interpret=interpret, limb_planes=limb_planes,
+                           vmem_limit=_quant_vmem_limit(
+                               br, N, bits, u is not None, limb_planes))
         return out[:, :M] if limb_planes else out[:M]
 
     args = (x, exp) + (() if u is None else (u,))
@@ -697,15 +834,20 @@ def attention_bwd(qm: jax.Array, q_exp: jax.Array,
 
     ``gm`` is the quantized upstream-grad limb stack in q layout; ``lse``
     (B, KV, G, Sq) and ``delta`` (B, Sq, KV, G) the forward-saved rows;
-    ``ds_exp`` the bound-derived dS scale exponent (traced int32).  Returns
+    ``ds_exp`` the bound-derived dS scale exponent (traced int32) of a call
+    that is neither causal nor windowed, and None for one that is: that
+    call scales dS per tile (kernels/int_attention.py).  Returns
     ``(dq, dk, dv)`` in model layout.  Padded lse rows are filled with
     +1e30 so the recomputed ``p`` vanishes there exactly.
     """
+    if (ds_exp is None) != (causal or window is not None):
+        raise ValueError("a causal or windowed call scales dS per tile and "
+                         "takes no ds_exp; any other call needs one")
     if interpret is None:
         interpret = not on_tpu()
-    exps = jnp.stack([jnp.reshape(q_exp, ()), jnp.reshape(k_exp, ()),
-                      jnp.reshape(v_exp, ()), jnp.reshape(g_exp, ()),
-                      jnp.reshape(ds_exp, ())]).astype(jnp.int32)
+    exps = jnp.stack([jnp.reshape(e, ()) for e in (
+        q_exp, k_exp, v_exp, g_exp, ds_exp) if e is not None]
+    ).astype(jnp.int32)
 
     def local(qm, km, vm, gm, lse, delta, q_off, exps):
         _, B, Sq, KV, G, hd = qm.shape

@@ -296,14 +296,16 @@ def int_attention_bwd_ref(qm: jax.Array, q_exp, km: jax.Array, k_exp,
                           vm: jax.Array, v_exp, gm: jax.Array, g_exp,
                           lse: jax.Array, delta: jax.Array, ds_exp,
                           p_bits: int, ds_bits: int, q_offset,
-                          *, causal: bool, window=None):
+                          *, causal: bool, window=None, tile=None):
     """Integer flash-attention backward oracle: ``(dq, dk, dv)`` in f64.
 
     ``gm`` is the quantized dO mantissa (B, Sq, KV, G, hd); ``lse``
     (B, KV, G, Sq) and ``delta`` (B, Sq, KV, G) are the forward-saved rows
     (delta = rowsum of the RAW upstream grad times O); ``ds_exp`` is the
-    bound-derived static dS scale exponent.  P and dS quantize exactly as
-    the kernels do — same clips, same static exponents.
+    bound-derived dS scale exponent, or None with ``tile = (bq, bk)``: each
+    tile of ``bq`` queries of one head by ``bk`` keys then takes the frexp
+    exponent of its largest |dS|, less ``ds_bits - 1``.  P and dS quantize
+    exactly as the kernels do — same clips, same exponents.
     """
     q, k, v, g = _f64(qm), _f64(km), _f64(vm), _f64(gm)
     B, Sq, KV, G, hd = q.shape
@@ -313,7 +315,6 @@ def int_attention_bwd_ref(qm: jax.Array, q_exp, km: jax.Array, k_exp,
     ks = 2.0 ** float(np.asarray(k_exp))
     vs = 2.0 ** float(np.asarray(v_exp))
     gs = 2.0 ** float(np.asarray(g_exp))
-    dss = 2.0 ** float(np.asarray(ds_exp))
     s = np.einsum("bqhgd,bkhd->bhgqk", q, k) * (qs * ks * sc)
     okb = _attn_mask_ref(B, Sq, Sk, q_offset, causal, window)[:, None, None]
     s = np.where(okb, s, -1e30)
@@ -324,10 +325,21 @@ def int_attention_bwd_ref(qm: jax.Array, q_exp, km: jax.Array, k_exp,
     dp = np.einsum("bqhgd,bkhd->bhgqk", g, v) * (gs * vs)
     dl = _f64(delta).transpose(0, 2, 3, 1)[..., None]
     ds = p * (dp - dl)
+    if ds_exp is None:
+        bq, bk = tile
+        nq, nk = -(-Sq // bq), -(-Sk // bk)
+        a = np.zeros(ds.shape[:3] + (nq * bq, nk * bk))
+        a[..., :Sq, :Sk] = np.abs(ds)
+        a = a.reshape(ds.shape[:3] + (nq, bq, nk, bk)).max(axis=(4, 6))
+        e = np.frexp(a)[1] - (ds_bits - 1)
+        dss = 2.0 ** np.repeat(np.repeat(e, bq, axis=3), bk, axis=4)[
+            ..., :Sq, :Sk]
+    else:
+        dss = 2.0 ** float(np.asarray(ds_exp))
     dlim = float(2 ** (ds_bits - 1) - 1)
-    dsm = np.clip(np.round(ds / dss), -dlim, dlim)
-    dq = np.einsum("bhgqk,bkhd->bqhgd", dsm, k) * (ks * dss * sc)
-    dk = np.einsum("bhgqk,bqhgd->bkhd", dsm, q) * (qs * dss * sc)
+    dsm = np.clip(np.round(ds / dss), -dlim, dlim) * dss
+    dq = np.einsum("bhgqk,bkhd->bqhgd", dsm, k) * (ks * sc)
+    dk = np.einsum("bhgqk,bqhgd->bkhd", dsm, q) * (qs * sc)
     return (jnp.asarray(dq, jnp.float32), jnp.asarray(dk, jnp.float32),
             jnp.asarray(dv, jnp.float32))
 

@@ -35,7 +35,7 @@ import numpy as np
 from repro import utils
 from repro.core import health, int_ops
 from repro.core.qpolicy import QuantLike, ensure_scope
-from repro.models.config import ArchConfig
+from repro.models.config import ArchConfig, RopeConfig
 
 Array = jax.Array
 Params = Dict[str, Any]
@@ -121,6 +121,45 @@ def rope(x: Array, positions: Array, theta: float) -> Array:
         positions = positions[None, :]
     ang = positions[..., None].astype(jnp.float32) * freqs       # (B, S, half)
     cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+
+
+def yarn_inv_freq(rc: RopeConfig, hd: int) -> np.ndarray:
+    """YaRN inverse frequencies (float32, ``hd // 2``), as Hugging Face's
+    ``_compute_yarn_parameters`` gives them: interpolated by ``factor``
+    below the truncated correction range of ``beta_fast``/``beta_slow``
+    rotations at ``original_max_position``, extrapolated above it, and
+    blended linearly across it."""
+    def dim_of(rotations):
+        return (hd * np.log(rc.original_max_position
+                            / (rotations * 2 * np.pi))
+                / (2 * np.log(rc.theta)))
+
+    low = max(np.floor(dim_of(rc.beta_fast)), 0)
+    high = min(np.ceil(dim_of(rc.beta_slow)), hd - 1)
+    if low == high:
+        high += 0.001
+    pos = rc.theta ** (np.arange(0, hd, 2, dtype=np.float32) / hd)
+    ramp = np.clip((np.arange(hd // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    extrapolate = 1 - ramp
+    inv = (1 / (rc.factor * pos)) * (1 - extrapolate) + (1 / pos) * extrapolate
+    return inv.astype(np.float32)
+
+
+def apply_rope(x: Array, positions: Array, rc: RopeConfig) -> Array:
+    """RoPE of one attention kind: ``rope`` at ``rc.theta``, or YaRN with
+    cos and sin scaled by ``rc.attention_factor``."""
+    if rc.kind == "default":
+        return rope(x, positions, rc.theta)
+    half = x.shape[-1] // 2
+    freqs = jnp.asarray(yarn_inv_freq(rc, x.shape[-1]))
+    if positions.ndim == 1:
+        positions = positions[None, :]
+    ang = positions[..., None].astype(jnp.float32) * freqs
+    cos = (jnp.cos(ang) * rc.attention_factor)[:, :, None, :]
+    sin = (jnp.sin(ang) * rc.attention_factor)[:, :, None, :]
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
 
@@ -240,8 +279,11 @@ def attention_apply(
     cache_index: Array | int = 0,
     kv_override: Optional[Tuple[Array, Array]] = None,  # cross-attention
     use_rope: bool = True,
+    kind: Optional[str] = None,
 ) -> Tuple[Array, Optional[Tuple[Array, Array]]]:
-    """Returns (out, updated_cache). x: (B, S, D)."""
+    """Returns (out, updated_cache). x: (B, S, D).  ``kind`` names the
+    layer's attention kind in ``cfg.layer_pattern`` (its window and RoPE);
+    None for a config whose layers are alike."""
     B, S, D = x.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     G = H // KV
@@ -267,10 +309,11 @@ def attention_apply(
         positions = jnp.atleast_1d(idx)[:, None] + jnp.arange(S)  # (1|B, S)
         positions = jnp.broadcast_to(positions, (B, S))
     if use_rope:
-        q = rope(q.reshape(B, S, H, hd), positions, cfg.rope_theta).reshape(
+        rc = cfg.rope_for(kind)
+        q = apply_rope(q.reshape(B, S, H, hd), positions, rc).reshape(
             B, S, KV, G, hd)
         if kv_override is None:
-            k = rope(k, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, rc)
 
     new_cache = None
     if kv_cache is not None:
@@ -301,7 +344,7 @@ def attention_apply(
     # only as the disabled/fp32 reference.
     leaf_qk = sc.leaf("qk")
     leaf_pv = sc.leaf("pv")
-    win = cfg.sliding_window if causal else None
+    win = cfg.window_for(kind) if causal else None
     if leaf_qk.enabled:
         o = int_ops.int_attention(q, k, v, jnp.asarray(q_offset),
                                   subkey(key, 4), leaf_qk, leaf_pv,
@@ -357,13 +400,14 @@ def mlp_apply(p: Params, x: Array, cfg: ArchConfig, qcfg: QuantLike,
 # =========================================================================
 
 def moe_init(key, cfg: ArchConfig) -> Params:
-    D, F, E = cfg.d_model, cfg.d_ff, cfg.moe_experts
+    """The router over every expert; the FFNs of the experts held here."""
+    D, F, E, H = cfg.d_model, cfg.d_ff, cfg.moe_experts, cfg.moe_held
     ks = jax.random.split(key, 5)
     p = {
         "router": _init(ks[0], (D, E)),
-        "wg_e": _init(ks[1], (E, D, F)),
-        "wu_e": _init(ks[2], (E, D, F)),
-        "wd_e": _init(ks[3], (E, F, D)),
+        "wg_e": _init(ks[1], (H, D, F)),
+        "wu_e": _init(ks[2], (H, D, F)),
+        "wd_e": _init(ks[3], (H, F, D)),
     }
     if cfg.moe_shared_dff:
         p["shared"] = mlp_init(ks[4], cfg, d_ff=cfg.moe_shared_dff)
@@ -372,8 +416,13 @@ def moe_init(key, cfg: ArchConfig) -> Params:
 
 @_named_module("moe")
 def moe_apply(p: Params, x: Array, cfg: ArchConfig, qcfg: QuantLike,
-              key: Optional[Array]) -> Tuple[Array, Array]:
-    """Returns (out, aux_loss). x: (B, S, D).
+              key: Optional[Array]) -> Tuple[Array, Tuple[Array, Array]]:
+    """Returns (out, (aux_loss, dropped)). x: (B, S, D); ``dropped`` counts
+    the (token, choice) pairs that found no room (f32).
+
+    A config with ``moe_shard`` runs the drop-free expert layer over the
+    experts it holds (``_expert_share_apply``); any other dispatches by
+    capacity, as below, and drops what overflows.
 
     Dispatch is **shard-local** (per data-parallel group): the token→slot
     position is computed with a cumsum *within* each DP group and every group
@@ -385,6 +434,8 @@ def moe_apply(p: Params, x: Array, cfg: ArchConfig, qcfg: QuantLike,
     """
     from repro import sharding as _sh
 
+    if cfg.moe_shard is not None:
+        return _expert_share_apply(p, x, cfg, ensure_scope(qcfg), key)
     B, S, D = x.shape
     E, K = cfg.moe_experts, cfg.moe_topk
     T = B * S
@@ -462,7 +513,93 @@ def moe_apply(p: Params, x: Array, cfg: ArchConfig, qcfg: QuantLike,
     if "shared" in p:
         y = y + mlp_apply(p["shared"], xf, cfg, sc.child("shared"),
                           subkey(key, 4))
-    return y.reshape(B, S, D), aux
+    dropped = jnp.sum(jnp.logical_not(keep)).astype(jnp.float32)
+    return y.reshape(B, S, D), (aux, dropped)
+
+
+def moe_aux_zero() -> Tuple[Array, Array]:
+    """A layer stack's running ``(load-balancing term, dropped pairs)``
+    before its first layer; a dense layer adds this."""
+    return jnp.float32(0), jnp.float32(0)
+
+
+def _expert_share_apply(p: Params, x: Array, cfg: ArchConfig, sc,
+                        key: Optional[Array]):
+    """Drop-free expert layer over the share ``cfg.moe_shard`` of experts.
+
+    The router's product and softmax cover all ``moe_experts``; each token
+    takes its top ``moe_topk`` and renormalises their gates.  The (token,
+    choice) pairs that land on a held expert are sorted by expert into rows
+    — each expert's rows padded to the row tile, and the buffer sized for
+    the case where every token picks ``min(topk, held)`` held experts, so
+    nothing is ever dropped — and the three SwiGLU products run on the
+    grouped limb matmul (``int_ops.int_grouped_linear``), one DFX exponent
+    per expert.  The gated rows are summed back per token: this layer's
+    part of the result, what the absent experts would add left out.
+    Returns ``(y, (load-balancing term over every expert, held pairs past
+    the rows))``; the second is 0 by the rows' sizing, and counted.
+
+    The router product is float32 at full precision (a departure from the
+    integer layers, like the softmax it feeds): top-k selection is
+    discrete, and an integer product flips near-ties against the float
+    reference.
+    """
+    from repro.kernels import ops as kops
+
+    B, S, D = x.shape
+    E, K = cfg.moe_experts, cfg.moe_topk
+    first, H = cfg.moe_shard
+    T = B * S
+    health.probe(sc.path, x, sc.leaf("wg_e").act_bits)
+    xf = x.reshape(T, D)
+    with jax.named_scope("router"):
+        logits = jnp.dot(xf, p["router"],
+                         precision=jax.lax.Precision.HIGHEST)
+        probs = int_ops.int_softmax(logits, sc.leaf("router"))
+        gate, sel = jax.lax.top_k(probs, K)                      # (T, K)
+        gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+        density = jnp.mean(jax.nn.one_hot(sel[:, 0], E), axis=0)
+        aux = E * jnp.sum(density * jnp.mean(probs, axis=0))
+
+    with jax.named_scope("dispatch"):
+        tm = kops.group_row_tile(-(-T * K // E))    # an expert's mean rows
+        rows = -(-(T * min(K, H) + H * tm) // tm) * tm
+        local = sel.reshape(-1) - first
+        held = (local >= 0) & (local < H)
+        grp = jnp.where(held, local, H).astype(jnp.int32)         # (T*K,)
+        counts = jnp.zeros((H + 1,), jnp.int32).at[grp].add(1)[:H]
+        padded = jnp.maximum(tm, (counts + tm - 1) // tm * tm)
+        offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                   jnp.cumsum(padded)]).astype(jnp.int32)
+        starts = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                  jnp.cumsum(counts)])
+        order = jnp.argsort(grp, stable=True)
+        g_sorted = jnp.minimum(grp[order], H - 1)
+        row_sorted = (offsets[g_sorted]
+                      + jnp.arange(T * K, dtype=jnp.int32) - starts[g_sorted])
+        row = jnp.zeros((T * K,), jnp.int32).at[order].set(row_sorted)
+        dropped = jnp.sum(held & (row >= rows))
+        row = jnp.where(held, row, rows)      # other shares' pairs: a zero row
+        tok = jnp.full((rows,), T, jnp.int32).at[row].set(
+            jnp.arange(T * K, dtype=jnp.int32) // K, mode="drop")
+        x_rows = jnp.concatenate([xf, jnp.zeros((1, D), xf.dtype)])[tok]
+
+    with jax.named_scope("experts"):
+        g = int_ops.int_grouped_linear(x_rows, p["wg_e"], offsets,
+                                       subkey(key, 1), sc.leaf("wg_e"), tm)
+        u = int_ops.int_grouped_linear(x_rows, p["wu_e"], offsets,
+                                       subkey(key, 2), sc.leaf("wu_e"), tm)
+        h = int_ops.int_activation(g, sc.leaf("act"), "silu") * u
+        y_rows = int_ops.int_grouped_linear(h, p["wd_e"], offsets,
+                                            subkey(key, 3), sc.leaf("wd_e"),
+                                            tm)
+
+    with jax.named_scope("combine"):
+        y_rows = jnp.concatenate([y_rows, jnp.zeros((1, D), y_rows.dtype)])
+        w = jnp.where(held, gate.reshape(-1), 0.0).reshape(T, K)
+        row = row.reshape(T, K)
+        y = sum(y_rows[row[:, k]] * w[:, k, None] for k in range(K))
+    return y.reshape(B, S, D), (aux, dropped.astype(jnp.float32))
 
 
 # =========================================================================

@@ -10,6 +10,33 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Tuple
 
+#: attention kinds a ``layer_pattern`` names: ``sliding`` layers attend
+#: within ``sliding_window``, ``full`` layers to every earlier position.
+LAYER_KINDS = ("sliding", "full")
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeConfig:
+    """Rotary embedding of one attention kind.
+
+    ``default`` is plain RoPE at ``theta``.  ``yarn`` is YaRN (Peng et al.
+    2023) as Hugging Face's ``_compute_yarn_parameters`` computes it: the
+    inverse frequencies blend interpolation by ``factor`` and extrapolation
+    over the truncated correction range that ``beta_fast``/``beta_slow``
+    rotations give at ``original_max_position``, and cos and sin are both
+    scaled by ``attention_factor``.
+    """
+    theta: float = 10000.0
+    kind: str = "default"               # default | yarn
+    factor: float = 1.0
+    original_max_position: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def __post_init__(self):
+        assert self.kind in ("default", "yarn"), self.kind
+
 
 @dataclasses.dataclass(frozen=True)
 class ArchConfig:
@@ -29,12 +56,22 @@ class ArchConfig:
     rope_theta: float = 10000.0
     sliding_window: Optional[int] = None
     max_position_embeddings: int = 1 << 20
+    # the repeating period of attention kinds (``LAYER_KINDS``), in the
+    # published order; empty: every layer alike, windowed by
+    # ``sliding_window`` when it is set
+    layer_pattern: Tuple[str, ...] = ()
+    # (kind, RoPE) pairs; a kind not listed takes plain RoPE at rope_theta
+    rope_by_kind: Tuple[Tuple[str, RopeConfig], ...] = ()
 
     # --- MoE ---
     moe_experts: int = 0
     moe_topk: int = 0
     moe_shared_dff: int = 0         # width of the always-on shared expert MLP
     moe_capacity_factor: float = 1.25
+    # (first, count): the router's experts this program holds, its share of
+    # an expert-parallel deployment (all of them for a whole model), routed
+    # drop-free; None holds every expert and dispatches by capacity
+    moe_shard: Optional[Tuple[int, int]] = None
 
     # --- SSM (Mamba2 / SSD) ---
     ssm_state: int = 0
@@ -62,6 +99,27 @@ class ArchConfig:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
         if self.n_heads and self.n_kv_heads:
             assert self.n_heads % self.n_kv_heads == 0, (self.n_heads, self.n_kv_heads)
+        if self.layer_pattern:
+            assert set(self.layer_pattern) <= set(LAYER_KINDS), self.layer_pattern
+            assert self.n_layers % len(self.layer_pattern) == 0, (
+                self.n_layers, self.layer_pattern)
+        if self.moe_shard is not None:
+            first, count = self.moe_shard
+            assert 0 <= first and 0 < count and first + count <= self.moe_experts, (
+                self.moe_shard, self.moe_experts)
+
+    # ---- per-kind attention ---------------------------------------------
+    def window_for(self, kind: Optional[str]) -> Optional[int]:
+        """Window of a layer of ``kind`` (None: the config's one kind)."""
+        return None if kind == "full" else self.sliding_window
+
+    def rope_for(self, kind: Optional[str]) -> RopeConfig:
+        return dict(self.rope_by_kind).get(kind, RopeConfig(theta=self.rope_theta))
+
+    @property
+    def moe_held(self) -> int:
+        """Experts held here: the share's count, else every expert."""
+        return self.moe_shard[1] if self.moe_shard else self.moe_experts
 
     # ---- derived quantities ---------------------------------------------
     @property
@@ -99,7 +157,7 @@ class ArchConfig:
             per = attn + 2 * D
             if self.moe_experts:
                 per += D * self.moe_experts              # router
-                per += self.moe_experts * 3 * D * F      # expert FFNs
+                per += self.moe_held * 3 * D * F         # expert FFNs
                 if self.moe_shared_dff:
                     per += 3 * D * self.moe_shared_dff
             else:
@@ -112,7 +170,7 @@ class ArchConfig:
         if not self.moe_experts:
             return self.param_count()
         D, F = self.d_model, self.d_ff
-        dense_extra = (self.moe_experts - self.moe_topk) * 3 * D * F
+        dense_extra = (self.moe_held - min(self.moe_topk, self.moe_held)) * 3 * D * F
         return int(self.param_count() - self.n_layers * dense_extra)
 
     # ---- smoke-test reduction -------------------------------------------
@@ -131,7 +189,11 @@ class ArchConfig:
         )
         if self.moe_experts:
             changes.update(moe_experts=4, moe_topk=2,
-                           moe_shared_dff=128 if self.moe_shared_dff else 0)
+                           moe_shared_dff=128 if self.moe_shared_dff else 0,
+                           moe_shard=None if self.moe_shard is None else
+                           (0, 4 if self.moe_held == self.moe_experts else 2))
+        if self.layer_pattern:          # one whole period
+            changes.update(n_layers=len(self.layer_pattern))
         if self.family in ("ssm", "hybrid"):
             changes.update(ssm_state=16, ssm_headdim=32, ssm_chunk=16,
                            n_layers=4 if self.family == "hybrid" else 2)

@@ -129,15 +129,15 @@ def lm_init(key, cfg: ArchConfig) -> Params:
 # =========================================================================
 
 def _attn_block(bp: Params, x: Array, cfg: ArchConfig, qcfg: QuantLike,
-                key, *, cache=None, cache_index=0):
+                key, *, cache=None, cache_index=0, kind=None):
     sc = ensure_scope(qcfg)
     h = blocks.norm_apply(bp["ln1"], x, cfg, sc.child("ln1"), subkey(key, 0))
     h, new_cache = blocks.attention_apply(
         bp["attn"], h, cfg, sc.child("attn"), subkey(key, 1),
-        kv_cache=cache, cache_index=cache_index)
+        kv_cache=cache, cache_index=cache_index, kind=kind)
     x = sharding.constrain_tokens(x + h)
     h = blocks.norm_apply(bp["ln2"], x, cfg, sc.child("ln2"), subkey(key, 2))
-    aux = jnp.float32(0)
+    aux = blocks.moe_aux_zero()
     if "moe" in bp:
         h, aux = blocks.moe_apply(bp["moe"], h, cfg, sc.child("moe"),
                                   subkey(key, 3))
@@ -163,7 +163,8 @@ def _uniform_stack_scope(sc, L: int, leaves, what: str):
 
 def _backbone_train(params: Params, x: Array, cfg: ArchConfig,
                     qcfg: QuantLike, key) -> Tuple[Array, Array]:
-    """Runs all layers (training/prefill, no cache). Returns (x, aux_sum)."""
+    """Runs all layers (training/prefill, no cache). Returns (x, (aux_sum,
+    dropped_sum))."""
     L = cfg.n_layers
     sc = ensure_scope(qcfg)
 
@@ -173,6 +174,8 @@ def _backbone_train(params: Params, x: Array, cfg: ArchConfig,
         # leak tracers out of the loop trace
         with health.suspend():
             return _backbone_train_ssm(params, x, cfg, sc, key)
+    if cfg.layer_pattern:
+        return _backbone_train_periods(params, x, cfg, sc, key)
 
     def make_body(bsc):
         def body(carry, inp):
@@ -183,12 +186,81 @@ def _backbone_train(params: Params, x: Array, cfg: ArchConfig,
             # module-global sink (core/health.py)
             with health.frame() as fr:
                 x, a, _ = _attn_block(bp, x, cfg, bsc, subkey(key, idx))
-            return (x, aux + a), fr.harvest()
+            return (x, _add(aux, a)), fr.harvest()
         return utils.checkpoint(body)
 
     groups = layer_groups(sc, L, _block_leaves(cfg))
-    (x, aux), hs = blocks.scan_stack(make_body, (x, jnp.float32(0)), groups,
+    (x, aux), hs = blocks.scan_stack(make_body, (x, blocks.moe_aux_zero()),
+                                     groups,
                                      (params["blocks"], jnp.arange(L)))
+    health.record_stacked(hs)
+    return x, aux
+
+
+# -------------------------------------------------------------------------
+# Layer patterns: one scan step per whole period
+# -------------------------------------------------------------------------
+# A config with a ``layer_pattern`` (e.g. three sliding-window layers and
+# one full layer) scans over periods: each step runs the period's layers in
+# the published order, each under the named scope of its kind (``sliding``,
+# ``full``) inside ``blocks`` and each rematerialised on its own, as the
+# layers of a uniform stack are.  Stacked inputs are viewed (L/P, P, ...).
+
+def _period_groups(sc, cfg: ArchConfig):
+    """``layer_groups`` in whole periods; a policy that splits a period
+    cannot be scanned by periods."""
+    P = len(cfg.layer_pattern)
+    groups = layer_groups(sc, cfg.n_layers, _block_leaves(cfg))
+    if any(s % P or e % P for s, e, _ in groups):
+        raise PolicyScopeError(
+            f"quantization policy splits the {P}-layer period of "
+            f"{cfg.name}; scope rules must resolve alike over whole periods")
+    return [(s // P, e // P, g) for s, e, g in groups]
+
+
+def _by_period(cfg: ArchConfig, tree):
+    P = len(cfg.layer_pattern)
+    return jax.tree.map(
+        lambda a: a.reshape((a.shape[0] // P, P) + a.shape[1:]), tree)
+
+
+def _at(tree, j: int):
+    return jax.tree.map(lambda a: a[j], tree)
+
+
+def _add(a, b):
+    return jax.tree.map(jnp.add, a, b)
+
+
+def _backbone_train_periods(params: Params, x: Array, cfg: ArchConfig,
+                            sc, key) -> Tuple[Array, Any]:
+    def make_layer(bsc, kind):
+        def layer(x, bp, idx):
+            with jax.named_scope(kind), health.frame() as fr:
+                x, a, _ = _attn_block(bp, x, cfg, bsc, subkey(key, idx),
+                                      kind=kind)
+            return x, a, fr.harvest()
+        return utils.checkpoint(layer)
+
+    def make_body(bsc):
+        layers = {k: make_layer(bsc, k) for k in set(cfg.layer_pattern)}
+
+        def body(carry, inp):
+            x, aux = carry
+            bp, idx = inp
+            harvests = []
+            for j, kind in enumerate(cfg.layer_pattern):
+                x, a, h = layers[kind](x, _at(bp, j), idx[j])
+                aux = _add(aux, a)
+                harvests += [h] if h else []
+            merged = (functools.reduce(health.merge, harvests)
+                      if harvests else None)
+            return (x, aux), merged
+        return body
+
+    (x, aux), hs = blocks.scan_stack(
+        make_body, (x, blocks.moe_aux_zero()), _period_groups(sc, cfg),
+        _by_period(cfg, (params["blocks"], jnp.arange(cfg.n_layers))))
     health.record_stacked(hs)
     return x, aux
 
@@ -211,7 +283,7 @@ def _backbone_train_ssm(params: Params, x: Array, cfg: ArchConfig,
         groups = layer_groups(sc, L, _MAMBA_LEAVES)
         x, _ = blocks.scan_stack(make_mamba_body, x, groups,
                                  (params["blocks"], jnp.arange(L)))
-        return x, jnp.float32(0)
+        return x, blocks.moe_aux_zero()
 
     # hybrid: groups of ``every`` mamba layers + the shared attn block
     bsc = _uniform_stack_scope(sc, L, _MAMBA_LEAVES, "hybrid")
@@ -233,7 +305,7 @@ def _backbone_train_ssm(params: Params, x: Array, cfg: ArchConfig,
         return x, None
 
     x, _ = utils.scan(group_body, x, (grouped, jnp.arange(G)))
-    return x, jnp.float32(0)
+    return x, blocks.moe_aux_zero()
 
 
 # =========================================================================
@@ -281,7 +353,7 @@ def lm_loss(params: Params, batch: Dict[str, Array], cfg: ArchConfig,
     tokens = sharding.constrain_batch(batch["tokens"])
     x = _embed(params, tokens, cfg, qcfg, key,
                prefix_embeds=batch.get("patch_embeds"))
-    x, aux = _backbone_train(params, x, cfg, qcfg, key)
+    x, (aux, dropped) = _backbone_train(params, x, cfg, qcfg, key)
     if cfg.vlm_prefix:
         x = x[:, -tokens.shape[1]:]     # loss only over text positions
     logits = _logits(params, x, cfg, qcfg, key)
@@ -291,9 +363,11 @@ def lm_loss(params: Params, batch: Dict[str, Array], cfg: ArchConfig,
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     ll = jnp.take_along_axis(logp, lab[..., None], axis=-1)[..., 0]
     loss = -jnp.sum(ll * valid) / jnp.maximum(jnp.sum(valid), 1)
+    counters = {}
     if cfg.moe_experts:
         loss = loss + 0.01 * aux / cfg.n_layers
-    return loss, {"ce": loss, "aux": aux}
+        counters = {"moe_dropped": dropped}
+    return loss, {"ce": loss, "aux": aux, **counters}
 
 
 # =========================================================================
@@ -440,19 +514,48 @@ def lm_prefill_cache(params: Params, tokens: Array, cache: Params,
     x = _embed(params, tokens, cfg, sc, key)
     L = cfg.n_layers
 
+    if cfg.layer_pattern:
+        def make_period(bsc):
+            def body(carry, inp):
+                x, aux = carry
+                bp, ck, cv, _ = inp
+                new = []
+                for j, kind in enumerate(cfg.layer_pattern):
+                    with jax.named_scope(kind):
+                        x, a, kv = _attn_block(
+                            _at(bp, j), x, cfg, bsc, None,
+                            cache=(ck[j], cv[j]), cache_index=index,
+                            kind=kind)
+                    aux = _add(aux, a)
+                    new.append(kv)
+                return (x, aux), tuple(jnp.stack(c) for c in zip(*new))
+            return body
+
+        with health.suspend():     # serve-path scan has no harvest channel
+            (x, _), (nk, nv) = blocks.scan_stack(
+                make_period, (x, blocks.moe_aux_zero()),
+                _period_groups(sc, cfg),
+                _by_period(cfg, (params["blocks"], cache["k"], cache["v"],
+                                 jnp.arange(L))))
+        nk, nv = (a.reshape((L,) + a.shape[2:]) for a in (nk, nv))
+        logits = _logits(params, x[:, -1:], cfg, sc, key)
+        new_index = index + tokens.shape[1]
+        return logits, _constrain_cache({"k": nk, "v": nv,
+                                         "index": new_index})
+
     def make_body(bsc):
         def body(carry, inp):
             x, aux = carry
             bp, ck, cv, idx = inp
             x, a, ncache = _attn_block(bp, x, cfg, bsc, None,
                                        cache=(ck, cv), cache_index=index)
-            return (x, aux + a), ncache
+            return (x, _add(aux, a)), ncache
         return body
 
     groups = layer_groups(sc, L, _block_leaves(cfg))
     with health.suspend():     # serve-path scan has no harvest channel
         (x, _), (nk, nv) = blocks.scan_stack(
-            make_body, (x, jnp.float32(0)), groups,
+            make_body, (x, blocks.moe_aux_zero()), groups,
             (params["blocks"], cache["k"], cache["v"], jnp.arange(L)))
     logits = _logits(params, x[:, -1:], cfg, sc, key)
     new_index = index + tokens.shape[1]

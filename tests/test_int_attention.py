@@ -19,6 +19,7 @@ import pytest
 from repro.core import dfx, int_ops
 from repro.core.qconfig import PRESETS, QuantConfig
 from repro.core.qpolicy import QuantPolicy, ensure_scope, rule
+from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.models import blocks
 
@@ -119,17 +120,17 @@ def test_fwd_matches_f64_oracle(case):
         < 1e-5, case
 
 
-def test_bwd_matches_f64_oracle():
-    B, Sq, Sk, KV, G, hd = 2, 13, 48, 2, 3, 24
+def _bwd_vs_oracle(B, Sq, Sk, KV, G, hd, off, causal):
+    """The kernels' (dq, dk, dv) and the f64 oracle's: a causal call with
+    each (bq, bk) tile's dS exponent, any other with the norm bound's."""
     cfg = dataclasses.replace(QuantConfig.preset("int8"),
                               stochastic_grad=False, backend="pallas",
                               warn_stability=False)
     q, k, v = _qkv(B=B, Sq=Sq, Sk=Sk, KV=KV, G=G, hd=hd)
-    off = 32
 
     def f(q, k, v):
         return int_ops.int_attention(q, k, v, jnp.asarray(off), None,
-                                     cfg, cfg, True, None)
+                                     cfg, cfg, causal, None)
 
     o, vjp = jax.vjp(f, q, k, v)
     g = jax.random.normal(jax.random.fold_in(KEY, 9), o.shape)
@@ -143,21 +144,58 @@ def test_bwd_matches_f64_oracle():
         np.asarray(qq.m, np.float64), float(qq.exp),
         np.asarray(qk.m, np.float64), float(qk.exp),
         np.asarray(qv.m, np.float64), float(qv.exp),
-        bits, off_v, causal=True)
+        bits, off_v, causal=causal)
     delta = np.sum(np.asarray(g, np.float64) * np.asarray(o, np.float64),
                    axis=-1)
-    ds_exp = int(int_ops._ds_exp(int_ops._max_row_norm(g),
-                                 int_ops._max_row_norm(v), cfg.grad_bits))
-    dq_r, dk_r, dv_r = kref.int_attention_bwd_ref(
+    ds_exp, tile = None, None
+    if causal:
+        bq, _, bk, _, _ = kops._attn_dims(Sq, Sk, hd)
+        tile = (bq, bk)
+    else:
+        ds_exp = int(int_ops._ds_exp(int_ops._max_row_norm(g),
+                                     int_ops._max_row_norm(v),
+                                     cfg.grad_bits))
+    ref = kref.int_attention_bwd_ref(
         np.asarray(qq.m, np.float64), float(qq.exp),
         np.asarray(qk.m, np.float64), float(qk.exp),
         np.asarray(qv.m, np.float64), float(qv.exp),
         np.asarray(qg.m, np.float64), float(qg.exp),
-        lse, delta, ds_exp, bits, cfg.grad_bits, off_v, causal=True)
+        lse, delta, ds_exp, bits, cfg.grad_bits, off_v, causal=causal,
+        tile=tile)
+    return (dq, dk, dv), ref
+
+
+def test_bwd_matches_f64_oracle():
+    (dq, dk, dv), (dq_r, dk_r, dv_r) = _bwd_vs_oracle(
+        2, 13, 48, 2, 3, 24, 32, True)
     for name, got, ref in (("dq", dq, dq_r), ("dk", dk, dk_r),
                            ("dv", dv, dv_r)):
         scale = float(np.abs(ref).max()) + 1e-12
         assert float(np.abs(np.asarray(got, np.float64) - ref).max()) \
+            / scale < 1e-4, name
+
+
+def test_bwd_ds_tiles_match_f64_oracle():
+    """dQ and dK of a causal call over three q tiles (Sq = 300: 128-row
+    tiles in the kernels' layout, the last ragged), each tile's dS at its
+    own exponent.  dV does not use dS; over 300 rows an 8-bit P mantissa
+    can round across a half between the oracle's f64 and the kernel's f32
+    ``lse``, so it is held to the single-tile case above."""
+    (dq, dk, _), (dq_r, dk_r, _) = _bwd_vs_oracle(
+        2, 300, 48, 2, 3, 24, 32, True)
+    for name, got, ref in (("dq", dq, dq_r), ("dk", dk, dk_r)):
+        scale = float(np.abs(ref).max()) + 1e-12
+        assert float(np.abs(np.asarray(got, np.float64) - ref).max()) \
+            / scale < 1e-4, name
+
+
+def test_bwd_matches_f64_oracle_bidirectional():
+    """Neither causal nor windowed (the encoders): the norm bound's one
+    dS exponent."""
+    got, ref = _bwd_vs_oracle(2, 13, 48, 2, 3, 24, 0, False)
+    for name, a, b in zip(("dq", "dk", "dv"), got, ref):
+        scale = float(np.abs(b).max()) + 1e-12
+        assert float(np.abs(np.asarray(a, np.float64) - b).max()) \
             / scale < 1e-4, name
 
 

@@ -1,5 +1,6 @@
 """Per-arch smoke tests (reduced configs, brief requirement) + consistency
 properties: decode-vs-prefill equality, quantized-vs-fp32 loss proximity."""
+import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -133,7 +134,7 @@ def test_moe_capacity_drops_tokens_at_scale():
     cfg = registry.get_config("mixtral-8x7b").reduced()
     p = B.moe_init(KEY, cfg)
     x = jax.random.normal(KEY, (8, 1024, cfg.d_model))   # T*K = 16384 > 4096
-    y, aux = B.moe_apply(p, x, cfg, QuantConfig.fp32(), None)
+    y, (aux, _) = B.moe_apply(p, x, cfg, QuantConfig.fp32(), None)
     assert y.shape == x.shape
     assert np.isfinite(float(aux))
 
@@ -165,3 +166,23 @@ def test_param_counts_match_published_scale():
     for arch, n in expect.items():
         got = registry.get_config(arch).param_count()
         assert abs(got - n) / n < 0.15, (arch, got, n)
+
+
+def test_mellum_registry_config_routes_without_drops():
+    """The registered Mellum2 config holds every expert as one share, so
+    its expert layers route drop-free even when routing is skewed; the
+    same config dispatched by capacity (``moe_shard`` None) drops pairs."""
+    cfg = registry.get_config("mellum2-12b-a2.5b")
+    assert cfg.moe_shard == (0, cfg.moe_experts)
+    cfg = cfg.reduced()
+    assert cfg.moe_shard == (0, cfg.moe_experts)
+    params = lm.lm_init(KEY, cfg)
+    moe = params["blocks"]["moe"]
+    moe["router"] = moe["router"].at[..., 0].add(10.0)    # every token: 0
+    tokens = jax.random.randint(KEY, (3, 800), 0, cfg.vocab)   # T*K > 4096
+    batch = {"tokens": tokens, "labels": tokens}
+    _, m = lm.lm_loss(params, batch, cfg, QuantConfig.fp32(), KEY)
+    assert float(m["moe_dropped"]) == 0
+    _, m = lm.lm_loss(params, batch, dataclasses.replace(cfg, moe_shard=None),
+                      QuantConfig.fp32(), KEY)
+    assert float(m["moe_dropped"]) > 0

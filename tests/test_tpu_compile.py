@@ -216,3 +216,62 @@ def test_int16_step_compiles_on_one_chip(topo, monkeypatch):
     monkeypatch.setattr(ops, "on_tpu", lambda: True)
     qcfg = dataclasses.replace(QuantConfig.int16(), backend="pallas")
     assert "bfp_matmul_tn" in _bert_step_text(topo.devices[:1], qcfg, 2)
+
+
+#: one chip's share of Mellum2-12B-A2.5B at B=2, S=8192: 8 held experts
+#: of width 896 at d=2304, the rows sized for every token's 8 choices
+#: landing here plus a 256-row tile per expert
+D_M, F_M, E_M, TM_M = 2304, 896, 8, 256
+ROWS_M = 2 * 8192 * 8 + E_M * TM_M
+
+
+@pytest.mark.parametrize("direction", ["nn", "nt", "tn"])
+def test_grouped_matmul_compiles(one_chip, direction):
+    """The grouped limb matmul at the expert widths, w16 (3x3 limbs): the
+    gate/up product (K=2304, N=896) and the down product (K=896, N=2304)
+    in each direction, group offsets as a scalar-prefetch operand."""
+    i8 = functools.partial(_s, dtype=jnp.int8, where=one_chip)
+    e = _s((E_M,), jnp.int32, one_chip)
+    off = _s((E_M + 1,), jnp.int32, one_chip)
+    for k, n in ((D_M, F_M), (F_M, D_M)):
+        if direction == "nn":
+            fn = lambda a, b, e, o: ops.dfx_matmul_grouped(          # noqa: E731
+                a, e, 16, b, e, 16, o, TM_M, interpret=False)
+            a, b = i8((3, ROWS_M, k)), i8((3, E_M, k, n))
+        elif direction == "nt":
+            fn = lambda a, b, e, o: ops.dfx_matmul_grouped_nt(       # noqa: E731
+                a, e, 16, b, e, 16, o, TM_M, interpret=False)
+            a, b = i8((3, ROWS_M, n)), i8((3, E_M, k, n))
+        else:
+            fn = lambda a, b, e, o: ops.dfx_matmul_grouped_tn(       # noqa: E731
+                a, e, 16, b, e, 16, o, TM_M, interpret=False)
+            a, b = i8((3, ROWS_M, k)), i8((3, ROWS_M, n))
+        assert "tpu_custom_call" in _compile(fn, a, b, e, off), (k, n)
+
+
+@pytest.mark.parametrize("window", [1024, None], ids=["sliding", "full"])
+def test_banded_attention_compiles(one_chip, window):
+    """Causal integer attention at Mellum2's S=8192, 32 query / 4 KV heads
+    of 128, int16, forward and backward: the k-block index maps read the
+    query offsets from the scalar prefetch, and the backward takes each
+    dS tile's exponent in-kernel."""
+    Bm, Sm, KV, G, hd = 2, 8192, 4, 8, 128
+    q = _s((3, Bm, Sm, KV, G, hd), jnp.int8, one_chip)
+    kv = _s((3, Bm, Sm, KV, hd), jnp.int8, one_chip)
+    e = _s((), jnp.int32, one_chip)
+    off = _s((Bm,), jnp.int32, one_chip)
+
+    def fwd(q, k, v, e, off):
+        return ops.attention_fwd(q, e, k, e, v, e, off, 16, causal=True,
+                                 window=window, interpret=False)
+
+    assert "tpu_custom_call" in _compile(fwd, q, kv, kv, e, off)
+
+    def bwd(q, k, v, lse, delta, e, off):
+        return ops.attention_bwd(q, e, k, e, v, e, q, e, lse, delta, None,
+                                 off, 16, 16, causal=True, window=window,
+                                 interpret=False)
+
+    lse = _s((Bm, KV, G, Sm), jnp.float32, one_chip)
+    delta = _s((Bm, Sm, KV, G), jnp.float32, one_chip)
+    assert "tpu_custom_call" in _compile(bwd, q, kv, kv, lse, delta, e, off)
