@@ -15,52 +15,71 @@ Layout ("rows" form, produced by kernels/ops.py wrappers):
 * Q / dO limb planes  ``(L, BH, R, hd_p)``   with ``BH = B·KV`` (batch ×
   kv-head) and ``R = G·Sq_p`` (GQA group-major rows: group ``g`` owns rows
   ``[g·Sq_p, (g+1)·Sq_p)``) — so one grid axis covers batch and head, and
-  every q block of ``bq`` rows lies inside a single group (``bq | Sq_p``),
+  every block of query rows lies inside a single group (it divides
+  ``Sq_p``),
 * K / V limb planes   ``(L, BH, Sk_p, hd_p)``,
 * O                   ``(BH, R, hd_p)`` f32,
 * lse / delta         ``(BH, R, 1)``   f32.
 
-Online softmax (forward): per 128-wide K block the kernel keeps the running
-row max ``m``, normalizer ``l`` and f32 accumulator in VMEM scratch across
-the innermost ("arbitrary") grid axis:
+Score tiles and grid blocks.  The numerics are defined on ``(bq, bk)``
+*score tiles* (128 × 128, or ``bq`` × 128 for short query runs): the
+online-softmax update runs once per key tile, a causal or windowed call
+takes each tile's own dS exponent, and every product that contracts over
+keys (PV, dQ) or over queries (dV, dK) is summed in f32 one tile at a
+time.  A grid step covers a *grid block* of ``nr × nt`` such tiles
+(``blocks=(rows, keys)``, multiples of the tile chosen by
+``ops._attn_blocks``) and walks them in the one order that reproduces the
+one-tile-a-step grid bit for bit: key tiles ascending for each row tile
+(forward, dq), query tiles ascending — group by group — for each key tile
+(dkv).  Different row tiles (forward, dq) or key tiles (dkv) are
+independent, so a call whose tiles are all live (neither causal nor
+windowed) computes them together, as one wide dot along the dimension that
+is not contracted: every output element is still the same exact int32 dot
+and the same f32 arithmetic.  Fewer, larger grid steps pay the fixed cost
+of a Pallas grid step less often; nothing else changes.
+
+Online softmax (forward), per key tile, with the running row max ``m``,
+normalizer ``l`` and f32 accumulator in VMEM scratch across the innermost
+("arbitrary") grid axis:
 
     s      = sc · Σ_pairs (q_limb · k_limbᵀ)    int32 MXU, f32 combine
     m_new  = max(m, rowmax(s));   p = where(ok, exp(s - m_new), 0)
     l      = l·α + rowsum(p),     α = exp(m - m_new)
     acc    = acc·α + Σ_pairs (quant(p) · v_limb) · 2^{-(p_bits-1)}
 
-``p ≤ 1`` by construction (``m_new`` dominates the in-block row max), so P
+``p ≤ 1`` by construction (``m_new`` dominates the in-tile row max), so P
 quantizes with the *static* exponent ``-(p_bits-1)`` — no extra max pass.
 ``l`` accumulates the **unquantized** ``p`` (the normalizer is a kept op);
 only the PV contraction sees the quantized mantissa.  The ``where``-guard on
-``exp`` is essential: a fully masked block has ``s == m_new == -1e30`` and
+``exp`` is essential: a fully masked tile has ``s == m_new == -1e30`` and
 a bare ``exp(0) = 1`` would poison ``l``.
 
-Backward (flash-attention-2 style, two kernels): ``dq`` iterates K blocks
-innermost accumulating one q-row block; ``dk/dv`` iterates q blocks
-innermost accumulating one k-row block.  Both recompute ``p`` from the saved
-row ``lse`` (no S×S residual), quantize ``p`` and ``dS = p·(dp − δ)`` to
-limb planes **in-register** (the digit split of kernels/dfx_quant.py), and
-run every contraction on the integer MXU path.  ``dS``'s scale exponent
-is, in a call that is neither causal nor windowed, a *bound-derived* int32
-operand (see core.int_ops); a causal or windowed call takes each (bq, bk)
-tile's own, the DFX exponent of the tile's largest magnitude
-(``_tile_ds_exp``), since there a row's P spreads over up to Sk keys and
-the bound's ``p <= 1`` leaves ~log2(Sk) of its bits unused.  No max pass
-over dS either way: a tile's maximum is taken where the tile is made, and
-each tile's product is scaled on its own before the f32 accumulation.
+Backward (flash-attention-2 style, two kernels): ``dq`` walks key tiles
+accumulating each row tile; ``dk/dv`` walks query tiles accumulating each
+key tile.  Both recompute ``p`` from the saved row ``lse`` (no S×S
+residual), quantize ``p`` and ``dS = p·(dp − δ)`` to limb planes
+**in-register** (the digit split of kernels/dfx_quant.py), and run every
+contraction on the integer MXU path.  ``dS``'s scale exponent is, in a
+call that is neither causal nor windowed, a *bound-derived* int32 operand
+(see core.int_ops); a causal or windowed call takes each (bq, bk) tile's
+own, the DFX exponent of the tile's largest magnitude (``_tile_ds_exp``),
+since there a row's P spreads over up to Sk keys and the bound's
+``p <= 1`` leaves ~log2(Sk) of its bits unused.  No max pass over dS
+either way: a tile's maximum is taken where the tile is made, and each
+tile's product is scaled on its own before the f32 accumulation.
 
 Masking: ``qpos = q_offset[b] + i_local`` (per-row offsets for KV-cache
 decode / chunked prefill / continuous-batching slots), ``kpos`` the global
 K column; validity is ``kpos < kv_len`` (ragged tail) ∧ causal
 (``kpos ≤ qpos``) ∧ sliding window (``kpos > qpos − window``).  A causal
-or windowed call visits only the blocks that validity can reach (the
-band calls at the end of this file); a call that is neither runs the
-full grid.
+or windowed call visits only the grid blocks that validity can reach (the
+band calls at the end of this file) and, inside a block, only the tiles it
+can reach (``pl.when`` on each tile's band); a call that is neither runs
+the full grid.
 
 Accumulator budget (quantlint QL006): every integer dot is digit×digit —
 |limb| ≤ 64 — so the int32 partials are bounded by ``64²·K`` with
-``K ≤ max(hd_p, bq, bk)``: ≤ 2^19 at the default 128 blocks, five orders of
+``K ≤ max(hd_p, bq, bk)``: ≤ 2^19 at 128-wide tiles, five orders of
 magnitude inside int32.
 """
 from __future__ import annotations
@@ -75,6 +94,7 @@ from jax.experimental.pallas import tpu as pltpu
 # single source of the limb radix + digit split: quantized P / dS planes cut
 # in-kernel MUST match the shifts the quantize kernel uses for Q/K/V.
 from repro.core import iapprox
+from repro.kernels.bfp_matmul import _SCOPED_VMEM_DEFAULT
 from repro.kernels.dfx_quant import (  # noqa: E402
     LIMB_BITS, _round_clip, _split_planes, n_limbs)
 
@@ -84,44 +104,80 @@ _BIG_NEG = -1e30
 #: index: Mosaic loads those only from SMEM.
 _SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
+#: lanes a (rows, 1) f32 block or scratch occupies in VMEM.
+_LANES = 128
 
-def _limb_dot(a_ref, b_ref, la: int, lb: int, dims, exp_f32, shift: int):
+
+def attention_vmem_bytes(kernel: str, rows: int, keys: int, hd_p: int,
+                         limbs: int, tile: tuple[int, int],
+                         all_live: bool) -> int:
+    """VMEM bytes one grid step of ``kernel`` (``"fwd"``, ``"dq"`` or
+    ``"dkv"``) keeps resident over a ``rows`` × ``keys`` grid block.
+
+    Counted: the double-buffered blocks (int8 limb planes, ``limbs`` of
+    each operand; f32 outputs; a (rows, 1) f32 column takes 128 lanes), the
+    f32 scratch, and the in-step f32 temporaries of one visit — S, P, dP,
+    dS, their limb planes, the mask and a pair product, about
+    ``8 + 2·limbs`` values over the scores one visit covers (a tile; all
+    rows of the block against a key tile, or a query tile against all its
+    keys, when every tile is live) — plus two f32 partials of its output.
+    """
+    col = rows * _LANES * 4
+    q_side = limbs * rows * hd_p
+    k_side = limbs * keys * hd_p
+    if kernel == "fwd":
+        io = q_side + 2 * k_side + rows * hd_p * 4 + col
+        scratch = 2 * col + rows * hd_p * 4
+    elif kernel == "dq":
+        io = 2 * q_side + 2 * k_side + 2 * col + rows * hd_p * 4
+        scratch = rows * hd_p * 4
+    else:
+        io = 2 * q_side + 2 * k_side + 2 * col + 2 * keys * hd_p * 4
+        scratch = 2 * keys * hd_p * 4
+    bq, bk = tile
+    if kernel == "dkv":                # a query tile against keys
+        visit_rows, visit_cols = bq, (keys if all_live else bk)
+        out = visit_cols
+    else:                              # rows against a key tile
+        visit_rows, visit_cols = (rows if all_live else bq), bk
+        out = visit_rows
+    temps = (8 + 2 * limbs) * visit_rows * visit_cols * 4 + 2 * out * hd_p * 4
+    return 2 * io + scratch + temps
+
+
+def _vmem_limit(kernel, blocks, hd_p, limbs, tile, all_live):
+    """Scoped VMEM to ask Mosaic for: the modelled working set plus half
+    again, or None (the default) where that fits the default."""
+    need = attention_vmem_bytes(kernel, *blocks, hd_p, limbs, tile, all_live)
+    need += need // 2
+    return None if need <= _SCOPED_VMEM_DEFAULT else need
+
+
+def _planes(ref, n: int, rows):
+    """The ``n`` int8 limb planes of an ``(L, 1, R, C)`` block over
+    ``rows``."""
+    return [ref[j, 0, rows, :] for j in range(n)]
+
+
+def _pair_dot(a, b, dims, exp_f32, shift: int):
     """Σ over limb pairs of ``dot(a[ja], b[jb])`` with the ordered f32
     combine of kernels/bfp_matmul.py.
 
-    ``a_ref``/``b_ref`` are ``(L, 1, rows, cols)`` int8 plane blocks; the
-    scale is applied as ``exp2(exp) * 2^(7(ja+jb)+shift)`` — ``exp2`` once
-    on the raw (traced) exponent, then a power-of-two *literal* multiply —
-    never folded into the exp2 argument (not correctly rounded at every
-    integer arg; same contract as the matmul combine).
+    ``a`` and ``b`` are lists of 2-D digit planes: int8 limb planes, or
+    the just-quantized P / dS as f32 digits, cast to int8 at the MXU
+    boundary (every digit lies in [-64, 64]).  The scale is applied as
+    ``exp2(exp) * 2^(7(ja+jb)+shift)`` — ``exp2`` once on the raw (traced)
+    exponent, then a power-of-two *literal* multiply — never folded into
+    the exp2 argument (not correctly rounded at every integer arg; same
+    contract as the matmul combine).
     """
     lc, rc = dims
     scale0 = jnp.exp2(exp_f32)
     out = None
-    for ja in range(la):
-        for jb in range(lb):
+    for ja, pa in enumerate(a):
+        for jb, pb in enumerate(b):
             part = jax.lax.dot_general(
-                a_ref[ja, 0], b_ref[jb, 0],
-                (((lc,), (rc,)), ((), ())),
-                preferred_element_type=jnp.int32,
-            )
-            part = (part.astype(jnp.float32) * scale0) * (
-                2.0 ** (LIMB_BITS * (ja + jb) + shift))
-            out = part if out is None else out + part
-    return out
-
-
-def _plane_dot(planes, b_ref, lb: int, dims, exp_f32, shift: int):
-    """Like ``_limb_dot`` but the lhs limbs are in-register f32 digit planes
-    (the just-quantized P or dS), cast to int8 at the MXU boundary — every
-    digit lies in [-64, 64]."""
-    lc, rc = dims
-    scale0 = jnp.exp2(exp_f32)
-    out = None
-    for ja, plane in enumerate(planes):
-        for jb in range(lb):
-            part = jax.lax.dot_general(
-                plane.astype(jnp.int8), b_ref[jb, 0],
+                pa.astype(jnp.int8), pb,
                 (((lc,), (rc,)), ((), ())),
                 preferred_element_type=jnp.int32,
             )
@@ -144,20 +200,21 @@ def _tile_ds_exp(ds, ds_bits: int):
     return (field - 126 - (ds_bits - 1)).astype(jnp.float32)
 
 
-def _valid_mask(off, qi, kj, *, bq: int, bk: int, sq_p: int, kv_len: int,
+def _valid_mask(off, row0, col0, shape, *, sq_p: int, kv_len: int,
                 causal: bool, window):
-    """(bq, bk) bool validity of score block (qi, kj).
+    """Bool validity of the ``shape`` scores whose first row is R-axis row
+    ``row0`` and whose first column is key ``col0``.
 
     ``off`` is the scalar per-batch-row query offset; the row index inside
-    the group is recovered from the group-major R axis — ``bq | sq_p`` so a
-    q block never straddles two GQA groups and the group id is the scalar
-    ``(qi·bq) // sq_p``.
+    the group is recovered from the group-major R axis — the rows lie in
+    one GQA group (every block of rows divides ``sq_p``), whose id is the
+    scalar ``row0 // sq_p``.
     """
-    g_blk = (qi * bq) // sq_p
-    i_local = (qi * bq - g_blk * sq_p
-               + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0))
+    g_blk = row0 // sq_p
+    i_local = (row0 - g_blk * sq_p
+               + jax.lax.broadcasted_iota(jnp.int32, shape, 0))
     qpos = off + i_local
-    kpos = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    kpos = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     ok = kpos < kv_len
     if causal:
         ok = jnp.logical_and(ok, kpos <= qpos)
@@ -167,46 +224,56 @@ def _valid_mask(off, qi, kj, *, bq: int, bk: int, sq_p: int, kv_len: int,
 
 
 def _k_band(off, qi, *, bq: int, bk: int, sq_p: int, n_k: int, causal: bool,
-            window):
-    """First and last K block that q block ``qi`` can see under the causal
-    and window masks (``_valid_mask``); a scalar computation, run by the
-    index maps and by the kernel alike."""
+            window, xp=jnp):
+    """First and last K block of width ``bk`` that query block ``qi`` of
+    ``bq`` rows can see under the causal and window masks
+    (``_valid_mask``); a scalar computation, run by the index maps (on grid
+    blocks) and by the kernels (on score tiles) alike, and over arrays of
+    blocks by the block chooser (``xp=numpy``)."""
     g_blk = (qi * bq) // sq_p
     r0 = qi * bq - g_blk * sq_p
     lo, hi = 0, n_k - 1
     if causal:
-        hi = jnp.minimum(hi, (off + r0 + bq - 1) // bk)
+        hi = xp.minimum(hi, (off + r0 + bq - 1) // bk)
     if window is not None:
-        lo = jnp.maximum(off + r0 - window + 1, 0) // bk
+        lo = xp.maximum(off + r0 - window + 1, 0) // bk
     return lo, hi
 
 
-def _q_band(off, kj, *, bq: int, bk: int, nqb: int, causal: bool, window):
-    """First and last q block, inside every GQA group of ``nqb`` blocks,
-    that can see K block ``kj``; ``hi < lo`` when none can."""
+def _q_band(off, kj, *, bq: int, bk: int, nqb: int, causal: bool, window,
+            xp=jnp):
+    """First and last query block of ``bq`` rows, inside every GQA group of
+    ``nqb`` blocks, that can see K block ``kj`` of width ``bk``; ``hi <
+    lo`` when none can."""
     lo, hi = 0, nqb - 1
     if causal:
-        lo = jnp.maximum(kj * bk - off, 0) // bq
+        lo = xp.maximum(kj * bk - off, 0) // bq
     if window is not None:
         last = kj * bk + bk + window - 2 - off
-        hi = jnp.minimum(hi, jnp.where(last < 0, -1, last // bq))
+        hi = xp.minimum(hi, xp.where(last < 0, -1, last // bq))
     return lo, hi
 
 
-def _k_visits(n_k: int, *, bq: int, bk: int, sq_p: int, kv_heads: int,
-              causal: bool, window):
-    """``(band, grid steps, k-block index map)`` of a call that steps over
-    key blocks innermost (forward, dq): every block in turn, or, when
-    causal or windowed, the band ``_k_band`` gives, clamped at its end."""
+def _k_visits(n_k: int, *, bq: int, bk: int, rows: int, keys: int,
+              sq_p: int, kv_heads: int, causal: bool, window):
+    """``(block band, tile band, grid steps, k-block index map)`` of a
+    call over ``n_k`` key tiles that steps over key blocks of ``keys``
+    innermost (forward, dq): every block in turn, or, when causal or
+    windowed, the band ``_k_band`` gives a block of ``rows`` queries,
+    clamped at its end; the tile band is ``_k_band``'s for one ``bq`` ×
+    ``bk`` score tile."""
+    n_blocks = n_k * bk // keys
     if not causal and window is None:
-        return None, n_k, lambda h, i, j, off: j
-    band = functools.partial(_k_band, bq=bq, bk=bk, sq_p=sq_p, n_k=n_k,
-                             causal=causal, window=window)
+        return None, None, n_blocks, lambda h, i, j, off: j
+    mask = dict(sq_p=sq_p, causal=causal, window=window)
+    band = functools.partial(_k_band, bq=rows, bk=keys, n_k=n_blocks, **mask)
+    tile_band = functools.partial(_k_band, bq=bq, bk=bk, n_k=n_k, **mask)
 
     def kblk(h, i, j, off):
         lo, hi = band(off[h // kv_heads], i)
         return jnp.minimum(lo + j, hi)
-    return band, _band_steps(n_k, bq, bk, causal, window), kblk
+    return (band, tile_band, _band_steps(n_blocks, rows, keys, causal, window),
+            kblk)
 
 
 def _band_steps(n: int, rows: int, cols: int, causal: bool, window) -> int:
@@ -229,8 +296,64 @@ def _visit_band(visit, band, off, blk, step):
     pl.when(lo + step <= hi)(lambda: visit(jnp.minimum(lo + step, hi)))
 
 
+def _walk_keys(tile, tile_band, off, qb, kb, *, bq: int, bk: int, nr: int,
+               nt: int):
+    """Run ``tile(r0, rows, k0, cols)`` (block-local offsets and sizes)
+    over the score tiles of query block ``qb`` × key block ``kb``, key
+    tiles ascending for every row tile (forward, dq).  With every tile
+    live (``tile_band`` None) all rows go at once; else each row tile in
+    turn, skipping the key tiles its band ``tile_band(off, qi)`` leaves
+    out.  Each key tile is one loop iteration, as it was one grid step."""
+    def key_tile(r0, rows, lo=None, hi=None):
+        def body(t, carry):
+            k0 = pl.multiple_of(t * bk, bk)
+            if lo is None:
+                tile(r0, rows, k0, bk)
+            else:
+                kj = kb * nt + t
+                pl.when(jnp.logical_and(lo <= kj, kj <= hi))(
+                    lambda: tile(r0, rows, k0, bk))
+            return carry
+        jax.lax.fori_loop(0, nt, body, 0)
+
+    if tile_band is None:
+        key_tile(0, nr * bq)
+        return
+
+    def row_tile(c, carry):
+        key_tile(pl.multiple_of(c * bq, bq), bq, *tile_band(off, qb * nr + c))
+        return carry
+    jax.lax.fori_loop(0, nr, row_tile, 0)
+
+
+def _walk_queries(tile, tile_band, off, qg, kb, *, bq: int, bk: int,
+                  nr: int, nt: int):
+    """Run ``tile(r0, rows, k0, cols)`` over the score tiles of key block
+    ``kb`` × the query block ``qg`` (counted inside its GQA group), query
+    tiles ascending for every key tile (dkv).  With every tile live all
+    keys go at once; else each key tile on its own, skipping the query
+    tiles its band ``tile_band(off, kj)`` leaves out.  Each query tile is
+    one loop iteration, as it was one grid step."""
+    def q_tile(c, carry):
+        r0 = pl.multiple_of(c * bq, bq)
+        if tile_band is None:
+            tile(r0, bq, 0, nt * bk)
+            return carry
+        qi = qg * nr + c
+
+        def key_tile(t, carry):
+            lo, hi = tile_band(off, kb * nt + t)
+            k0 = pl.multiple_of(t * bk, bk)
+            pl.when(jnp.logical_and(lo <= qi, qi <= hi))(
+                lambda: tile(r0, bq, k0, bk))
+            return carry
+        return jax.lax.fori_loop(0, nt, key_tile, carry)
+    jax.lax.fori_loop(0, nr, q_tile, 0)
+
+
 def _attn_call(kernel, *, banded: bool, grid, in_blocks, out_blocks,
-               out_shape, scratch, name: str, interpret: bool):
+               out_shape, scratch, name: str, interpret: bool,
+               vmem_limit: int | None):
     """One attention ``pallas_call``, returned as ``f(*blocked, q_off=,
     exps=)``.  ``in_blocks``/``out_blocks`` pair each block shape with its
     index map ``(h, i, j, off)``; the kernel takes the blocked refs, then
@@ -266,7 +389,8 @@ def _attn_call(kernel, *, banded: bool, grid, in_blocks, out_blocks,
             out_specs=out_specs, scratch_shapes=scratch),
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit),
         name=name,
         interpret=interpret,
     )
@@ -290,6 +414,14 @@ def _p_exp(x, integer_exp: bool):
     return jnp.exp(x)
 
 
+def _grid_block(blocks, bq: int, bk: int) -> tuple[int, int, int, int]:
+    """``(rows, keys, nr, nt)`` of a grid block: ``blocks`` (None = one
+    score tile) and its row and key tiles."""
+    rows, keys = blocks or (bq, bk)
+    assert rows % bq == 0 and keys % bk == 0, (blocks, bq, bk)
+    return rows, keys, rows // bq, keys // bk
+
+
 # =========================================================================
 # Forward
 # =========================================================================
@@ -298,10 +430,11 @@ def _int_attn_fwd_kernel(q_ref, k_ref, v_ref, off_ref, exp_ref,
                          o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
                          n_k: int, lq: int, lk: int, lv: int, p_bits: int,
                          sq_p: int, kv_heads: int, kv_len: int, causal: bool,
-                         window, sc: float, bq: int, bk: int,
-                         integer_exp: bool, band=None):
+                         window, sc: float, bq: int, bk: int, nr: int,
+                         nt: int, integer_exp: bool, band=None,
+                         tile_band=None):
     h = pl.program_id(0)
-    qi = pl.program_id(1)
+    qb = pl.program_id(1)
     step = pl.program_id(2)
 
     @pl.when(step == 0)
@@ -315,29 +448,36 @@ def _int_attn_fwd_kernel(q_ref, k_ref, v_ref, off_ref, exp_ref,
     ve = exp_ref[2].astype(jnp.float32)
     off = off_ref[h // kv_heads]
 
-    def visit(kj):
-        ok = _valid_mask(off, qi, kj, bq=bq, bk=bk, sq_p=sq_p,
-                         kv_len=kv_len, causal=causal, window=window)
-        s = _limb_dot(q_ref, k_ref, lq, lk, (1, 1), qe + ke, 0) * sc
-        s = jnp.where(ok, s, _BIG_NEG)
+    def visit(kb):
+        def tile(r0, rows, k0, cols):
+            rs, ks = pl.ds(r0, rows), pl.ds(k0, cols)
+            ok = _valid_mask(off, qb * nr * bq + r0, kb * nt * bk + k0,
+                             (rows, cols), sq_p=sq_p, kv_len=kv_len,
+                             causal=causal, window=window)
+            s = _pair_dot(_planes(q_ref, lq, rs), _planes(k_ref, lk, ks),
+                          (1, 1), qe + ke, 0) * sc
+            s = jnp.where(ok, s, _BIG_NEG)
 
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        # the where-guard is load-bearing: a fully masked block has
-        # s == m_new == _BIG_NEG and exp(0) = 1 would corrupt l
-        p = jnp.where(ok, _p_exp(s - m_new, integer_exp), 0.0)
-        alpha = _p_exp(m_prev - m_new, integer_exp)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        m_scr[...] = m_new
+            m_prev = m_scr[rs, :]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            # the where-guard is load-bearing: a fully masked tile has
+            # s == m_new == _BIG_NEG and exp(0) = 1 would corrupt l
+            p = jnp.where(ok, _p_exp(s - m_new, integer_exp), 0.0)
+            alpha = _p_exp(m_prev - m_new, integer_exp)
+            l_scr[rs, :] = (l_scr[rs, :] * alpha
+                            + jnp.sum(p, axis=1, keepdims=True))
+            m_scr[rs, :] = m_new
 
-        # P quantizes at the static exponent -(p_bits-1): p <= 1 by
-        # construction
-        pm = _round_clip(jnp.round(p * (2.0 ** (p_bits - 1))), p_bits)
-        pv = _plane_dot(_split_planes(pm, n_limbs(p_bits)), v_ref, lv,
-                        (1, 0), ve, -(p_bits - 1))
-        acc_scr[...] = acc_scr[...] * alpha + pv
+            # P quantizes at the static exponent -(p_bits-1): p <= 1 by
+            # construction
+            pm = _round_clip(jnp.round(p * (2.0 ** (p_bits - 1))), p_bits)
+            pv = _pair_dot(_split_planes(pm, n_limbs(p_bits)),
+                           _planes(v_ref, lv, ks), (1, 0), ve, -(p_bits - 1))
+            acc_scr[rs, :] = acc_scr[rs, :] * alpha + pv
 
-    _visit_band(visit, band, off, qi, step)
+        _walk_keys(tile, tile_band, off, qb, kb, bq=bq, bk=bk, nr=nr, nt=nt)
+
+    _visit_band(visit, band, off, qb, step)
 
     @pl.when(step == n_k - 1)
     def _epilogue():
@@ -352,7 +492,7 @@ def _int_attn_fwd_kernel(q_ref, k_ref, v_ref, off_ref, exp_ref,
 
 @functools.partial(jax.jit, static_argnames=(
     "p_bits", "sq_p", "kv_heads", "kv_len", "causal", "window", "sc",
-    "bq", "bk", "interpret", "integer_exp"))
+    "bq", "bk", "blocks", "interpret", "integer_exp"))
 def int_attn_fwd(
     qm: jax.Array,          # (Lq, BH, R, hd_p) int8 limb planes
     km: jax.Array,          # (Lk, BH, Sk_p, hd_p) int8 limb planes
@@ -369,25 +509,28 @@ def int_attn_fwd(
     sc: float,
     bq: int = 128,
     bk: int = 128,
+    blocks: tuple[int, int] | None = None,
     interpret: bool = False,
     integer_exp: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """Fused forward: ``(o, lse)`` — (BH, R, hd_p) and (BH, R, 1) f32.
 
-    ``integer_exp=True`` swaps the in-kernel online softmax's FP32 exp for
-    the iapprox fixed-point form (kept_ops="integer"); the running-max /
-    normalizer recurrence is unchanged."""
+    ``bq`` × ``bk`` is the score tile, ``blocks`` the grid block (rows,
+    keys; None for one tile a step).  ``integer_exp=True`` swaps the
+    in-kernel online softmax's FP32 exp for the iapprox fixed-point form
+    (kept_ops="integer"); the running-max / normalizer recurrence is
+    unchanged."""
     Lq, BH, R, hd_p = qm.shape
     Lk, BH2, Skp, hd2 = km.shape
     Lv = vm.shape[0]
     assert BH == BH2 and hd_p == hd2 and vm.shape[1:] == km.shape[1:], (
         qm.shape, km.shape, vm.shape)
-    assert R % bq == 0 and Skp % bk == 0 and sq_p % bq == 0, (
-        R, Skp, sq_p, bq, bk)
-    masked = causal or window is not None
-    band, steps, kblk = _k_visits(Skp // bk, bq=bq, bk=bk, sq_p=sq_p,
-                                  kv_heads=kv_heads, causal=causal,
-                                  window=window)
+    rows, keys, nr, nt = _grid_block(blocks, bq, bk)
+    band, tile_band, steps, kblk = _k_visits(
+        Skp // bk, bq=bq, bk=bk, rows=rows, keys=keys, sq_p=sq_p,
+        kv_heads=kv_heads, causal=causal, window=window)
+    assert R % rows == 0 and Skp % keys == 0 and sq_p % rows == 0, (
+        R, Skp, sq_p, rows, keys)
 
     def q_rows(h, i, j, off):
         return (0, h, i, 0)
@@ -401,28 +544,31 @@ def int_attn_fwd(
     kernel = functools.partial(
         _int_attn_fwd_kernel, n_k=steps, lq=Lq, lk=Lk, lv=Lv,
         p_bits=p_bits, sq_p=sq_p, kv_heads=kv_heads, kv_len=kv_len,
-        causal=causal, window=window, sc=sc, bq=bq, bk=bk,
-        integer_exp=integer_exp, band=band)
+        causal=causal, window=window, sc=sc, bq=bq, bk=bk, nr=nr, nt=nt,
+        integer_exp=integer_exp, band=band, tile_band=tile_band)
     return _attn_call(
-        kernel, banded=masked, grid=(BH, R // bq, steps),
-        in_blocks=[((Lq, 1, bq, hd_p), q_rows), ((Lk, 1, bk, hd_p), k_rows),
-                   ((Lv, 1, bk, hd_p), k_rows)],
-        out_blocks=[((1, bq, hd_p), out_rows), ((1, bq, 1), out_rows)],
+        kernel, banded=band is not None, grid=(BH, R // rows, steps),
+        in_blocks=[((Lq, 1, rows, hd_p), q_rows),
+                   ((Lk, 1, keys, hd_p), k_rows),
+                   ((Lv, 1, keys, hd_p), k_rows)],
+        out_blocks=[((1, rows, hd_p), out_rows), ((1, rows, 1), out_rows)],
         out_shape=[
             jax.ShapeDtypeStruct((BH, R, hd_p), jnp.float32),
             jax.ShapeDtypeStruct((BH, R, 1), jnp.float32),
         ],
         scratch=[
-            pltpu.VMEM((bq, 1), jnp.float32),      # running row max
-            pltpu.VMEM((bq, 1), jnp.float32),      # running normalizer
-            pltpu.VMEM((bq, hd_p), jnp.float32),   # output accumulator
+            pltpu.VMEM((rows, 1), jnp.float32),      # running row max
+            pltpu.VMEM((rows, 1), jnp.float32),      # running normalizer
+            pltpu.VMEM((rows, hd_p), jnp.float32),   # output accumulator
         ],
         name="int_attn_fwd", interpret=interpret,
+        vmem_limit=_vmem_limit("fwd", (rows, keys), hd_p, max(Lq, Lk, Lv),
+                               (bq, bk), band is None),
     )(qm, km, vm, q_off=q_off, exps=exps)
 
 
 # =========================================================================
-# Backward — dQ (K blocks innermost, one q-row block accumulated)
+# Backward — dQ (key tiles walked, each row tile accumulated)
 # =========================================================================
 
 def _int_attn_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref,
@@ -430,10 +576,10 @@ def _int_attn_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref,
                             n_k: int, lq: int, lk: int, lv: int, lg: int,
                             ds_bits: int, sq_p: int, kv_heads: int,
                             kv_len: int, causal: bool, window, sc: float,
-                            bq: int, bk: int, integer_exp: bool,
-                            band=None):
+                            bq: int, bk: int, nr: int, nt: int,
+                            integer_exp: bool, band=None, tile_band=None):
     h = pl.program_id(0)
-    qi = pl.program_id(1)
+    qb = pl.program_id(1)
     step = pl.program_id(2)
 
     @pl.when(step == 0)
@@ -449,22 +595,31 @@ def _int_attn_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref,
     dse = None if tile_ds else exp_ref[4].astype(jnp.float32)
     off = off_ref[h // kv_heads]
 
-    def visit(kj):
-        ok = _valid_mask(off, qi, kj, bq=bq, bk=bk, sq_p=sq_p,
-                         kv_len=kv_len, causal=causal, window=window)
-        s = _limb_dot(q_ref, k_ref, lq, lk, (1, 1), qe + ke, 0) * sc
-        s = jnp.where(ok, s, _BIG_NEG)
-        # padded q rows carry lse = +1e30, so p vanishes there exactly
-        p = jnp.where(ok, _p_exp(s - lse_ref[0], integer_exp), 0.0)
+    def visit(kb):
+        def tile(r0, rows, k0, cols):
+            rs, ks = pl.ds(r0, rows), pl.ds(k0, cols)
+            ok = _valid_mask(off, qb * nr * bq + r0, kb * nt * bk + k0,
+                             (rows, cols), sq_p=sq_p, kv_len=kv_len,
+                             causal=causal, window=window)
+            s = _pair_dot(_planes(q_ref, lq, rs), _planes(k_ref, lk, ks),
+                          (1, 1), qe + ke, 0) * sc
+            s = jnp.where(ok, s, _BIG_NEG)
+            # padded q rows carry lse = +1e30, so p vanishes there exactly
+            p = jnp.where(ok, _p_exp(s - lse_ref[0, rs, :], integer_exp),
+                          0.0)
 
-        dp = _limb_dot(g_ref, v_ref, lg, lv, (1, 1), ge + ve, 0)
-        ds = p * (dp - d_ref[0])
-        e = _tile_ds_exp(ds, ds_bits) if tile_ds else dse
-        dsm = _round_clip(jnp.round(ds * jnp.exp2(-e)), ds_bits)
-        dq_scr[...] += _plane_dot(_split_planes(dsm, n_limbs(ds_bits)),
-                                  k_ref, lk, (1, 0), e + ke, 0)
+            dp = _pair_dot(_planes(g_ref, lg, rs), _planes(v_ref, lv, ks),
+                           (1, 1), ge + ve, 0)
+            ds = p * (dp - d_ref[0, rs, :])
+            e = _tile_ds_exp(ds, ds_bits) if tile_ds else dse
+            dsm = _round_clip(jnp.round(ds * jnp.exp2(-e)), ds_bits)
+            dq_scr[rs, :] += _pair_dot(_split_planes(dsm, n_limbs(ds_bits)),
+                                       _planes(k_ref, lk, ks), (1, 0),
+                                       e + ke, 0)
 
-    _visit_band(visit, band, off, qi, step)
+        _walk_keys(tile, tile_band, off, qb, kb, bq=bq, bk=bk, nr=nr, nt=nt)
+
+    _visit_band(visit, band, off, qb, step)
 
     @pl.when(step == n_k - 1)
     def _epilogue():
@@ -473,7 +628,7 @@ def _int_attn_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref,
 
 @functools.partial(jax.jit, static_argnames=(
     "ds_bits", "sq_p", "kv_heads", "kv_len", "causal", "window", "sc",
-    "bq", "bk", "interpret", "integer_exp"))
+    "bq", "bk", "blocks", "interpret", "integer_exp"))
 def int_attn_bwd_dq(
     qm: jax.Array,          # (Lq, BH, R, hd_p) int8 limb planes
     km: jax.Array,          # (Lk, BH, Sk_p, hd_p)
@@ -494,21 +649,23 @@ def int_attn_bwd_dq(
     sc: float,
     bq: int = 128,
     bk: int = 128,
+    blocks: tuple[int, int] | None = None,
     interpret: bool = False,
     integer_exp: bool = False,
 ) -> jax.Array:
-    """Fused dQ: (BH, R, hd_p) f32.  ``integer_exp`` must match the
-    forward's flag — the FA2 recompute ``p = exp(s - lse)`` has to rebuild
-    the same P the forward contracted."""
+    """Fused dQ: (BH, R, hd_p) f32.  Tile and block as in ``int_attn_fwd``;
+    ``integer_exp`` must match the forward's flag — the FA2 recompute
+    ``p = exp(s - lse)`` has to rebuild the same P the forward
+    contracted."""
     Lq, BH, R, hd_p = qm.shape
     Lk, _, Skp, _ = km.shape
     Lv, Lg = vm.shape[0], gm.shape[0]
     assert gm.shape[1:] == qm.shape[1:] and lse.shape == (BH, R, 1), (
         qm.shape, gm.shape, lse.shape)
-    masked = causal or window is not None
-    band, steps, kblk = _k_visits(Skp // bk, bq=bq, bk=bk, sq_p=sq_p,
-                                  kv_heads=kv_heads, causal=causal,
-                                  window=window)
+    rows, keys, nr, nt = _grid_block(blocks, bq, bk)
+    band, tile_band, steps, kblk = _k_visits(
+        Skp // bk, bq=bq, bk=bk, rows=rows, keys=keys, sq_p=sq_p,
+        kv_heads=kv_heads, causal=causal, window=window)
 
     def q_rows(h, i, j, off):
         return (0, h, i, 0)
@@ -522,22 +679,26 @@ def int_attn_bwd_dq(
     kernel = functools.partial(
         _int_attn_bwd_dq_kernel, n_k=steps, lq=Lq, lk=Lk, lv=Lv, lg=Lg,
         ds_bits=ds_bits, sq_p=sq_p, kv_heads=kv_heads, kv_len=kv_len,
-        causal=causal, window=window, sc=sc, bq=bq, bk=bk,
-        integer_exp=integer_exp, band=band)
+        causal=causal, window=window, sc=sc, bq=bq, bk=bk, nr=nr, nt=nt,
+        integer_exp=integer_exp, band=band, tile_band=tile_band)
     return _attn_call(
-        kernel, banded=masked, grid=(BH, R // bq, steps),
-        in_blocks=[((Lq, 1, bq, hd_p), q_rows), ((Lk, 1, bk, hd_p), k_rows),
-                   ((Lv, 1, bk, hd_p), k_rows), ((Lg, 1, bq, hd_p), q_rows),
-                   ((1, bq, 1), row), ((1, bq, 1), row)],
-        out_blocks=((1, bq, hd_p), row),
+        kernel, banded=band is not None, grid=(BH, R // rows, steps),
+        in_blocks=[((Lq, 1, rows, hd_p), q_rows),
+                   ((Lk, 1, keys, hd_p), k_rows),
+                   ((Lv, 1, keys, hd_p), k_rows),
+                   ((Lg, 1, rows, hd_p), q_rows),
+                   ((1, rows, 1), row), ((1, rows, 1), row)],
+        out_blocks=((1, rows, hd_p), row),
         out_shape=jax.ShapeDtypeStruct((BH, R, hd_p), jnp.float32),
-        scratch=[pltpu.VMEM((bq, hd_p), jnp.float32)],
+        scratch=[pltpu.VMEM((rows, hd_p), jnp.float32)],
         name="int_attn_bwd_dq", interpret=interpret,
+        vmem_limit=_vmem_limit("dq", (rows, keys), hd_p,
+                               max(Lq, Lk, Lv, Lg), (bq, bk), band is None),
     )(qm, km, vm, gm, lse, delta, q_off=q_off, exps=exps)
 
 
 # =========================================================================
-# Backward — dK / dV (q blocks innermost, one k-row block accumulated)
+# Backward — dK / dV (query tiles walked, each key tile accumulated)
 # =========================================================================
 
 def _int_attn_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref,
@@ -546,10 +707,11 @@ def _int_attn_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref,
                              n_q: int, lq: int, lk: int, lv: int, lg: int,
                              p_bits: int, ds_bits: int, sq_p: int,
                              kv_heads: int, kv_len: int, causal: bool,
-                             window, sc: float, bq: int, bk: int,
-                             integer_exp: bool, q_block=None):
+                             window, sc: float, bq: int, bk: int, nr: int,
+                             nt: int, integer_exp: bool, q_block=None,
+                             tile_band=None):
     h = pl.program_id(0)
-    kj = pl.program_id(1)
+    kb = pl.program_id(1)
     step = pl.program_id(2)
 
     @pl.when(step == 0)
@@ -564,32 +726,45 @@ def _int_attn_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref,
     tile_ds = causal or window is not None
     dse = None if tile_ds else exp_ref[4].astype(jnp.float32)
     off = off_ref[h // kv_heads]
+    nqb = sq_p // (nr * bq)                 # query blocks in one GQA group
 
-    def visit(qi):
-        ok = _valid_mask(off, qi, kj, bq=bq, bk=bk, sq_p=sq_p,
-                         kv_len=kv_len, causal=causal, window=window)
-        s = _limb_dot(q_ref, k_ref, lq, lk, (1, 1), qe + ke, 0) * sc
-        s = jnp.where(ok, s, _BIG_NEG)
-        p = jnp.where(ok, _p_exp(s - lse_ref[0], integer_exp), 0.0)
+    def visit(qb):
+        def tile(r0, rows, k0, cols):
+            rs, ks = pl.ds(r0, rows), pl.ds(k0, cols)
+            ok = _valid_mask(off, qb * nr * bq + r0, kb * nt * bk + k0,
+                             (rows, cols), sq_p=sq_p, kv_len=kv_len,
+                             causal=causal, window=window)
+            s = _pair_dot(_planes(q_ref, lq, rs), _planes(k_ref, lk, ks),
+                          (1, 1), qe + ke, 0) * sc
+            s = jnp.where(ok, s, _BIG_NEG)
+            p = jnp.where(ok, _p_exp(s - lse_ref[0, rs, :], integer_exp),
+                          0.0)
 
-        # dV: quantized-Pᵀ · dO — the same static-exponent P mantissa the
-        # forward contracted against V (straight-through at the quantizer)
-        pm = _round_clip(jnp.round(p * (2.0 ** (p_bits - 1))), p_bits)
-        dv_scr[...] += _plane_dot(_split_planes(pm, n_limbs(p_bits)), g_ref,
-                                  lg, (0, 0), ge, -(p_bits - 1))
+            # dV: quantized-Pᵀ · dO — the same static-exponent P mantissa
+            # the forward contracted against V (straight-through at the
+            # quantizer)
+            pm = _round_clip(jnp.round(p * (2.0 ** (p_bits - 1))), p_bits)
+            dv_scr[ks, :] += _pair_dot(_split_planes(pm, n_limbs(p_bits)),
+                                       _planes(g_ref, lg, rs), (0, 0), ge,
+                                       -(p_bits - 1))
 
-        dp = _limb_dot(g_ref, v_ref, lg, lv, (1, 1), ge + ve, 0)
-        ds = p * (dp - d_ref[0])
-        e = _tile_ds_exp(ds, ds_bits) if tile_ds else dse
-        dsm = _round_clip(jnp.round(ds * jnp.exp2(-e)), ds_bits)
-        dk_scr[...] += _plane_dot(_split_planes(dsm, n_limbs(ds_bits)),
-                                  q_ref, lq, (0, 0), e + qe, 0)
+            dp = _pair_dot(_planes(g_ref, lg, rs), _planes(v_ref, lv, ks),
+                           (1, 1), ge + ve, 0)
+            ds = p * (dp - d_ref[0, rs, :])
+            e = _tile_ds_exp(ds, ds_bits) if tile_ds else dse
+            dsm = _round_clip(jnp.round(ds * jnp.exp2(-e)), ds_bits)
+            dk_scr[ks, :] += _pair_dot(_split_planes(dsm, n_limbs(ds_bits)),
+                                       _planes(q_ref, lq, rs), (0, 0),
+                                       e + qe, 0)
+
+        _walk_queries(tile, tile_band, off, qb % nqb, kb, bq=bq, bk=bk,
+                      nr=nr, nt=nt)
 
     if q_block is None:
         visit(step)
     else:
-        qi, live = q_block(off, kj, step)
-        pl.when(live)(lambda: visit(qi))
+        qb, live = q_block(off, kb, step)
+        pl.when(live)(lambda: visit(qb))
 
     @pl.when(step == n_q - 1)
     def _epilogue():
@@ -599,7 +774,7 @@ def _int_attn_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref,
 
 @functools.partial(jax.jit, static_argnames=(
     "p_bits", "ds_bits", "sq_p", "kv_heads", "kv_len", "causal", "window",
-    "sc", "bq", "bk", "interpret", "integer_exp"))
+    "sc", "bq", "bk", "blocks", "interpret", "integer_exp"))
 def int_attn_bwd_dkv(
     qm: jax.Array,          # (Lq, BH, R, hd_p) int8 limb planes
     km: jax.Array,          # (Lk, BH, Sk_p, hd_p)
@@ -621,32 +796,37 @@ def int_attn_bwd_dkv(
     sc: float,
     bq: int = 128,
     bk: int = 128,
+    blocks: tuple[int, int] | None = None,
     interpret: bool = False,
     integer_exp: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """Fused dK, dV: each (BH, Sk_p, hd_p) f32.  ``integer_exp`` as in
-    ``int_attn_bwd_dq``."""
+    """Fused dK, dV: each (BH, Sk_p, hd_p) f32.  Tile and block as in
+    ``int_attn_fwd``; ``integer_exp`` as in ``int_attn_bwd_dq``."""
     Lq, BH, R, hd_p = qm.shape
     Lk, _, Skp, _ = km.shape
     Lv, Lg = vm.shape[0], gm.shape[0]
     assert gm.shape[1:] == qm.shape[1:] and lse.shape == (BH, R, 1), (
         qm.shape, gm.shape, lse.shape)
+    rows, keys, nr, nt = _grid_block(blocks, bq, bk)
     masked = causal or window is not None
-    q_block, steps = None, R // bq
+    q_block, tile_band, steps = None, None, R // rows
 
     def qblk(h, j, i, off):
         return i
 
     if masked:
-        nqb = sq_p // bq                   # q blocks in one GQA group
-        band = functools.partial(_q_band, bq=bq, bk=bk, nqb=nqb,
+        nqb = sq_p // rows                 # query blocks in one GQA group
+        band = functools.partial(_q_band, bq=rows, bk=keys, nqb=nqb,
                                  causal=causal, window=window)
-        per_group = _band_steps(nqb, bk, bq, causal, window)
+        tile_band = functools.partial(_q_band, bq=bq, bk=bk,
+                                      nqb=sq_p // bq, causal=causal,
+                                      window=window)
+        per_group = _band_steps(nqb, keys, rows, causal, window)
         steps = (R // sq_p) * per_group
 
         def q_block(off, kj, step):
-            """(q block, whether it is visited) of grid step ``step``: the
-            group's band, group by group."""
+            """(query block, whether it is visited) of grid step ``step``:
+            the group's band, group by group."""
             g = step // per_group
             j = step - g * per_group
             lo, hi = band(off, kj)
@@ -672,20 +852,25 @@ def int_attn_bwd_dkv(
         _int_attn_bwd_dkv_kernel, n_q=steps, lq=Lq, lk=Lk, lv=Lv, lg=Lg,
         p_bits=p_bits, ds_bits=ds_bits, sq_p=sq_p, kv_heads=kv_heads,
         kv_len=kv_len, causal=causal, window=window, sc=sc, bq=bq, bk=bk,
-        integer_exp=integer_exp, q_block=q_block)
+        nr=nr, nt=nt, integer_exp=integer_exp, q_block=q_block,
+        tile_band=tile_band)
     return _attn_call(
-        kernel, banded=masked, grid=(BH, Skp // bk, steps),
-        in_blocks=[((Lq, 1, bq, hd_p), q_rows), ((Lk, 1, bk, hd_p), k_rows),
-                   ((Lv, 1, bk, hd_p), k_rows), ((Lg, 1, bq, hd_p), q_rows),
-                   ((1, bq, 1), row), ((1, bq, 1), row)],
-        out_blocks=[((1, bk, hd_p), out_rows), ((1, bk, hd_p), out_rows)],
+        kernel, banded=masked, grid=(BH, Skp // keys, steps),
+        in_blocks=[((Lq, 1, rows, hd_p), q_rows),
+                   ((Lk, 1, keys, hd_p), k_rows),
+                   ((Lv, 1, keys, hd_p), k_rows),
+                   ((Lg, 1, rows, hd_p), q_rows),
+                   ((1, rows, 1), row), ((1, rows, 1), row)],
+        out_blocks=[((1, keys, hd_p), out_rows), ((1, keys, hd_p), out_rows)],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Skp, hd_p), jnp.float32),
             jax.ShapeDtypeStruct((BH, Skp, hd_p), jnp.float32),
         ],
         scratch=[
-            pltpu.VMEM((bk, hd_p), jnp.float32),
-            pltpu.VMEM((bk, hd_p), jnp.float32),
+            pltpu.VMEM((keys, hd_p), jnp.float32),
+            pltpu.VMEM((keys, hd_p), jnp.float32),
         ],
         name="int_attn_bwd_dkv", interpret=interpret,
+        vmem_limit=_vmem_limit("dkv", (rows, keys), hd_p,
+                               max(Lq, Lk, Lv, Lg), (bq, bk), not masked),
     )(qm, km, vm, gm, lse, delta, q_off=q_off, exps=exps)

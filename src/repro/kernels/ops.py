@@ -70,6 +70,8 @@ from __future__ import annotations
 import functools
 import itertools
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 
@@ -83,7 +85,9 @@ from repro.kernels.bfp_matmul import (bfp_matmul, bfp_matmul_batched,
 from repro.kernels.dfx_quant import (LIMB_BITS as _LIMB_BITS, _out_dtype,
                                      dfx_quantize, dfx_quantize_grouped,
                                      n_limbs)
-from repro.kernels.int_attention import (int_attn_bwd_dkv, int_attn_bwd_dq,
+from repro.kernels import int_attention as _ia
+from repro.kernels.int_attention import (attention_vmem_bytes,
+                                         int_attn_bwd_dkv, int_attn_bwd_dq,
                                          int_attn_fwd)
 from repro.kernels.int_norm import (int_layernorm_bwd, int_layernorm_fwd,
                                     int_rmsnorm_bwd, int_rmsnorm_fwd)
@@ -750,10 +754,12 @@ def rmsnorm_bwd_pallas(xm: jax.Array, x_exp: jax.Array, gm: jax.Array,
 # =========================================================================
 
 def _attn_dims(Sq: int, Sk: int, hd: int):
-    """Block / padded sizes of the rows layout.
+    """Score tile / padded sizes of the rows layout.
 
-    ``bq`` shrinks for short query runs (decode: Sq=1 -> bq=8) but always
-    divides ``sq_p``, so a q block never straddles two GQA groups.
+    ``bq`` × ``bk`` is the score tile, the unit of the kernels' numerics
+    (kernels/int_attention.py); ``bq`` shrinks for short query runs
+    (decode: Sq=1 -> bq=8) but always divides ``sq_p``, so a tile never
+    straddles two GQA groups.
     """
     bq = min(_LANE, _round_up_multiple(Sq, _SUBLANE))
     sq_p = _round_up_multiple(Sq, bq)
@@ -761,6 +767,98 @@ def _attn_dims(Sq: int, Sk: int, hd: int):
     sk_p = _round_up_multiple(Sk, bk)
     hd_p = _round_up_multiple(hd, _LANE)
     return bq, sq_p, bk, sk_p, hd_p
+
+
+#: modelled cost, in µs on a v5e, of one attention grid step and of one
+#: score tile a step checks and skips: least-squares fits over the block
+#: sweep in PERF.md (0.23-0.36 a step by kernel; 0.017 a skipped tile in
+#: dkv, ~0 in the forward and dq).  A live tile costs the same in every
+#: grid block, so it does not enter the pick.
+_ATTN_STEP_US = 0.3
+_ATTN_DEAD_US = 0.017
+
+#: VMEM budget for one attention grid step (``attention_vmem_bytes``); the
+#: kernel asks Mosaic for half again its modelled working set.
+_ATTN_VMEM_BUDGET = 32 * 1024 * 1024
+
+
+def _attn_counts(kernel: str, sq_p: int, sk_p: int, bq: int, bk: int,
+                 blocks: tuple[int, int], causal: bool, window,
+                 q_off: int = 0) -> tuple[int, int, int]:
+    """``(grid steps, tiles checked, live tiles)`` of one query head of one
+    batch row (one GQA group) in ``kernel`` at grid block ``blocks``, for
+    query offset ``q_off``: the grid the kernels build and the tiles their
+    walk visits (``int_attention._walk_keys`` / ``_walk_queries``)."""
+    rows, keys = blocks
+    nr, nt = rows // bq, keys // bk
+    nq, nk = sq_p // rows, sk_p // keys
+    if not causal and window is None:
+        return nq * nk, nq * nk * nr * nt, nq * nk * nr * nt
+    mask = dict(causal=causal, window=window, xp=np)
+    if kernel == "dkv":
+        lo, hi = _ia._q_band(q_off, np.arange(nk), bq=rows, bk=keys, nqb=nq,
+                             **mask)
+        tlo, thi = _ia._q_band(q_off, np.arange(sk_p // bk), bq=bq, bk=bk,
+                               nqb=sq_p // bq, **mask)
+        steps = nk * _ia._band_steps(nq, keys, rows, causal, window)
+    else:
+        lo, hi = _ia._k_band(q_off, np.arange(nq), bq=rows, bk=keys,
+                             sq_p=sq_p, n_k=nk, **mask)
+        tlo, thi = _ia._k_band(q_off, np.arange(sq_p // bq), bq=bq, bk=bk,
+                               sq_p=sq_p, n_k=sk_p // bk, **mask)
+        steps = nq * _ia._band_steps(nk, rows, keys, causal, window)
+    checked = int(np.sum(np.maximum(hi - lo + 1, 0))) * nr * nt
+    return steps, checked, int(np.sum(np.maximum(thi - tlo + 1, 0)))
+
+
+def _attn_blocks(kernel: str, sq_p: int, sk_p: int, hd_p: int, limbs: int,
+                 causal: bool, window, bq: int, bk: int) -> tuple[int, int]:
+    """Grid block ``(rows, keys)`` of ``kernel`` (``"fwd"``, ``"dq"``,
+    ``"dkv"``) at padded extents ``sq_p`` × ``sk_p``, score tile ``bq`` ×
+    ``bk`` and ``limbs`` planes per operand (the most of any).
+
+    Every block is a multiple of the tile along each axis and divides the
+    padded extent, so no operand is padded further and a block of rows
+    never straddles two GQA groups.  Among the blocks whose modelled VMEM
+    (``attention_vmem_bytes``) fits the budget, the chooser takes the one
+    of least modelled cost — grid steps × ``_ATTN_STEP_US`` + tiles checked
+    and skipped × ``_ATTN_DEAD_US`` (at query offset 0) — then the
+    smaller working set."""
+    all_live = not causal and window is None
+
+    def cost(b):
+        steps, checked, live = _attn_counts(kernel, sq_p, sk_p, bq, bk, b,
+                                            causal, window)
+        return (steps * _ATTN_STEP_US + (checked - live) * _ATTN_DEAD_US,
+                vmem(b))
+
+    def vmem(b):
+        return attention_vmem_bytes(kernel, *b, hd_p, limbs, (bq, bk),
+                                    all_live)
+
+    fitting = [b for b in itertools.product(
+        range(bq, sq_p + 1, bq), range(bk, sk_p + 1, bk))
+        if sq_p % b[0] == 0 and sk_p % b[1] == 0
+        and vmem(b) <= _ATTN_VMEM_BUDGET]
+    return min(fitting, key=cost) if fitting else (bq, bk)
+
+
+def attention_grid_plan(Sq: int, Sk: int, hd: int, limbs: int, causal: bool,
+                        window: int | None = None, q_off: int = 0) -> dict:
+    """Each attention kernel's grid block, grid steps and live score tiles
+    for one query head of one batch row: ``{kernel: {"blocks": (rows,
+    keys), "steps": n, "tiles": n}}`` over ``"fwd"``, ``"dq"``, ``"dkv"``
+    — the blocks the wrappers below pick, and the counts their grids
+    run at query offset ``q_off``."""
+    bq, sq_p, bk, sk_p, hd_p = _attn_dims(Sq, Sk, hd)
+    plan = {}
+    for kernel in ("fwd", "dq", "dkv"):
+        blocks = _attn_blocks(kernel, sq_p, sk_p, hd_p, limbs, causal,
+                              window, bq, bk)
+        steps, _, live = _attn_counts(kernel, sq_p, sk_p, bq, bk, blocks,
+                                      causal, window, q_off)
+        plan[kernel] = {"blocks": blocks, "steps": steps, "tiles": live}
+    return plan
 
 
 def _q_rows(qm: jax.Array, sq_p: int, hd_p: int) -> jax.Array:
@@ -808,11 +906,14 @@ def attention_fwd(qm: jax.Array, q_exp: jax.Array,
         _, B, Sq, KV, G, hd = qm.shape
         Sk = km.shape[2]
         bq, sq_p, bk, sk_p, hd_p = _attn_dims(Sq, Sk, hd)
+        limbs = max(qm.shape[0], km.shape[0], vm.shape[0])
         o, lse = int_attn_fwd(
             _q_rows(qm, sq_p, hd_p), _kv_rows(km, sk_p, hd_p),
             _kv_rows(vm, sk_p, hd_p), q_off, exps,
             p_bits=p_bits, sq_p=sq_p, kv_heads=KV, kv_len=Sk, causal=causal,
             window=window, sc=1.0 / float(hd) ** 0.5, bq=bq, bk=bk,
+            blocks=_attn_blocks("fwd", sq_p, sk_p, hd_p, limbs, causal,
+                                window, bq, bk),
             interpret=interpret, integer_exp=integer_exp)
         return (_rows_q_out(o, B, KV, G, sq_p, Sq, hd),
                 lse.reshape(B, KV, G, sq_p)[..., :Sq])
@@ -866,10 +967,16 @@ def attention_bwd(qm: jax.Array, q_exp: jax.Array,
         common = dict(sq_p=sq_p, kv_heads=KV, kv_len=Sk, causal=causal,
                       window=window, sc=sc, bq=bq, bk=bk,
                       interpret=interpret, integer_exp=integer_exp)
+        limbs = max(qm.shape[0], km.shape[0], vm.shape[0], gm.shape[0])
+
+        def blocks(kernel):
+            return _attn_blocks(kernel, sq_p, sk_p, hd_p, limbs, causal,
+                                window, bq, bk)
         dq = int_attn_bwd_dq(qr, kr, vr, gr, lse_r, d_r, q_off, exps,
-                             ds_bits=ds_bits, **common)
+                             ds_bits=ds_bits, blocks=blocks("dq"), **common)
         dk, dv = int_attn_bwd_dkv(qr, kr, vr, gr, lse_r, d_r, q_off, exps,
-                                  p_bits=p_bits, ds_bits=ds_bits, **common)
+                                  p_bits=p_bits, ds_bits=ds_bits,
+                                  blocks=blocks("dkv"), **common)
         dq = _rows_q_out(dq, B, KV, G, sq_p, Sq, hd)
         dk = dk.reshape(B, KV, sk_p, hd_p)[:, :, :Sk, :hd].transpose(
             0, 2, 1, 3)
