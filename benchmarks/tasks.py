@@ -1,16 +1,17 @@
-"""Synthetic proxy tasks + fine-tuning harness for the paper's tables.
+"""Synthetic proxy tasks + a fine-tuning loop for the bit-width sweeps in
+``examples/``.
 
-GLUE/SQuAD/CIFAR do not ship in this container (DESIGN.md §8); the paper's
-*claims* are about score deltas across bit-widths, so each benchmark
-fine-tunes a small transformer on a structured synthetic task and reports the
-same metric sweep. Tasks are built so the FP32 model reaches high accuracy
-quickly, making quantization-induced drops visible.
+GLUE/SQuAD/CIFAR are not bundled (DESIGN.md §8); the paper's *claims* are
+about score deltas across bit-widths, so each sweep fine-tunes a small
+transformer on a structured synthetic task and reports the same metric.
+Tasks are built so the FP32 model reaches high accuracy quickly, making
+quantization-induced drops visible.
 """
 from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Callable, Dict, List, Tuple
+from typing import Dict, List
 
 import jax
 import jax.numpy as jnp
@@ -122,8 +123,7 @@ def _task_setup(task: str, key, ft: FtConfig):
     return cfg, params, sampler, loss_fn, lr
 
 
-def finetune(task: str, qcfg: QuantConfig, ft: FtConfig = FtConfig(),
-             return_losses: bool = False):
+def finetune(task: str, qcfg: QuantConfig, ft: FtConfig = FtConfig()):
     """Fine-tune the task's model under ``qcfg``; returns (metric, losses)."""
     key = jax.random.PRNGKey(ft.seed)
     cfg, params, sampler, loss_fn, lr = _task_setup(task, key, ft)
@@ -163,7 +163,7 @@ def finetune(task: str, qcfg: QuantConfig, ft: FtConfig = FtConfig(),
                               None, patch=8)
         metric = 100 * float(jnp.mean(jnp.argmax(logits, -1)
                                       == jnp.asarray(ev["labels"])))
-    return (metric, losses) if return_losses else (metric, None)
+    return metric, losses
 
 
 def step_stats(task: str, qcfg: QuantConfig, ft: FtConfig = FtConfig()

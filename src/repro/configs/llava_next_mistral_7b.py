@@ -1,7 +1,7 @@
 """llava-next-mistral-7b — VLM, anyres tiling stub [hf:llava-hf/llava-v1.6-mistral-7b-hf; unverified].
 
-The vision tower is a STUB per the brief: ``input_specs`` provides
-precomputed patch embeddings (anyres tiling: base 576 patches + 4 tiles of
+The vision tower is a stub: the batch carries precomputed patch embeddings
+(``batch["patch_embeds"]``; anyres tiling: base 576 patches + 4 tiles of
 576 = 2880-token prefix); the mm projector + LM backbone are real.
 """
 from repro.models.config import ArchConfig

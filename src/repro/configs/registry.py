@@ -1,19 +1,10 @@
-"""Architecture registry: ``--arch <id>`` -> ArchConfig + model entry points
-+ dry-run ``input_specs``.
-
-``input_specs(arch, shape)`` returns ShapeDtypeStruct stand-ins for every
-model input of that (arch x shape) cell — weak-type-correct, shardable, no
-device allocation — consumed by ``launch/dryrun.py``.
-"""
+"""Architecture registry: ``--arch <id>`` -> ArchConfig, and ``--quant
+<name>`` -> a QuantConfig or QuantPolicy."""
 from __future__ import annotations
 
 import importlib
-from typing import Any, Dict
 
-import jax
-import jax.numpy as jnp
-
-from repro.models.config import SHAPES, ArchConfig, shape_applicable
+from repro.models.config import ArchConfig
 
 _MODULES = {
     "zamba2-2.7b": "zamba2_2p7b",
@@ -64,66 +55,3 @@ def get_quant(name: str):
     from repro.core import qpolicy
     return qpolicy.get(name)
 
-
-# ---------------------------------------------------------------------------
-# input specs per (arch, shape)
-# ---------------------------------------------------------------------------
-
-def _sds(shape, dtype):
-    return jax.ShapeDtypeStruct(shape, dtype)
-
-
-def input_specs(cfg: ArchConfig, shape: str) -> Dict[str, Any]:
-    """ShapeDtypeStructs for the entry point selected by ``shape``.
-
-    train:   the batch pytree fed to ``train_step``
-    prefill: prompt batch for ``prefill``
-    decode:  one-token batch + cache for ``serve_step``
-    """
-    ok, why = shape_applicable(cfg, shape)
-    if not ok:
-        raise ValueError(f"{cfg.name} x {shape}: {why}")
-    S, B, kind = SHAPES[shape]
-
-    if kind == "train":
-        if cfg.enc_dec:
-            return {
-                "frames": _sds((B, S, cfg.d_model), jnp.float32),
-                "tokens": _sds((B, S), jnp.int32),
-                "labels": _sds((B, S), jnp.int32),
-            }
-        batch = {"tokens": _sds((B, S), jnp.int32),
-                 "labels": _sds((B, S), jnp.int32)}
-        if cfg.vlm_prefix:
-            batch["tokens"] = _sds((B, S - cfg.vlm_prefix), jnp.int32)
-            batch["labels"] = _sds((B, S - cfg.vlm_prefix), jnp.int32)
-            batch["patch_embeds"] = _sds((B, cfg.vlm_prefix, cfg.d_model),
-                                         jnp.float32)
-        return batch
-
-    if kind == "prefill":
-        if cfg.enc_dec:
-            return {"frames": _sds((B, S, cfg.d_model), jnp.float32),
-                    "tokens": _sds((B, S), jnp.int32)}
-        batch = {"tokens": _sds((B, S), jnp.int32)}
-        if cfg.vlm_prefix:
-            batch["tokens"] = _sds((B, S - cfg.vlm_prefix), jnp.int32)
-            batch["patch_embeds"] = _sds((B, cfg.vlm_prefix, cfg.d_model),
-                                         jnp.float32)
-        return batch
-
-    # decode: one new token against a seq_len-deep cache
-    from repro.models import encdec, lm  # local import to avoid cycles
-    spec = {"token": _sds((B, 1), jnp.int32)}
-    if cfg.enc_dec:
-        cache = jax.eval_shape(
-            lambda: encdec.encdec_init_cache(cfg, B, S))
-        spec["cache"] = cache
-        KV, hd = cfg.n_kv_heads, cfg.head_dim
-        spec["cross_kv"] = (
-            _sds((cfg.n_layers, B, S, KV, hd), jnp.bfloat16),
-            _sds((cfg.n_layers, B, S, KV, hd), jnp.bfloat16),
-        )
-    else:
-        spec["cache"] = jax.eval_shape(lambda: lm.init_cache(cfg, B, S))
-    return spec

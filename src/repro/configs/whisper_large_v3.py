@@ -1,7 +1,7 @@
 """whisper-large-v3 — enc-dec, conv frontend stub [arXiv:2212.04356; unverified].
 
-``input_specs`` provides precomputed frame embeddings (the mel+conv frontend
-is a stub per the brief); encoder/decoder transformer stacks are real.
+The batch carries precomputed frame embeddings (the mel+conv frontend is a
+stub); encoder/decoder transformer stacks are real.
 """
 from repro.models.config import ArchConfig
 

@@ -36,8 +36,7 @@ def storage_dtype(bits: int):
     """Narrowest signed-integer dtype that holds a ``bits``-bit mantissa.
 
     Narrow storage is a real memory win: residual activations saved for the
-    backward pass are int8/int16 mantissas instead of FP32 (4x/2x smaller) —
-    this shows up directly in the dry-run ``memory_analysis``.
+    backward pass are int8/int16 mantissas instead of FP32 (4x/2x smaller).
     """
     if bits <= 8:
         return jnp.int8

@@ -13,7 +13,7 @@ mapping to the collective level: the cross-pod data-parallel all-reduce
      (Karimireddy et al. 2019 — without EF, signSGD-style compression can
      stall; with EF it matches full-precision convergence rates).
 
-4x fewer bytes over the pod interconnect; measured in EXPERIMENTS.md §Perf.
+4x fewer bytes over the pod interconnect (a model, not measured on a chip).
 
 The wire format is a :class:`repro.core.qtensor.QTensor` quantized against
 a ``pmax``-shared scale (the ``exp=`` override), so this module carries no

@@ -10,8 +10,8 @@ integer arithmetic on b-bit dynamic fixed-point mantissas (paper: Fig. 2 and
                                       rounded (Assumption 2 unbiasedness)
 
 Residuals saved for the backward pass are the *quantized* mantissas
-(int8/int16), which is a 4x/2x activation-memory saving over FP32 — visible
-in the dry-run memory analysis.
+(int8/int16), a 4x/2x activation-memory saving over FP32 (the chip
+benchmark's ``peak_hbm_gib`` reads the device's peak).
 
 ``int_attention`` extends the same contract to the attention block: the two
 quadratic contractions (QKᵀ and PV) and all four backward products run on
@@ -129,28 +129,6 @@ def _quant_grad(g: Array, cfg: QuantConfig, key,
                      limb_planes=limb_planes)
 
 
-#: When True, FSDP-sharded weights are quantized *shard-locally* and the
-#: int8/int16 MANTISSAS are what the all-gather moves (4x/2x fewer bytes on
-#: the wire than gathering FP32 then quantizing) — the paper's mapping
-#: promoted to the FSDP collective. Enabled via dryrun --variant q_gather;
-#: measured in EXPERIMENTS.md §Perf.
-QUANTIZED_WEIGHT_GATHER = False
-
-
-def _maybe_gather_quantized(qw: dfx.DfxTensor) -> dfx.DfxTensor:
-    if not QUANTIZED_WEIGHT_GATHER:
-        return qw
-    from repro import sharding as _sh
-    spec = [None] * (qw.m.ndim - 1) + ["model"]
-    # optimization_barrier on BOTH sides of the reshard: XLA's algebraic
-    # simplifier otherwise swaps the narrow-int convert with the all-gather
-    # and moves FP32 over the wire (verified in the compiled HLO).
-    m = jax.lax.optimization_barrier(qw.m)
-    m = _sh.constrain(m, *spec)
-    m = jax.lax.optimization_barrier(m)
-    return dfx.DfxTensor(m=m, exp=qw.exp)
-
-
 # =========================================================================
 # Linear
 # =========================================================================
@@ -179,8 +157,7 @@ def _int_linear_fwd(x, w, b, key, cfg: QuantConfig):
     # split never runs as XLA arithmetic, forward or backward).
     qx = _quantize(x, cfg.act_bits, cfg, stochastic=kf is not None, key=kf,
                    limb_planes=True)
-    qw = _maybe_gather_quantized(
-        _quantize(w, cfg.weight_bits, cfg, limb_planes=True))
+    qw = _quantize(w, cfg.weight_bits, cfg, limb_planes=True)
     if cfg.backend == "pallas":
         # kernel path: batch dims flattened to the 2-D (M, K) @ (K, N)
         # tiling, limb planes riding the leading axis
@@ -500,7 +477,7 @@ def int_embedding(table: Array, ids: Array, key, cfg: QuantConfig) -> Array:
 
 
 def _int_embedding_fwd(table, ids, key, cfg: QuantConfig):
-    if not cfg.enabled or not cfg.int_embedding:
+    if not cfg.enabled:
         return table[ids], (table.shape, ids, key)
     # backend-routed: QuantConfig(backend="pallas") quantizes the table
     # through the Pallas kernel like every other integer layer
@@ -513,7 +490,7 @@ def _int_embedding_fwd(table, ids, key, cfg: QuantConfig):
 
 def _int_embedding_bwd(cfg: QuantConfig, res, g):
     table_shape, ids, key = res
-    if not cfg.enabled or not cfg.int_embedding:
+    if not cfg.enabled:
         gq = g
     else:
         gq = dfx.dequantize(_quant_grad(g, cfg, key))
@@ -560,8 +537,8 @@ def int_layernorm(x: Array, gamma: Array, beta: Array, key,
 
 
 def _int_ln_fwd(x, gamma, beta, key, cfg: QuantConfig, eps):
-    ik = cfg.enabled and cfg.int_layernorm and cfg.kept_ops == "integer"
-    if cfg.enabled and cfg.int_layernorm:
+    ik = cfg.enabled and cfg.kept_ops == "integer"
+    if cfg.enabled:
         kf = None
         if cfg.stochastic_fwd and key is not None:
             key, kf = jax.random.split(key)
@@ -594,7 +571,7 @@ def _int_ln_fwd(x, gamma, beta, key, cfg: QuantConfig, eps):
 
 def _int_ln_bwd(cfg: QuantConfig, eps, res, g):
     xr, gv, rstd, mu, key = res
-    if cfg.enabled and cfg.int_layernorm and cfg.backend == "pallas":
+    if cfg.enabled and cfg.backend == "pallas":
         qg = _quant_grad(g, cfg, key)
         D = g.shape[-1]
         dx, dgamma, dbeta = kops.layernorm_bwd_pallas(
@@ -602,7 +579,7 @@ def _int_ln_bwd(cfg: QuantConfig, eps, res, g):
             gv, mu.reshape(-1, 1), rstd.reshape(-1, 1))
         return (dx.reshape(g.shape), dgamma, dbeta,
                 _float0(key) if key is not None else None)
-    if cfg.enabled and cfg.int_layernorm:
+    if cfg.enabled:
         xv = dfx.dequantize(xr)
         gq = dfx.dequantize(_quant_grad(g, cfg, key))
     else:
@@ -628,8 +605,8 @@ def int_rmsnorm(x: Array, gamma: Array, key, cfg: QuantConfig,
 
 
 def _int_rms_fwd(x, gamma, key, cfg: QuantConfig, eps):
-    ik = cfg.enabled and cfg.int_layernorm and cfg.kept_ops == "integer"
-    if cfg.enabled and cfg.int_layernorm:
+    ik = cfg.enabled and cfg.kept_ops == "integer"
+    if cfg.enabled:
         kf = None
         if cfg.stochastic_fwd and key is not None:
             key, kf = jax.random.split(key)
@@ -655,7 +632,7 @@ def _int_rms_fwd(x, gamma, key, cfg: QuantConfig, eps):
 
 def _int_rms_bwd(cfg: QuantConfig, eps, res, g):
     xr, gv, rstd, key = res
-    if cfg.enabled and cfg.int_layernorm and cfg.backend == "pallas":
+    if cfg.enabled and cfg.backend == "pallas":
         qg = _quant_grad(g, cfg, key)
         D = g.shape[-1]
         dx, dgamma = kops.rmsnorm_bwd_pallas(
@@ -663,7 +640,7 @@ def _int_rms_bwd(cfg: QuantConfig, eps, res, g):
             gv, rstd.reshape(-1, 1))
         return (dx.reshape(g.shape), dgamma,
                 _float0(key) if key is not None else None)
-    if cfg.enabled and cfg.int_layernorm:
+    if cfg.enabled:
         xv = dfx.dequantize(xr)
         gq = dfx.dequantize(_quant_grad(g, cfg, key))
     else:

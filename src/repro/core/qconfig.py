@@ -19,7 +19,6 @@ from __future__ import annotations
 import dataclasses
 import os
 import warnings
-from typing import Optional
 
 
 class StabilityWarning(UserWarning):
@@ -77,13 +76,6 @@ class QuantConfig:
     stochastic_grad: bool = True
     #: also stochastically round the forward mappings (off in the paper).
     stochastic_fwd: bool = False
-    #: block size for per-block scales (None => per-tensor scale, the paper's
-    #: setting). Per-block is a beyond-paper extension evaluated in §Perf.
-    block_size: Optional[int] = None
-    #: quantize the layer-norm statistics path (paper: yes, LN is integer).
-    int_layernorm: bool = True
-    #: quantize embedding tables / lookups (paper: yes).
-    int_embedding: bool = True
     #: execution backend for the integer layers: "sim" runs the mantissa
     #: contractions through XLA with float accumulators (exactness governed
     #: by ``dfx.acc_dtype``); "pallas" routes quantization and both matmul
@@ -118,17 +110,12 @@ class QuantConfig:
                 "the paper's stability constraint (Fig. 4: w8-a8-g8 diverges "
                 "while w8-a12-g8 matches FP32); pass warn_stability=False to "
                 "silence", StabilityWarning, stacklevel=2)
-        if self.block_size is not None and self.block_size < 8:
-            raise ValueError("block_size must be >= 8 (VMEM lane alignment)")
         if self.backend not in ("sim", "pallas"):
             raise ValueError(
                 f"backend={self.backend!r} not in ('sim', 'pallas')")
         if self.kept_ops not in ("fp32", "integer"):
             raise ValueError(
                 f"kept_ops={self.kept_ops!r} not in ('fp32', 'integer')")
-        if self.backend == "pallas" and self.block_size is not None:
-            raise ValueError("backend='pallas' supports per-tensor scales "
-                             "only (block_size must be None)")
 
     # -- presets matching the paper's experimental grid -------------------
     @staticmethod

@@ -1,4 +1,4 @@
-"""Production mesh construction (brief: MULTI-POD DRY-RUN step 1).
+"""Mesh construction.
 
 A function, not a module-level constant, so importing this module never
 touches jax device state.

@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import utils
 from repro.core import health, int_ops
 from repro.core.qpolicy import QuantLike, ensure_scope
 from repro.models.config import ArchConfig, RopeConfig
@@ -86,7 +85,7 @@ def scan_stack(make_body, carry, groups, xs):
     along as an ordinary element, since ``arange(L)[s:e] == arange(s, e)``.
 
     With one group (uniform policy, or a bare config) this is exactly
-    ``utils.scan(make_body(scope), carry, xs)`` — no slicing, so the traced
+    ``jax.lax.scan(make_body(scope), carry, xs)`` — no slicing, so the traced
     jaxpr is byte-identical to the pre-policy path.  With several, each run
     scans its slice of ``xs`` and stacked outputs are concatenated back in
     layer order (decode caches, per-layer KV, ...).
@@ -95,10 +94,10 @@ def scan_stack(make_body, carry, groups, xs):
     # stack's name
     with jax.named_scope(groups[0][2].path[-2]):
         if len(groups) == 1:
-            return utils.scan(make_body(groups[0][2]), carry, xs)
+            return jax.lax.scan(make_body(groups[0][2]), carry, xs)
         outs = []
         for (s, e, bsc) in groups:
-            carry, out = utils.scan(
+            carry, out = jax.lax.scan(
                 make_body(bsc), carry,
                 jax.tree.map(lambda a, s=s, e=e: a[s:e], xs))
             outs.append(out)
@@ -219,7 +218,7 @@ def flash_attention(
     m0 = jnp.full((B, Hkv, G, Sq), _BIG_NEG, jnp.float32)
     l0 = jnp.zeros((B, Hkv, G, Sq), jnp.float32)
     a0 = jnp.zeros((B, Hkv, G, Sq, hd), jnp.float32)
-    (m, l, acc), _ = utils.scan(body, (m0, l0, a0), jnp.arange(n_chunks))
+    (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0), jnp.arange(n_chunks))
     out = acc / jnp.maximum(l, 1e-20)[..., None]
     return out.transpose(0, 3, 1, 2, 4)          # (B, Sq, Hkv, G, hd)
 

@@ -1,5 +1,5 @@
-"""Whisper-style encoder–decoder backbone (audio frontend is a stub per the
-brief: ``input_specs`` provides precomputed frame embeddings).
+"""Whisper-style encoder–decoder backbone (the audio frontend is a stub:
+the batch carries precomputed frame embeddings, ``batch["frames"]``).
 
 Encoder: bidirectional self-attention, layernorm, GeLU MLP (integer layers).
 Decoder: causal self-attention + cross-attention over encoder output.

@@ -299,12 +299,12 @@ def _backbone_train_ssm(params: Params, x: Array, cfg: ArchConfig,
 
     def group_body(x, inp):
         gp, gidx = inp
-        x, _ = utils.scan(mamba_body, x,
+        x, _ = jax.lax.scan(mamba_body, x,
                             (gp, gidx * every + jnp.arange(every)))
         x, _ = shared_body(x, gidx)
         return x, None
 
-    x, _ = utils.scan(group_body, x, (grouped, jnp.arange(G)))
+    x, _ = jax.lax.scan(group_body, x, (grouped, jnp.arange(G)))
     return x, blocks.moe_aux_zero()
 
 
@@ -463,7 +463,7 @@ def lm_decode_step(params: Params, token: Array, cache: Params,
 
             def group_body(x, inp):
                 gp, s_ssm, s_cx, s_cbc, ck, cv = inp
-                x, ns = utils.scan(mamba_body, x, (gp, s_ssm, s_cx, s_cbc))
+                x, ns = jax.lax.scan(mamba_body, x, (gp, s_ssm, s_cx, s_cbc))
                 h = blocks.norm_apply(params["shared_attn"]["ln1"], x, cfg,
                                       ssc.child("ln1"), None)
                 h, (nk, nv) = blocks.attention_apply(
@@ -477,7 +477,7 @@ def lm_decode_step(params: Params, token: Array, cache: Params,
                 return x + h, ns + (nk, nv)
 
             with health.suspend():   # probes inside group_body can't harvest
-                x, (n_ssm, n_cx, n_cbc, nk, nv) = utils.scan(
+                x, (n_ssm, n_cx, n_cbc, nk, nv) = jax.lax.scan(
                     group_body, x,
                     (grouped,) + g_states + (cache["k"], cache["v"]))
             new_cache = {

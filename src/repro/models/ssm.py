@@ -22,7 +22,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import utils
 from repro.core import int_ops
 from repro.core.qpolicy import QuantLike, ensure_scope
 from repro.models.blocks import subkey, _init
@@ -100,12 +99,11 @@ def ssd_chunked(x: Array, dt: Array, A: Array, B: Array, C: Array,
         return s, s_in
 
     s0 = init_state if init_state is not None else jnp.zeros((b, H, P, N), jnp.float32)
-    # cheap elementwise recurrence: excluded from analysis unrolling (the
-    # heavy intra-chunk einsums above are batched over chunks already)
-    final_state, prev_states = utils.scan(
+    # cheap elementwise recurrence (the heavy intra-chunk einsums above are
+    # batched over chunks already)
+    final_state, prev_states = jax.lax.scan(
         scan_fn, s0,
-        (states.transpose(1, 0, 2, 3, 4), chunk_decay.transpose(1, 0, 2)),
-        analysis_unroll=False)
+        (states.transpose(1, 0, 2, 3, 4), chunk_decay.transpose(1, 0, 2)))
     prev_states = prev_states.transpose(1, 0, 2, 3, 4)      # (b, nc, H, P, N)
 
     state_decay_in = jnp.exp(dA_cs)

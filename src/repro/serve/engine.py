@@ -2,9 +2,7 @@
 continuous-batching slot scheduler.
 
 The engine is deliberately model-agnostic: it drives the ``lm_prefill`` /
-``lm_decode_step`` entry points (or their enc-dec equivalents) that the
-dry-run also lowers, so serve-time sharding is identical to the compiled
-decode cells in EXPERIMENTS.md.
+``lm_decode_step`` entry points (or their enc-dec equivalents).
 """
 from __future__ import annotations
 

@@ -159,19 +159,12 @@ def constrain_batch(x: jax.Array) -> jax.Array:
     return constrain(x, batch_axes(), *([None] * (x.ndim - 1)))
 
 
-#: sequence-parallel residual sharding (Megatron-SP layout). Disable via the
-#: dry-run "--variant no_sp" to measure its collective cost/benefit.
-SEQUENCE_SHARDING = True
-
-
 def constrain_tokens(x: jax.Array) -> jax.Array:
     """(B, S, D) residual stream: batch over DP, sequence over model (the
     Megatron-SP layout — XLA all-gathers S for attention and reduce-scatters
     after, halving activation memory per device)."""
     if x.ndim == 3:
-        if SEQUENCE_SHARDING:
-            return constrain(x, batch_axes(), "model", None)
-        return constrain(x, batch_axes(), None, None)
+        return constrain(x, batch_axes(), "model", None)
     return constrain_batch(x)
 
 

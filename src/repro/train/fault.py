@@ -17,8 +17,7 @@ simulation here and under a k8s/JobSet launcher:
   magnitude high and mask real stragglers for hundreds of steps.
 * **structured events** — retries, restores and straggler flags are emitted
   through an ``on_event`` callback (dicts with a ``type`` key), the feed
-  the chaos tests and ``benchmarks/backend_compare.py``'s robustness
-  section consume.
+  the chaos tests consume.
 * **elastic restart** — restore accepts any mesh (checkpoint.py is
   mesh-agnostic), so recovering with fewer/more pods only requires
   re-deriving shardings, which the trainer does from the params pytree.

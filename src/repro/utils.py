@@ -1,11 +1,5 @@
-"""Shared utilities.
-
-``scan`` wraps ``jax.lax.scan`` with a global ANALYSIS_UNROLL switch: XLA's
-``cost_analysis`` counts a while-loop body **once** regardless of trip count,
-so the roofline pass lowers reduced-depth configs with every scan fully
-unrolled and extrapolates per-layer costs (launch/dryrun.py).  Production
-lowering keeps the rolled loops (small HLO, working activation memory).
-"""
+"""Shared utilities: the layer stacks' remat, jaxpr counters, and the
+persistent compile cache."""
 from __future__ import annotations
 
 import os
@@ -13,31 +7,12 @@ import pathlib
 
 import jax
 
-ANALYSIS_UNROLL = False
-
-
-def scan(body, carry, xs, length=None, unroll=None, analysis_unroll=True):
-    """``analysis_unroll=False`` marks loops whose body is cheap/elementwise
-    (e.g. the SSD inter-chunk state recurrence): their per-trip cost is
-    negligible, and unrolling them would explode analysis-mode HLO."""
-    if ANALYSIS_UNROLL and analysis_unroll:
-        unroll = True
-    return jax.lax.scan(body, carry, xs, length=length,
-                        unroll=unroll if unroll is not None else 1)
-
-
-#: activation-checkpoint policy for the per-layer remat:
-#:   None      — full remat (recompute everything; min memory, +~2ND flops)
-#:   "dots"    — save matmul outputs, recompute elementwise (perf variant)
-#:   "nothing" — alias of full remat
-CHECKPOINT_POLICY = None
-
 
 def checkpoint(f):
-    """jax.checkpoint wrapper honouring the global CHECKPOINT_POLICY."""
-    if CHECKPOINT_POLICY == "dots":
-        return jax.checkpoint(
-            f, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+    """The one place the layer stacks take their remat from: a full
+    ``jax.checkpoint`` (every activation recomputed in the backward).  A
+    remat policy chosen from observed memory belongs here and nowhere
+    else."""
     return jax.checkpoint(f)
 
 
@@ -60,9 +35,8 @@ def count_pallas_calls(jaxpr) -> int:
     """Count ``pallas_call`` eqns in a (closed) jaxpr.
 
     Used by the MoE and norm dispatch-count acceptance tests and by
-    ``benchmarks/backend_compare.py`` to measure the batched expert-axis
-    kernels against the per-expert unrolled loop they replaced.  Thin
-    wrapper over ``repro.analysis.walker.count_pallas_calls``.
+    ``benchmarks/check_dispatch.py``.  Thin wrapper over
+    ``repro.analysis.walker.count_pallas_calls``.
     """
     from repro.analysis import walker
     return walker.count_pallas_calls(jaxpr)
@@ -84,17 +58,3 @@ def use_compile_cache() -> str:
         jax.config.update("jax_compilation_cache_dir", path)
     return path
 
-
-class analysis_unroll:
-    """Context manager enabling full scan unrolling (roofline analysis)."""
-
-    def __enter__(self):
-        global ANALYSIS_UNROLL
-        self._prev = ANALYSIS_UNROLL
-        ANALYSIS_UNROLL = True
-        return self
-
-    def __exit__(self, *exc):
-        global ANALYSIS_UNROLL
-        ANALYSIS_UNROLL = self._prev
-        return False

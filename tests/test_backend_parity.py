@@ -270,8 +270,6 @@ def test_stochastic_grad_unbiased_on_pallas():
 def test_backend_validation():
     with pytest.raises(ValueError):
         QuantConfig(backend="cuda")
-    with pytest.raises(ValueError):
-        QuantConfig(backend="pallas", block_size=64)
 
 
 def test_acc_dtype_escalation():

@@ -1,6 +1,6 @@
 """Distribution tests that need >1 device: run in a subprocess with
 --xla_force_host_platform_device_count so the main pytest process keeps its
-single-device view (the dry-run owns the 512-device config)."""
+single-device view."""
 import os
 import subprocess
 import sys
