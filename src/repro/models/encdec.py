@@ -104,7 +104,8 @@ def encode(params: Params, frames: Array, cfg: ArchConfig, qcfg: QuantLike,
             h = blocks.mlp_apply(bp["mlp"], h, cfg, bsc.child("mlp"),
                                  subkey(k, 3))
             return sharding.constrain_tokens(x + h), None
-        return utils.checkpoint(body)
+        return utils.checkpoint(body, stack="enc_blocks",
+                                depth=cfg.n_enc_layers)
 
     Le = cfg.n_enc_layers
     groups = layer_groups(sc, Le, _enc_leaves(cfg), stack="enc")
@@ -160,7 +161,8 @@ def _decoder(params: Params, x: Array, enc: Array, cfg: ArchConfig,
     if self_cache is None:      # teacher-forced training: cross KV on the fly
         def make_body(bsc):
             return utils.checkpoint(
-                lambda c, i: (body(c, i[0], i[1], None, None, bsc)[0], None))
+                lambda c, i: (body(c, i[0], i[1], None, None, bsc)[0], None),
+                stack="dec_blocks", depth=L)
 
         with health.suspend():     # enc-dec scans have no harvest channel
             x, _ = blocks.scan_stack(make_body, x, groups,

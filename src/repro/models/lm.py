@@ -2,9 +2,11 @@
 families, assembled from the integer blocks.
 
 Layers are **scan-stacked** (one traced layer body, ``lax.scan`` over stacked
-params) with ``jax.checkpoint`` remat — keeps the HLO small enough to compile
-88-layer/12k-wide configs against a 512-device mesh and bounds activation
-memory to one residual checkpoint per layer.
+params) — keeps the HLO small enough to compile 88-layer/12k-wide configs
+against a 512-device mesh — with each layer's remat from
+``utils.checkpoint``: its residuals kept where the stack's fit the device's
+memory, else a full ``jax.checkpoint`` that bounds activation memory to one
+residual checkpoint per layer.
 
 Three entry points per the shape grid:
   * ``loss_fn``      — next-token CE training objective (train_4k)
@@ -187,7 +189,7 @@ def _backbone_train(params: Params, x: Array, cfg: ArchConfig,
             with health.frame() as fr:
                 x, a, _ = _attn_block(bp, x, cfg, bsc, subkey(key, idx))
             return (x, _add(aux, a)), fr.harvest()
-        return utils.checkpoint(body)
+        return utils.checkpoint(body, stack="blocks", depth=L)
 
     groups = layer_groups(sc, L, _block_leaves(cfg))
     (x, aux), hs = blocks.scan_stack(make_body, (x, blocks.moe_aux_zero()),
@@ -240,7 +242,8 @@ def _backbone_train_periods(params: Params, x: Array, cfg: ArchConfig,
                 x, a, _ = _attn_block(bp, x, cfg, bsc, subkey(key, idx),
                                       kind=kind)
             return x, a, fr.harvest()
-        return utils.checkpoint(layer)
+        return utils.checkpoint(layer, stack=f"blocks.{kind}",
+                                depth=cfg.n_layers)
 
     def make_body(bsc):
         layers = {k: make_layer(bsc, k) for k in set(cfg.layer_pattern)}
@@ -277,7 +280,7 @@ def _backbone_train_ssm(params: Params, x: Array, cfg: ArchConfig,
             h, _ = ssm.mamba2_apply(bp["mamba"], x, cfg,
                                     bsc.child("mamba"), k)
             return sharding.constrain_tokens(x + h), None
-        return utils.checkpoint(mamba_body)
+        return utils.checkpoint(mamba_body, stack="blocks", depth=L)
 
     if cfg.family == "ssm":
         groups = layer_groups(sc, L, _MAMBA_LEAVES)
@@ -295,7 +298,8 @@ def _backbone_train_ssm(params: Params, x: Array, cfg: ArchConfig,
     shared_body = utils.checkpoint(
         lambda x, idx: _attn_block(params["shared_attn"], x, cfg,
                                    sc.child("shared_attn"),
-                                   subkey(key, 10_000 + idx))[:2])
+                                   subkey(key, 10_000 + idx))[:2],
+        stack="shared_attn", depth=G)
 
     def group_body(x, inp):
         gp, gidx = inp

@@ -86,7 +86,7 @@ def _encoder(params, x, cfg, qcfg, key):
             h = blocks.mlp_apply(bp["mlp"], h, cfg, bsc.child("mlp"),
                                  subkey(k, 3))
             return x + h, None
-        return utils.checkpoint(body)
+        return utils.checkpoint(body, stack="blocks", depth=cfg.n_layers)
 
     L = cfg.n_layers
     groups = layer_groups(sc, L, _ENC_BLOCK_LEAVES)

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-from repro import sharding
+from repro import sharding, utils
 from repro.configs.bert_base import CONFIG as BERT_BASE
 from repro.core.qconfig import QuantConfig
 from repro.kernels import ops
@@ -216,6 +216,17 @@ def test_int16_step_compiles_on_one_chip(topo, monkeypatch):
     monkeypatch.setattr(ops, "on_tpu", lambda: True)
     qcfg = dataclasses.replace(QuantConfig.int16(), backend="pallas")
     assert "bfp_matmul_tn" in _bert_step_text(topo.devices[:1], qcfg, 2)
+
+
+def test_int16_step_compiles_keeping_residuals(topo, monkeypatch):
+    """The same step with the layers' residuals kept (the remat policy a
+    chip with room takes, forced here by the memory limit it reads): the
+    backward reads the saved kernel outputs instead of re-running them."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    monkeypatch.setattr(utils, "device_bytes_limit", lambda: 16 << 30)
+    qcfg = dataclasses.replace(QuantConfig.int16(), backend="pallas")
+    assert "bfp_matmul_tn" in _bert_step_text(topo.devices[:1], qcfg, 2)
+    assert utils.remat_decisions()["blocks"].mode == "policy"
 
 
 #: one chip's share of Mellum2-12B-A2.5B at B=2, S=8192: 8 held experts
